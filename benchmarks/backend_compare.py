@@ -49,7 +49,7 @@ def run_p(p: int, qpp: int = QPP, steps: int = STEPS, iters: int = ITERS):
         n_queries = steps * cfg.queries_per_step
         ops_j, keys_j, vals_j = mixed_stream(cfg, steps)  # same in every mode
         jfn = jax.jit(run_stream,
-                      static_argnames=("backend", "fused", "bucket_tiles"))
+                      static_argnames=("backend", "fused"))
         fns[mode] = functools.partial(jfn, tab, ops_j, keys_j, vals_j,
                                       fused=fused)
     us = bench_group(fns, iters=iters, warmup=2)

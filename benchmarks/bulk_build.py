@@ -42,8 +42,6 @@ import argparse
 import functools
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 
@@ -99,8 +97,7 @@ def run_single(n: int, kind: str, iters: int):
     vals_j = jnp.array(vv_t.reshape(T, N, cfg.val_words))
     keys_f, vals_f = jnp.array(keys), jnp.array(vals)
 
-    jscan = jax.jit(run_stream, static_argnames=("backend", "fused",
-                                                 "bucket_tiles", "binned"))
+    jscan = jax.jit(run_stream, static_argnames=("backend", "fused"))
     jstep = jax.jit(step, static_argnames=("backend",))
 
     def streamed():
@@ -237,17 +234,10 @@ def main() -> None:
 
     sharded = (not args.smoke) if args.sharded is None else args.sharded
     if sharded:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8"
-                            ).strip()
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(_ROOT, "src"), _ROOT, env.get("PYTHONPATH", "")])
-        cmd = [sys.executable, os.path.abspath(__file__), "--child"]
-        if args.smoke:
-            cmd.append("--smoke")
-        r = subprocess.run(cmd, env=env, cwd=_ROOT, capture_output=True,
-                           text=True)
+        from benchmarks.common import run_fake_device_child
+        r = run_fake_device_child(__file__,
+                                  ["--smoke"] if args.smoke else [],
+                                  capture=True)
         if r.returncode:
             raise RuntimeError(f"bulk_build sharded child failed "
                                f"(exit {r.returncode}):\n{r.stderr}")

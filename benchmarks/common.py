@@ -1,13 +1,40 @@
 """Benchmark plumbing: wall-clock timing + CSV rows (name,us_per_call,derived)."""
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
 import time
-from typing import Callable, List
+from typing import Callable, List, Sequence
 
 import numpy as np
 import jax
 
 ROWS: List[str] = []
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def run_fake_device_child(script: str, flags: Sequence[str] = (),
+                          devices: int = 8, capture: bool = False):
+    """Re-exec ``script --child *flags`` on ``devices`` fake CPU devices (a
+    mesh benchmark's measurement half), keeping this process's own device
+    view.  Refuses on a TPU backend: this process then holds the chip, so a
+    child could neither take it nor stand in for it."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{os.path.basename(script)} measures its mesh on {devices} fake "
+            f"CPU devices in a child process; on a TPU host this process "
+            f"holds the chip — run it with JAX_PLATFORMS=cpu, or use "
+            f"chip_smoke.py --chips 4 for the real four-chip mesh")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={devices}").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(ROOT, "src"), ROOT, env.get("PYTHONPATH", "")])
+    return subprocess.run([sys.executable, os.path.abspath(script), "--child",
+                           *flags], env=env, cwd=ROOT,
+                          capture_output=capture, text=capture)
 
 
 def bench(fn: Callable, iters: int = 20, warmup: int = 3) -> float:

@@ -51,8 +51,6 @@ import argparse
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 
 SHARDS = (2, 4, 8)
 T_FULL, NL_FULL, BUCKETS_FULL, ITERS = 16, 8, 1 << 13, 9
@@ -371,16 +369,11 @@ def main() -> None:
         return
     # a device mesh needs >1 device; fork with forced fake devices so the
     # driver (benchmarks/run.py) keeps its real single-device view
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(_ROOT, "src"), _ROOT, env.get("PYTHONPATH", "")])
-    cmd = [sys.executable, os.path.abspath(__file__), "--child"]
-    for flag in ("smoke", "bounded", "skewproof", "replicated"):
-        if getattr(args, flag):
-            cmd.append(f"--{flag}")
-    r = subprocess.run(cmd, env=env, cwd=_ROOT)
+    from benchmarks.common import run_fake_device_child
+    r = run_fake_device_child(
+        __file__, [f"--{flag}" for flag in ("smoke", "bounded", "skewproof",
+                                            "replicated")
+                   if getattr(args, flag)])
     if r.returncode:
         raise RuntimeError(f"distributed_throughput child failed "
                            f"(exit {r.returncode})")

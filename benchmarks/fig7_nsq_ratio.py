@@ -26,6 +26,7 @@ from repro.core import (HashTableConfig, OP_INSERT, OP_SEARCH, bulk_build,
                         engine, init_table, memory_bytes, pack_trace,
                         run_stream)
 from repro.core.perfmodel import plan_geometry, _planner_bucket_tiles
+from repro.kernels import ops as kops
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -102,18 +103,23 @@ def geometry_ab(smoke: bool) -> dict:
                                   jnp.array(vals))
         tab_auto = engine.reconfigure(tab_fixed, cfg_auto)
 
-        def make_fn(tab, cfg, tiles):
+        def make_fn(tab, cfg):
             op_s, kk_s, vv_s = pack_trace(ops, keys, vals, cfg)
             args = (jnp.array(op_s), jnp.array(kk_s), jnp.array(vv_s))
-            fn = jax.jit(lambda t: run_stream(t, *args, fused=True,
-                                              bucket_tiles=tiles,
-                                              binned=True))
+            fn = jax.jit(lambda t: run_stream(t, *args, fused=True))
             return op_s.shape[0], (lambda: fn(tab)[1].found)
 
-        steps_fixed, fn_fixed = make_fn(tab_fixed, cfg_fixed, tiles_fixed)
-        steps_auto, fn_auto = make_fn(tab_auto, cfg_auto, tiles_auto)
-        us = bench_group({"fixed": fn_fixed, "auto": fn_auto},
-                         iters=iters, warmup=1)
+        steps_fixed, fn_fixed = make_fn(tab_fixed, cfg_fixed)
+        steps_auto, fn_auto = make_fn(tab_auto, cfg_auto)
+        # the kernels size their sweep passes from the budget when traced
+        # (in the warmup), so both sides trace under the bench-local one
+        saved = kops.VMEM_TABLE_BUDGET_BYTES
+        kops.VMEM_TABLE_BUDGET_BYTES = budget
+        try:
+            us = bench_group({"fixed": fn_fixed, "auto": fn_auto},
+                             iters=iters, warmup=1)
+        finally:
+            kops.VMEM_TABLE_BUDGET_BYTES = saved
         mops = {name: n_q / t for name, t in us.items()}
         regime = lambda tiles: "resident" if tiles == 1 else "blocked"
         out = {

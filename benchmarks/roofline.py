@@ -13,10 +13,10 @@ max(hlo x chips, model); the ratio model/hlo is reported per cell.
 
 Also reports measured-vs-modeled for the fused stream kernel: every
 BENCH_stream.json row is re-derived from ``perfmodel.stream_modeled_mops``
-(commit-cost + blocked-regime terms) at the benchmark's config, for each
-measured column (scanned ~ serial commit, fused, blocked binned/unbinned).
+(commit-cost + table-traffic terms) at the benchmark's config, for each
+measured column (scanned ~ serial commit, fused).
 Off-TPU the measurement is interpret-mode CPU, so the interesting number is
-the RELATIVE shape (fused/blocked/binned ratios), not the absolute gap —
+the RELATIVE shape (fused/scanned ratios), not the absolute gap —
 both are printed.  The routed distributed stream gets the same treatment
 (BENCH_distributed.json x ``perfmodel.sharded_stream_modeled_mops`` /
 ``replicated_read_mops``), including the 2-D replication A/B with its
@@ -153,8 +153,6 @@ def stream_measured_vs_modeled(path: str = "BENCH_stream.json") -> list:
         "mops_scanned": dict(bucket_tiles=1, vectorized_commit=False,
                              fused=False),
         "mops_fused": dict(bucket_tiles=1),
-        "mops_fused_blocked8": dict(bucket_tiles=8, binned=True),
-        "mops_fused_blocked8_nobinned": dict(bucket_tiles=8, binned=False),
     }
     rows = []
     for r in bench["rows"]:

@@ -10,6 +10,8 @@ def main() -> None:
     # some benchmark mains parse argv (e.g. --smoke); the driver runs them
     # all in full mode, and a stray driver arg must not SystemExit the sweep
     sys.argv = sys.argv[:1]
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (backend_compare, bulk_build,
                             distributed_throughput,
                             fig4_memory, fig5_throughput, fig6_capacity,

@@ -44,7 +44,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -254,15 +253,8 @@ def main() -> None:
         return
     # the sharded mesh needs >1 device; fork with forced fake devices so the
     # driver (benchmarks/run.py) keeps its real single-device view
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(_ROOT, "src"), _ROOT, env.get("PYTHONPATH", "")])
-    cmd = [sys.executable, os.path.abspath(__file__), "--child"]
-    if args.smoke:
-        cmd.append("--smoke")
-    r = subprocess.run(cmd, env=env, cwd=_ROOT)
+    from benchmarks.common import run_fake_device_child
+    r = run_fake_device_child(__file__, ["--smoke"] if args.smoke else [])
     if r.returncode:
         raise RuntimeError(f"serve_latency child failed (exit {r.returncode})")
 

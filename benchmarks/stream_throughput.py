@@ -1,22 +1,12 @@
-"""Fused-stream scaling: MOPS vs stream length T, plus the bucket-blocked
-HBM-resident regime.
+"""Fused-stream scaling: MOPS vs stream length T.
 
 The fused xor_stream kernel amortizes one kernel launch over the whole
 ``[T, N]`` stream while the scanned path dispatches probe+commit per step —
 so the fused/scanned ratio should GROW with T (the FPGA pipeline analogy:
 longer bursts keep the PE array full).  The default ``fused`` column is the
-single-pass in-kernel scan (off-TPU ``binned`` defaults True, so even the
-unblocked ``bucket_tiles == 1`` kernel runs its T steps inside ONE grid
-iteration); ``fused_stepgrid`` pins ``binned=False`` — the per-step
-``grid=(1, T)`` layout the scan collapsed — as its paired A/B baseline.
-The ``blocked`` rows pin
-``bucket_tiles=8`` so the same table runs the bucket-blocked kernel,
-exercising the HBM-resident code path — in BOTH dispatch layouts
-(DESIGN.md §3.1): ``blocked8`` is the tile-binned dispatch (sorted lanes,
-windowed sweep, the default), ``blocked8_nobinned`` the mask-all-N baseline
-it replaced.  ``--binned`` / ``--no-binned`` restrict the A/B to one side
-(CI runs both); the default measures all columns in ONE paired round-robin
-group, so the binned-over-unbinned ratio is drift-immune.  The table is
+VMEM-resident kernel (one sweep pass: its T steps run inside ONE grid
+iteration; tables past the VMEM budget sweep in passes, DESIGN.md §3.1 —
+chip_smoke.py drives that regime at its real size).  The table is
 bulk-prepopulated with the stream's key set (``engine.bulk_build``) so
 search lanes exercise the hit path, not the empty-table miss path.  Emits
 ``BENCH_stream.json`` (full mode only; ``--smoke`` is the CI harness
@@ -46,10 +36,9 @@ TABLE = dict(buckets=1 << 12, slots=4, replicate_reads=False,
              stagger_slots=True)
 
 
-def run_t(steps: int, qpp: int = QPP, iters: int = ITERS,
-          blocked_tiles: int = 8, binned_variants=(True, False)):
-    """scanned vs fused vs bucket-blocked-fused (binned and/or unbinned) on
-    identical stimulus, timed round-robin (drift-immune paired comparison)."""
+def run_t(steps: int, qpp: int = QPP, iters: int = ITERS):
+    """scanned vs fused on identical stimulus, timed round-robin
+    (drift-immune paired comparison)."""
     cfg = HashTableConfig(p=P, k=P, queries_per_pe=qpp, backend="pallas",
                           **TABLE)
     tab = init_table(cfg, jax.random.key(0))
@@ -60,29 +49,16 @@ def run_t(steps: int, qpp: int = QPP, iters: int = ITERS,
     # empty-table variant measured only the miss path for every search lane
     tab, _ = bulk_build(tab, keys_j.reshape(-1, cfg.key_words),
                         vals_j.reshape(-1, cfg.val_words))
-    jfn = jax.jit(run_stream, static_argnames=("backend", "fused",
-                                               "bucket_tiles", "binned"))
+    jfn = jax.jit(run_stream, static_argnames=("backend", "fused"))
 
     fns = {
         "scanned": functools.partial(jfn, tab, ops_j, keys_j, vals_j,
                                      fused=False),
-        # the default unblocked kernel: off-TPU this is the single-pass
-        # in-kernel scan (grid == ONE iteration for all T steps)
+        # the default unblocked kernel: one sweep pass, all T steps inside
+        # ONE grid iteration
         "fused": functools.partial(jfn, tab, ops_j, keys_j, vals_j,
                                    fused=True),
-        # per-step-grid A/B baseline at bucket_tiles == 1: same VMEM-resident
-        # aliased tiles, but grid=(1, T) re-enters the kernel once per step
-        "fused_stepgrid": functools.partial(jfn, tab, ops_j, keys_j, vals_j,
-                                            fused=True, binned=False),
     }
-    # pinned bucket_tiles exercises the >VMEM blocked regime without
-    # allocating a table beyond the budget (the knob is jit-static, so the
-    # cache keeps these distinct from the auto-tiled fused variant)
-    for binned in binned_variants:
-        name = f"blocked{blocked_tiles}" + ("" if binned else "_nobinned")
-        fns[name] = functools.partial(jfn, tab, ops_j, keys_j, vals_j,
-                                      fused=True, bucket_tiles=blocked_tiles,
-                                      binned=binned)
     us = bench_group(fns, iters=iters, warmup=2)
     return {name: steps * N / t for name, t in us.items()}   # MOPS
 
@@ -91,49 +67,21 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes, 1 iter, no JSON — CI harness check")
-    ap.add_argument("--binned", action=argparse.BooleanOptionalAction,
-                    default=None,
-                    help="A/B: restrict the blocked rows to the tile-binned "
-                         "dispatch (--binned) or the mask-all-N baseline "
-                         "(--no-binned); default measures both")
     args = ap.parse_args()
     ts, qpp, iters = ((2,), 2, 1) if args.smoke else (TS, QPP, ITERS)
-    variants = (True, False) if args.binned is None else (args.binned,)
 
     results = {"host_backend": jax.default_backend(),
                "interpret_mode": jax.default_backend() != "tpu",
                "p": P, "qpp": qpp, "iters": iters, "table": TABLE,
                "stat": "paired best-of-N (bench_group round-robin)",
-               "notes": "blocked8 pays ONE full-replica sweep (tile in+out) "
-                        "per stream regardless of T (perfmodel "
-                        "stream_modeled_mops sweep term), so short streams "
-                        "(T=2) are sweep-dominated; the unblocked kernel's "
-                        "aliased in-place tiles pay no sweep.",
                "rows": []}
     for steps in ts:
-        mops = run_t(steps, qpp=qpp, iters=iters, binned_variants=variants)
+        mops = run_t(steps, qpp=qpp, iters=iters)
         scanned, fused = mops["scanned"], mops["fused"]
-        stepgrid = mops["fused_stepgrid"]
         rec = {"steps": steps, "mops_scanned": scanned, "mops_fused": fused,
-               "fused_over_scanned": fused / scanned,
-               "mops_fused_stepgrid": stepgrid,
-               "scan_over_stepgrid": fused / stepgrid}
+               "fused_over_scanned": fused / scanned}
         derived = (f"scanned_MOPS={scanned:.2f};fused_MOPS={fused:.2f};"
-                   f"fused_over_scanned={fused / scanned:.3f};"
-                   f"stepgrid_MOPS={stepgrid:.2f};"
-                   f"scan_over_stepgrid={fused / stepgrid:.2f}")
-        if "blocked8" in mops:
-            rec["mops_fused_blocked8"] = mops["blocked8"]
-            rec["blocked8_over_fused"] = mops["blocked8"] / fused
-            derived += f";fused_blocked8_MOPS={mops['blocked8']:.2f}"
-        if "blocked8_nobinned" in mops:
-            rec["mops_fused_blocked8_nobinned"] = mops["blocked8_nobinned"]
-            derived += (f";fused_blocked8_nobinned_MOPS="
-                        f"{mops['blocked8_nobinned']:.2f}")
-        if "blocked8" in mops and "blocked8_nobinned" in mops:
-            rec["binned_over_nobinned"] = (mops["blocked8"]
-                                           / mops["blocked8_nobinned"])
-            derived += f";binned_over_nobinned={rec['binned_over_nobinned']:.2f}"
+                   f"fused_over_scanned={fused / scanned:.3f}")
         results["rows"].append(rec)
         row(f"stream_throughput_T{steps}", 0.0, derived)
     if args.smoke:

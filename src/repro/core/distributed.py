@@ -72,7 +72,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import engine as _engine
 from repro.core.config import HashTableConfig
@@ -102,7 +102,7 @@ def make_ht_mesh(n_devices: int | None = None, axis: str = "ht",
     if n_devices is None and replica_groups is not None:
         n_devices = sum(replica_groups)
     n = n_devices or len(devs)
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def init_distributed_table(cfg: HashTableConfig, rng: jax.Array,
@@ -152,8 +152,6 @@ def init_distributed_table(cfg: HashTableConfig, rng: jax.Array,
 def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
                             axis: str = "ht",
                             fused: bool | None = None,
-                            bucket_tiles: int | None = None,
-                            binned: bool | None = None,
                             router: str | None = None,
                             routed_slack: int | None = None):
     """Build the multi-device stream.
@@ -162,8 +160,8 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
     step tensors, queries sharded over ``axis`` (``N = n_dev * n_local``).
     ``cfg.shards`` selects the mapping (module docstring): ``n_dev`` =
     bucket-sharded route+stream+return, ``1`` = the replicated per-step
-    all-gather oracle scanned over T.  ``fused``/``bucket_tiles``/``binned``
-    pin the sharded local-stream regime exactly as in ``engine.run_stream``;
+    all-gather oracle scanned over T.  ``fused`` pins the sharded
+    local-stream regime exactly as in ``engine.run_stream``;
     ``router``/``routed_slack`` override ``cfg.router``/``cfg.routed_slack``
     for the sharded mapping.  The skew-proof/replicated callables are jitted
     end to end; the bounded callable is a host wrapper (measurement pass +
@@ -174,7 +172,6 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
     pass 1), ``.dispatch`` (pass 2 under an explicit plan), plus
     ``.router``/``.cfg``/``.slack`` for feature detection (DESIGN.md §4).
     """
-    from jax.experimental.shard_map import shard_map
     n_dev = mesh.shape[axis]
     if cfg.shards != 1:
         cfg.validate_mesh(n_dev, axis)
@@ -202,22 +199,22 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
 
         table_spec = XorHashTable(P(), P(), P(), P(), cfg)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_stream, mesh=mesh,
             in_specs=(table_spec, P(None, axis), P(None, axis),
                       P(None, axis)),
             out_specs=(table_spec, P(None, axis)),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn)
 
     table_spec = XorHashTable(P(), P(None, None, axis),
                               P(None, None, axis), P(None, None, axis), cfg)
-    shmap = lambda body: jax.jit(shard_map(
+    shmap = lambda body: jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(table_spec, P(None, axis), P(None, axis), P(None, axis)),
         out_specs=(table_spec, P(None, axis)),
-        check_rep=False,
+        check_vma=False,
     ))
 
     # device d's partition start: shard_of[d] (2-D grouped mapping) or d
@@ -245,7 +242,7 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
                 cfg, table.store_keys, table.store_vals, table.store_valid,
                 pe, r_bkt, r_op, r_key, r_val,
                 bucket_base=_shard_of[d] * cfg.local_buckets,
-                fused=fused, bucket_tiles=bucket_tiles, binned=binned)
+                fused=fused)
             f_l, ok_l, v_l = _engine.inverse_route(axis, tgt, found, ok, value)
             table = XorHashTable(table.q_masks, sk, sv, sb, cfg)
             return table, StepResults(found=f_l, value=v_l, ok=ok_l,
@@ -328,7 +325,7 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
                 cfg, table.store_keys, table.store_vals, table.store_valid,
                 pe, r_bkt, r_op, r_key, r_val,
                 bucket_base=_shard_of[d] * cfg.local_buckets,
-                fused=fused, bucket_tiles=bucket_tiles, binned=binned)
+                fused=fused)
             f_l, ok_l, v_l = _engine.inverse_route_bounded(
                 axis, carry, found, ok, value)
             table = XorHashTable(table.q_masks, sk, sv, sb, cfg)
@@ -402,8 +399,7 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
 
 def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
                                 axis: str = "ht", router: str | None = None,
-                                backend: str | None = None,
-                                bucket_tiles: int | None = None):
+                                backend: str | None = None):
     """Bucket-sharded bulk build (DESIGN.md §3.2): route records to their
     owner shards with the existing exchange, then run ONE local
     count-then-place sweep per partition.
@@ -423,7 +419,6 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
     Spill/placement flags ride the inverse exchange home, so the report
     keeps the caller's ``[T, N]`` record layout.
     """
-    from jax.experimental.shard_map import shard_map
     n_dev = mesh.shape[axis]
     cfg.validate_mesh(n_dev, axis)
     router = cfg.router if router is None else router
@@ -435,12 +430,12 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
 
     table_spec = XorHashTable(P(), P(None, None, axis),
                               P(None, None, axis), P(None, None, axis), cfg)
-    shmap = lambda body: jax.jit(shard_map(
+    shmap = lambda body: jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(table_spec, P(None, axis), P(None, axis), P(None, axis)),
         out_specs=(table_spec, P(None, axis), P(None, axis), P(None, axis),
                    P(None, axis), P(None, axis), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
     def _local_sweep(table, r_bkt, r_key, r_val, r_live, d):
@@ -455,7 +450,7 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
             _engine.bulk_place_records(
                 cfg, table.store_keys, table.store_vals, table.store_valid,
                 fb, fk, fv, fl, bucket_base=_shard_of[d] * cfg.local_buckets,
-                backend=backend, bucket_tiles=bucket_tiles)
+                backend=backend)
         shape = r_bkt.shape
         return (sk, sv, sb, placed.reshape(shape), spilled.reshape(shape),
                 slot.reshape(shape), first.reshape(shape),
@@ -558,13 +553,11 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
 
 
 def make_distributed_compact(mesh: Mesh, cfg: HashTableConfig,
-                             axis: str = "ht", backend: str | None = None,
-                             bucket_tiles: int | None = None):
+                             axis: str = "ht", backend: str | None = None):
     """Shard-local compaction: every owner rewrites its own partition with
     the count-then-place sweep (records already live at their owners, so no
     exchange is needed).  Returns ``f(table) -> table`` — jitted end to
     end; same semantics per partition as ``engine.compact``."""
-    from jax.experimental.shard_map import shard_map
     n_dev = mesh.shape[axis]
     cfg.validate_mesh(n_dev, axis)
     table_spec = XorHashTable(P(), P(None, None, axis),
@@ -573,24 +566,21 @@ def make_distributed_compact(mesh: Mesh, cfg: HashTableConfig,
     def body(table):
         local = XorHashTable(table.q_masks, table.store_keys,
                              table.store_vals, table.store_valid, cfg)
-        return _engine.compact(local, backend=backend,
-                               bucket_tiles=bucket_tiles)
+        return _engine.compact(local, backend=backend)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(table_spec,),
-                             out_specs=table_spec, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(table_spec,),
+                                 out_specs=table_spec, check_vma=False))
 
 
 def make_distributed_reconfigure(mesh: Mesh, cfg: HashTableConfig,
                                  new_cfg: HashTableConfig, axis: str = "ht",
-                                 backend: str | None = None,
-                                 bucket_tiles: int | None = None):
+                                 backend: str | None = None):
     """Shard-local geometry migration: every owner re-places its own
     partition's records into the new ``(replicas, k)`` store shape (records
     stay at their owners — the bucket axis is untouched — so like
     :func:`make_distributed_compact` no exchange is needed).  Returns
     ``f(table) -> table`` holding ``new_cfg``-shaped partitions; same
     record-set contract per partition as ``engine.reconfigure``."""
-    from jax.experimental.shard_map import shard_map
     n_dev = mesh.shape[axis]
     cfg.validate_mesh(n_dev, axis)
     new_cfg.validate_mesh(n_dev, axis)
@@ -602,11 +592,10 @@ def make_distributed_reconfigure(mesh: Mesh, cfg: HashTableConfig,
     def body(table):
         local = XorHashTable(table.q_masks, table.store_keys,
                              table.store_vals, table.store_valid, cfg)
-        return _engine.reconfigure(local, new_cfg, backend=backend,
-                                   bucket_tiles=bucket_tiles)
+        return _engine.reconfigure(local, new_cfg, backend=backend)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                             out_specs=out_spec, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                                 out_specs=out_spec, check_vma=False))
 
 
 class DistributedResize:
@@ -646,8 +635,6 @@ class DistributedResize:
 def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
                             new_buckets: int, axis: str = "ht",
                             fused: bool | None = None,
-                            bucket_tiles: int | None = None,
-                            binned: bool | None = None,
                             backend: str | None = None) -> DistributedResize:
     """Build the sharded online-resize driver (class docstring above;
     DESIGN.md §6).  ``new_buckets`` is the successor's GLOBAL bucket count
@@ -655,7 +642,6 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
     added index bits all land in the per-shard local range).  The stream and
     migrate entry points are jitted shard_maps with the watermark riding as
     a traced scalar — migration progress never recompiles."""
-    from jax.experimental.shard_map import shard_map
     n_dev = mesh.shape[axis]
     if cfg.shards == 1:
         raise ValueError(
@@ -727,12 +713,12 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
             cfg, pred.store_keys, pred.store_vals, pred.store_valid,
             pe, r_bo, jnp.where(mig, 0, r_op), r_key, r_val,
             bucket_base=_shard_of[d] * bl_old,
-            fused=fused, bucket_tiles=bucket_tiles, binned=binned)
+            fused=fused)
         sk, sv, sb, f_s, ok_s, v_s = _engine.run_stream_local(
             new_cfg, succ.store_keys, succ.store_vals, succ.store_valid,
             pe, r_bn, jnp.where(mig, r_op, 0), r_key, r_val,
             bucket_base=_shard_of[d] * new_cfg.local_buckets,
-            fused=fused, bucket_tiles=bucket_tiles, binned=binned)
+            fused=fused)
         found = jnp.where(mig, f_s, f_p)
         ok = jnp.where(mig, ok_s, ok_p)
         value = jnp.where(mig[..., None], v_s, v_p)
@@ -742,12 +728,12 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
         return pred, succ, StepResults(found=f_l, value=v_l, ok=ok_l,
                                        bucket=b_new)
 
-    _stream_jit = jax.jit(shard_map(
+    _stream_jit = jax.jit(jax.shard_map(
         _local_stream, mesh=mesh,
         in_specs=(pred_spec, succ_spec, P(), P(None, axis), P(None, axis),
                   P(None, axis)),
         out_specs=(pred_spec, succ_spec, P(None, axis)),
-        check_rep=False,
+        check_vma=False,
     ))
 
     def stream(state, ops, keys, vals):
@@ -778,7 +764,7 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
                 new_cfg, succ.store_keys, succ.store_vals, succ.store_valid,
                 b_new, keys, vals, live,
                 bucket_base=_shard_of[d] * new_cfg.local_buckets,
-                backend=backend, bucket_tiles=bucket_tiles)
+                backend=backend)
             zero = lambda x: jax.lax.dynamic_update_slice_in_dim(
                 x, jnp.zeros(x.shape[:2] + (n,) + x.shape[3:], x.dtype),
                 w, axis=2)
@@ -788,9 +774,9 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
             succ = XorHashTable(succ.q_masks, sk, sv, sb, new_cfg)
             return pred, succ
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(pred_spec, succ_spec, P()),
-            out_specs=(pred_spec, succ_spec), check_rep=False))
+            out_specs=(pred_spec, succ_spec), check_vma=False))
 
     def migrate(state, n_buckets: int):
         """Every shard migrates its own local rows ``[w, w + n)`` — one

@@ -326,7 +326,7 @@ def commit_jnp(store_keys, store_vals, store_valid, port, bucket, slot,
 # Backends
 #
 # StreamBackend protocol: in addition to probe/commit, a backend may expose
-#   run_stream(table, ops, keys, vals, bucket_tiles=None)
+#   run_stream(table, ops, keys, vals)
 #       -> (table', StepResults[T, N])
 # processing a whole [T, N] query stream at once.  The jnp implementation is
 # the scanned per-step oracle; the pallas implementation is the fused
@@ -379,17 +379,14 @@ class JnpBackend:
         return commit_records(table, encode_records(pr, plan))
 
     def run_stream(self, table: XorHashTable, ops: jnp.ndarray,
-                   keys: jnp.ndarray, vals: jnp.ndarray,
-                   bucket_tiles: Optional[int] = None,
-                   binned: Optional[bool] = None
+                   keys: jnp.ndarray, vals: jnp.ndarray
                    ) -> Tuple[XorHashTable, StepResults]:
-        # bucket_tiles/binned are fused-kernel knobs; the scan has no tiling
         return _scan_stream(table, ops, keys, vals, backend=self.name)
 
     def bulk_place(self, plane_k, plane_v, plane_b, w_bucket, w_slot,
-                   keys, vals, bucket_tiles: Optional[int] = None):
+                   keys, vals):
         """Plaintext placement of pre-planned records into the port-0 plane
-        (``[B, S, W*]``) — the jnp oracle for the binned placement kernel.
+        (``[B, S, W*]``) — the jnp oracle for the placement kernel.
         Targets are pairwise distinct by construction (plan_bulk_build), so
         a plain masked scatter needs no supersession pass.  The three planes
         scatter as ONE packed ``[B, S, Wk+Wv+1]`` write: a scatter's cost is
@@ -431,12 +428,10 @@ class PallasBackend:
         plan = mutation_plan(table.cfg, batch, pr) if plan is None else plan
         # Replicas are byte-identical, so one encoding serves every replica:
         # compute it ONCE from the ProbeResult rem basis the probe already
-        # produced, leaving the per-replica kernel grid only the masked
-        # scatter (instead of R identical gather+XOR-tree encodes).
+        # produced, leaving the kernel only the masked stores (instead of R
+        # identical gather+XOR-tree encodes); ops.xor_commit falls back to
+        # the jnp scatter past the VMEM budget.
         rec = encode_records(pr, plan)
-        if kops.replica_bytes(table.store_keys, table.store_vals,
-                              table.store_valid) > kops.VMEM_TABLE_BUDGET_BYTES:
-            return commit_records(table, rec)
         sk, sv, sb = kops.xor_commit(
             table.store_keys, table.store_vals, table.store_valid,
             rec["port"], rec["bucket"], rec["slot"],
@@ -444,22 +439,14 @@ class PallasBackend:
         return XorHashTable(table.q_masks, sk, sv, sb, table.cfg)
 
     def run_stream(self, table: XorHashTable, ops: jnp.ndarray,
-                   keys: jnp.ndarray, vals: jnp.ndarray,
-                   bucket_tiles: Optional[int] = None,
-                   binned: Optional[bool] = None
+                   keys: jnp.ndarray, vals: jnp.ndarray
                    ) -> Tuple[XorHashTable, StepResults]:
         """The fused stream kernel: one pallas_call for the whole [T, N]
         stream, table VMEM-persistent across steps.  Unlike the per-step
         kernels this path does NOT fall back to jnp past the VMEM budget —
-        HBM-resident tables run compiled Pallas via bucket-axis blocking
-        (``bucket_tiles=None`` sizes the tiling from the VMEM budget; pass it
-        explicitly to pin the regime — NB the budget is read at trace time,
-        so callers that re-jit this function must pass ``bucket_tiles``
-        rather than vary the budget, or the jit cache will conflate them).
-        ``binned`` picks the blocked regime's dispatch (DESIGN.md §3.1):
-        None defaults per backend (tile-binned off-TPU, block-pipelined on
-        TPU — kernels.ops.xor_stream), False pins the mask-all-N baseline,
-        True pins the binned dispatch.
+        HBM-resident tables run compiled Pallas in VMEM-sized sweep passes
+        (``kernels.ops.stream_bucket_tiles``; the budget is read at trace
+        time).
 
         Replicas are byte-identical at step boundaries (commit writes all of
         them), so the kernel streams over replica 0 and the result is
@@ -474,13 +461,10 @@ class PallasBackend:
         legal = (pe < cfg.k).astype(jnp.int32)
         bucket = kops.h3_hash(keys.reshape(T * N, cfg.key_words),
                               table.q_masks).reshape(T, N)
-        tiles = bucket_tiles if bucket_tiles is not None else \
-            kops.stream_bucket_tiles(table.store_keys, table.store_vals,
-                                     table.store_valid)
         sk, sv, sb, found, ok, value = kops.xor_stream(
             bucket, port, legal, ops, keys, vals, table.store_keys[0],
-            table.store_vals[0], table.store_valid[0], bucket_tiles=tiles,
-            stagger=cfg.stagger_slots, binned=binned)
+            table.store_vals[0], table.store_valid[0],
+            stagger=cfg.stagger_slots)
         R = table.store_keys.shape[0]
         new_table = XorHashTable(
             table.q_masks,
@@ -491,14 +475,13 @@ class PallasBackend:
                                       bucket=bucket)
 
     def bulk_place(self, plane_k, plane_v, plane_b, w_bucket, w_slot,
-                   keys, vals, bucket_tiles: Optional[int] = None):
-        """The binned placement kernel (kernels.bulk_place): records sorted
-        by bucket tile, one residency-sized span load/store per pass — one
-        plane round trip for the whole build.  Interpret mode off-TPU."""
+                   keys, vals):
+        """The placement kernel (kernels.bulk_place): records sorted by
+        bucket, each touched bucket group moved in and out of VMEM once.
+        Interpret mode off-TPU."""
         from repro.kernels import ops as kops
         return kops.bulk_place(w_bucket, w_slot, keys, vals,
-                               plane_k, plane_v, plane_b,
-                               bucket_tiles=bucket_tiles)
+                               plane_k, plane_v, plane_b)
 
 
 _BACKENDS: Dict[str, object] = {}
@@ -547,8 +530,8 @@ def resolve_backend(cfg: HashTableConfig, table: Optional[XorHashTable] = None):
     from repro.kernels import ops as kops
     name = _resolve_name(cfg)
     if name == "pallas" and table is not None:
-        if kops.replica_bytes(table.store_keys, table.store_vals,
-                              table.store_valid) > kops.VMEM_TABLE_BUDGET_BYTES:
+        if not kops.fits_vmem(table.store_keys, table.store_vals,
+                              table.store_valid):
             name = "jnp"
     return get_backend(name)
 
@@ -586,9 +569,7 @@ def step(table: XorHashTable, batch: QueryBatch,
 
 def run_stream(table: XorHashTable, ops: jnp.ndarray, keys: jnp.ndarray,
                vals: jnp.ndarray, backend: Optional[str] = None,
-               fused: Optional[bool] = None,
-               bucket_tiles: Optional[int] = None,
-               binned: Optional[bool] = None
+               fused: Optional[bool] = None
                ) -> Tuple[XorHashTable, StepResults]:
     """Stream a whole ``[T, N]`` query trace through the engine seam.
 
@@ -601,11 +582,7 @@ def run_stream(table: XorHashTable, ops: jnp.ndarray, keys: jnp.ndarray,
       False  force the scanned per-step path (the semantic oracle).
 
     Note the fused path does not use :func:`resolve_backend`'s VMEM fallback:
-    tables beyond the budget run compiled Pallas with bucket-axis blocking —
-    auto-sized from the VMEM budget, or pinned via ``bucket_tiles``.
-    ``binned`` picks the blocked regime's dispatch: None defaults per
-    backend (tile-binned off-TPU — kernels.ops.xor_stream), ``False`` is
-    the mask-all-N A/B baseline, ``True`` pins the binned dispatch.
+    tables beyond the budget run compiled Pallas in VMEM-sized sweep passes.
     """
     cfg = table.cfg
     if ops.ndim != 2 or ops.shape[1] != cfg.queries_per_step:
@@ -613,14 +590,10 @@ def run_stream(table: XorHashTable, ops: jnp.ndarray, keys: jnp.ndarray,
                          f"{cfg.queries_per_step}]")
     name = _resolve_name(cfg, backend)
     if fused is True:
-        return get_backend("pallas").run_stream(table, ops, keys, vals,
-                                                bucket_tiles=bucket_tiles,
-                                                binned=binned)
+        return get_backend("pallas").run_stream(table, ops, keys, vals)
     if fused is False:
         return _scan_stream(table, ops, keys, vals, backend=name)
-    return get_backend(name).run_stream(table, ops, keys, vals,
-                                        bucket_tiles=bucket_tiles,
-                                        binned=binned)
+    return get_backend(name).run_stream(table, ops, keys, vals)
 
 
 # ---------------------------------------------------------------------------
@@ -718,9 +691,7 @@ def run_stream_local(cfg: HashTableConfig, store_keys: jnp.ndarray,
                      pe: jnp.ndarray, bucket: jnp.ndarray, ops: jnp.ndarray,
                      keys: jnp.ndarray, vals: jnp.ndarray, *,
                      bucket_base, backend: Optional[str] = None,
-                     fused: Optional[bool] = None,
-                     bucket_tiles: Optional[int] = None,
-                     binned: Optional[bool] = None):
+                     fused: Optional[bool] = None):
     """Stream ``[T, Nr]`` routed queries through ONE bucket-shard partition.
 
     ``store_*`` ``[R, k, local_buckets, S, W]`` hold the global bucket range
@@ -731,8 +702,7 @@ def run_stream_local(cfg: HashTableConfig, store_keys: jnp.ndarray,
     step-invariant) or ``[T, Nr]`` (bounded routing: rows are re-binned
     mixtures, so the origin varies per step).  On the pallas backend this is
     the fused ``xor_stream`` kernel with the bucket-base offset (the
-    bucket-tiling and tile-binned dispatch paths reused unchanged —
-    ``binned`` as in :func:`run_stream`); elsewhere the scanned jnp oracle
+    sweep-pass sizing reused unchanged); elsewhere the scanned jnp oracle
     with the same partition masking.  Returns ``(store_keys', store_vals',
     store_valid', found, ok, value)``.
     """
@@ -745,12 +715,10 @@ def run_stream_local(cfg: HashTableConfig, store_keys: jnp.ndarray,
     if use_fused:
         from repro.kernels import ops as kops
         legal = (pe < k).astype(jnp.int32)
-        tiles = bucket_tiles if bucket_tiles is not None else \
-            kops.stream_bucket_tiles(store_keys, store_vals, store_valid)
         sk, sv, sb, found, ok, value = kops.xor_stream(
             bucket, port, legal, ops, keys, vals, store_keys[0],
-            store_vals[0], store_valid[0], bucket_tiles=tiles,
-            stagger=cfg.stagger_slots, bucket_base=base, binned=binned)
+            store_vals[0], store_valid[0], stagger=cfg.stagger_slots,
+            bucket_base=base)
         bc = lambda x: jnp.broadcast_to(x[None], (R,) + x.shape)
         return bc(sk), bc(sv), bc(sb), found, ok, value
 
@@ -1484,14 +1452,17 @@ def plan_bulk_build(keys: jnp.ndarray, vals: jnp.ndarray, bucket: jnp.ndarray,
               occurrence of such a key is reported spilled (its streamed
               insert would find no match and no open slot -> ok=False).
 
-    ``host`` picks the implementation: the direct numpy pass (off-TPU
-    default — the arrays already live in host memory and host sorts are ~4x
-    faster than XLA:CPU's), or the pure-XLA two-lexsort path (the TPU
-    default — no device->host round trip).  Both are field-for-field
-    bit-exact.  The host pass needs CONCRETE arrays, so under a trace
-    (jit / scan / shard_map) the XLA path always runs — callers that want
-    the host plan keep ``bulk_build`` itself out of ``jax.jit`` and let its
-    internally-jitted placement stage do the compiling.  (A
+    ``host`` picks the implementation: the direct numpy pass (the default
+    for concrete arrays on every backend — host sorts are ~4x faster than
+    XLA:CPU's, and compiling the two-lexsort plan for a v5e took 7-9
+    minutes per record count on an 8-core host, against ~17 s for the
+    numpy pass over 10M two-word keys on the same host), or the pure-XLA
+    two-lexsort path (``host=False``).
+    Both are field-for-field bit-exact.  The host pass needs CONCRETE
+    arrays, so under a trace (jit / scan / shard_map) the XLA path always
+    runs — callers that want the host plan keep ``bulk_build`` itself out
+    of ``jax.jit`` and let its internally-jitted placement stage do the
+    compiling.  (A
     ``jax.pure_callback`` bridge was tried and abandoned: XLA:CPU executes
     the callback on the intra-op pool and ``pure_callback_impl``'s
     ``device_put`` of large operands deadlocks against it.)
@@ -1510,11 +1481,9 @@ def plan_bulk_build(keys: jnp.ndarray, vals: jnp.ndarray, bucket: jnp.ndarray,
                     slot=z, placed=zb, spilled=zb, first=zb,
                     max_load=jnp.zeros((), jnp.int32))
     live = jnp.ones((n,), jnp.bool_) if live is None else live
-    if host is None:
-        host = jax.default_backend() != "tpu"
     tracing = any(isinstance(x, jax.core.Tracer)
                   for x in (keys, vals, bucket, live))
-    if host and not tracing:
+    if host is not False and not tracing:
         # numpy outputs flow straight into the jitted placement call (its
         # implicit device_put) — eagerly wrapping them here would just add
         # eight dispatches
@@ -1569,10 +1538,9 @@ def plan_bulk_build(keys: jnp.ndarray, vals: jnp.ndarray, bucket: jnp.ndarray,
         max_load=jnp.max(jnp.where(is_rep, rank + 1, 0)).astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("backend_name", "bucket_tiles"))
+@functools.partial(jax.jit, static_argnames=("backend_name",))
 def _place_into_stores(store_keys, store_vals, store_valid, w_bucket, w_slot,
-                       keys, val_w, *, backend_name: str,
-                       bucket_tiles: Optional[int] = None):
+                       keys, val_w, *, backend_name: str):
     """The device half of a bulk placement: scatter the planned records into
     the port-0 plane and broadcast it to every replica.  Jitted so the
     eager host-planned path (``bulk_build`` outside ``jax.jit``) pays one
@@ -1580,7 +1548,7 @@ def _place_into_stores(store_keys, store_vals, store_valid, w_bucket, w_slot,
     be = get_backend(backend_name)
     pk, pv, pb = be.bulk_place(
         store_keys[0, 0], store_vals[0, 0], store_valid[0, 0],
-        w_bucket, w_slot, keys, val_w, bucket_tiles=bucket_tiles)
+        w_bucket, w_slot, keys, val_w)
     # every record writes port 0 of every replica (replica invariant)
     return (store_keys.at[:, 0].set(pk), store_vals.at[:, 0].set(pv),
             store_valid.at[:, 0].set(pb))
@@ -1589,8 +1557,7 @@ def _place_into_stores(store_keys, store_vals, store_valid, w_bucket, w_slot,
 def bulk_place_records(cfg: HashTableConfig, store_keys, store_vals,
                        store_valid, bucket, keys, vals,
                        live: Optional[jnp.ndarray] = None, *,
-                       bucket_base=0, backend: Optional[str] = None,
-                       bucket_tiles: Optional[int] = None):
+                       bucket_base=0, backend: Optional[str] = None):
     """Count-then-place a flat record batch into (a partition of) empty
     stores — the raw-array core shared by :func:`bulk_build`,
     :func:`compact` and the shard_map distributed builder.
@@ -1610,16 +1577,14 @@ def bulk_place_records(cfg: HashTableConfig, store_keys, store_vals,
                            buckets=Bl, slots=store_keys.shape[3])
     sk, sv, sb = _place_into_stores(
         store_keys, store_vals, store_valid, plan["w_bucket"], plan["w_slot"],
-        keys, plan["val_w"], backend_name=_resolve_name(cfg, backend),
-        bucket_tiles=bucket_tiles)
+        keys, plan["val_w"], backend_name=_resolve_name(cfg, backend))
     return (sk, sv, sb, plan["placed"], plan["spilled"], plan["slot"],
             plan["first"], plan["max_load"])
 
 
 def bulk_build(table: XorHashTable, keys: jnp.ndarray, vals: jnp.ndarray,
                live: Optional[jnp.ndarray] = None,
-               backend: Optional[str] = None,
-               bucket_tiles: Optional[int] = None
+               backend: Optional[str] = None
                ) -> Tuple[XorHashTable, BulkBuildReport]:
     """Construct table state from a flat record batch in O(1) sweeps.
 
@@ -1629,8 +1594,7 @@ def bulk_build(table: XorHashTable, keys: jnp.ndarray, vals: jnp.ndarray,
     record per step (the serialized-insert oracle — tests/test_bulk_build).
     Intra-batch duplicate keys resolve last-wins; bucket overflow degrades
     to per-record spill reporting (``report.spilled``), never a silent
-    drop.  ``backend`` as in :func:`run_stream`; ``bucket_tiles`` pins the
-    placement kernel's sweep-pass count (auto-sized from the VMEM budget).
+    drop.  ``backend`` as in :func:`run_stream`.
     """
     cfg = table.cfg
     keys = jnp.asarray(keys).astype(jnp.uint32).reshape(-1, cfg.key_words)
@@ -1650,7 +1614,7 @@ def bulk_build(table: XorHashTable, keys: jnp.ndarray, vals: jnp.ndarray,
         bucket = _h3_jnp(keys, table.q_masks)
     sk, sv, sb, placed, spilled, slot, first, max_load = bulk_place_records(
         cfg, table.store_keys, table.store_vals, table.store_valid,
-        bucket, keys, vals, live, backend=name, bucket_tiles=bucket_tiles)
+        bucket, keys, vals, live, backend=name)
     report = BulkBuildReport(bucket=bucket.astype(jnp.int32), slot=slot,
                              placed=placed, spilled=spilled, first=first,
                              max_load=max_load)
@@ -1671,8 +1635,8 @@ def extract_records(table: XorHashTable):
             jnp.repeat(jnp.arange(B, dtype=jnp.int32), S))
 
 
-def compact(table: XorHashTable, backend: Optional[str] = None,
-            bucket_tiles: Optional[int] = None) -> XorHashTable:
+def compact(table: XorHashTable, backend: Optional[str] = None
+            ) -> XorHashTable:
     """Rewrite a fragmented table into dense slot occupancy: the bulk-build
     sweep run over the table's own occupied slots.  Every live record
     survives at its bucket (slots densify to ``0..count-1`` in slot order),
@@ -1685,7 +1649,7 @@ def compact(table: XorHashTable, backend: Optional[str] = None,
         cfg, jnp.zeros_like(table.store_keys),
         jnp.zeros_like(table.store_vals),
         jnp.zeros_like(table.store_valid),
-        bucket, keys, vals, live, backend=backend, bucket_tiles=bucket_tiles)
+        bucket, keys, vals, live, backend=backend)
     return XorHashTable(table.q_masks, sk, sv, sb, cfg)
 
 
@@ -1822,8 +1786,7 @@ def resize_buckets(b_old: jnp.ndarray, extra: jnp.ndarray, lib: int, g: int,
 
 
 def _resize_stream(pred, succ, w, ops, keys, vals, *,
-                   backend=None, fused=None, bucket_tiles=None,
-                   binned=None):
+                   backend=None, fused=None):
     """The dual-table step body (jitted below): watermark ``w`` rides in as
     a traced uint32 scalar, so migration progress never mints a recompile."""
     cfg, new_cfg = pred.cfg, succ.cfg
@@ -1841,12 +1804,10 @@ def _resize_stream(pred, succ, w, ops, keys, vals, *,
     zk = jnp.zeros_like(keys)
     pred, rp = run_stream(pred, jnp.where(mig, 0, ops),
                           jnp.where(mig[..., None], zk, keys), vals,
-                          backend=backend, fused=fused,
-                          bucket_tiles=bucket_tiles, binned=binned)
+                          backend=backend, fused=fused)
     succ, rs = run_stream(succ, jnp.where(mig, ops, 0),
                           jnp.where(mig[..., None], keys, zk), vals,
-                          backend=backend, fused=fused,
-                          bucket_tiles=bucket_tiles, binned=binned)
+                          backend=backend, fused=fused)
     res = StepResults(
         found=jnp.where(mig, rs.found, rp.found),
         value=jnp.where(mig[..., None], rs.value, rp.value),
@@ -1856,14 +1817,14 @@ def _resize_stream(pred, succ, w, ops, keys, vals, *,
 
 
 _resize_stream_jit = functools.partial(
-    jax.jit, static_argnames=("backend", "fused", "bucket_tiles", "binned")
+    jax.jit, static_argnames=("backend", "fused")
 )(_resize_stream)
 # donated twin: pred/succ buffers update in place instead of being copied
 # per step — a full-table copy per dispatch would dominate the resize
 # window.  Only for linear-use callers (the serving loop rebinds the state
 # every call and never touches the stale one); the default stays copying.
 _resize_stream_jit_donated = functools.partial(
-    jax.jit, static_argnames=("backend", "fused", "bucket_tiles", "binned"),
+    jax.jit, static_argnames=("backend", "fused"),
     donate_argnums=(0, 1),
 )(_resize_stream)
 
@@ -1872,8 +1833,6 @@ def run_stream_resize(state: ResizeState, ops: jnp.ndarray,
                       keys: jnp.ndarray, vals: jnp.ndarray,
                       backend: Optional[str] = None,
                       fused: Optional[bool] = None,
-                      bucket_tiles: Optional[int] = None,
-                      binned: Optional[bool] = None,
                       donate: bool = False
                       ) -> Tuple[ResizeState, StepResults]:
     """Stream a ``[T, N]`` trace through an in-flight resize.
@@ -1898,15 +1857,14 @@ def run_stream_resize(state: ResizeState, ops: jnp.ndarray,
     step = _resize_stream_jit_donated if donate else _resize_stream_jit
     pred, succ, res = step(
         state.pred, state.succ, jnp.uint32(state.watermark), ops, keys, vals,
-        backend=backend, fused=fused, bucket_tiles=bucket_tiles,
-        binned=binned)
+        backend=backend, fused=fused)
     return dataclasses.replace(state, pred=pred, succ=succ), res
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "backend", "bucket_tiles"),
+                   static_argnames=("n", "backend"),
                    donate_argnums=(0, 1))
-def _migrate_slab_jit(pred, succ, w, *, n, backend=None, bucket_tiles=None):
+def _migrate_slab_jit(pred, succ, w, *, n, backend=None):
     """The jitted slab body: pred/succ buffers are DONATED so XLA updates
     the stores in place — an eager sweep would copy both full tables per
     slab, turning the per-slab pause O(table) instead of O(slab) and
@@ -1937,7 +1895,7 @@ def _migrate_slab_jit(pred, succ, w, *, n, backend=None, bucket_tiles=None):
     # dynamic_update_slice pays that copy again)
     ssk, ssv, ssb, _, _, _, _, _ = bulk_place_records(
         new_cfg, succ.store_keys, succ.store_vals, succ.store_valid,
-        b_new, keys, vals, live, backend=backend, bucket_tiles=bucket_tiles)
+        b_new, keys, vals, live, backend=backend)
     zero = lambda x: jax.lax.dynamic_update_slice_in_dim(
         x, jnp.zeros(x.shape[:2] + (n,) + x.shape[3:], x.dtype), w, axis=2)
     return (XorHashTable(pred.q_masks, zero(pred.store_keys),
@@ -1946,8 +1904,7 @@ def _migrate_slab_jit(pred, succ, w, *, n, backend=None, bucket_tiles=None):
 
 
 def migrate_slab(state: ResizeState, n_buckets: int,
-                 backend: Optional[str] = None,
-                 bucket_tiles: Optional[int] = None) -> ResizeState:
+                 backend: Optional[str] = None) -> ResizeState:
     """Migrate the next ``n_buckets`` predecessor rows ``[w, w + n)`` into
     the successor and advance the watermark.
 
@@ -1965,8 +1922,7 @@ def migrate_slab(state: ResizeState, n_buckets: int,
     if n <= 0:
         return state
     pred, succ = _migrate_slab_jit(state.pred, state.succ, jnp.uint32(w),
-                                   n=n, backend=backend,
-                                   bucket_tiles=bucket_tiles)
+                                   n=n, backend=backend)
     return ResizeState(pred=pred, succ=succ, watermark=w + n)
 
 
@@ -1997,7 +1953,6 @@ def _shrunk_masks(q_masks: jnp.ndarray, old_cfg: HashTableConfig,
 
 def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
                 backend: Optional[str] = None,
-                bucket_tiles: Optional[int] = None,
                 rng=None) -> XorHashTable:
     """Migrate a live table into a different geometry or capacity.
 
@@ -2050,8 +2005,7 @@ def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
             jnp.zeros((R, k, Bl, S, old.key_words), jnp.uint32),
             jnp.zeros((R, k, Bl, S, old.val_words), jnp.uint32),
             jnp.zeros((R, k, Bl, S), jnp.uint32),
-            bucket, keys, vals, live, backend=backend,
-            bucket_tiles=bucket_tiles)
+            bucket, keys, vals, live, backend=backend)
         return XorHashTable(table.q_masks, sk, sv, sb, new_cfg)
     if old.shards > 1:
         raise ValueError(
@@ -2078,7 +2032,7 @@ def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
         jnp.zeros((R, k, B, S, old.key_words), jnp.uint32),
         jnp.zeros((R, k, B, S, old.val_words), jnp.uint32),
         jnp.zeros((R, k, B, S), jnp.uint32),
-        bucket, keys, vals, live, backend=backend, bucket_tiles=bucket_tiles)
+        bucket, keys, vals, live, backend=backend)
     spill_ct = jnp.sum(spilled.astype(jnp.int32))
     if not isinstance(spill_ct, jax.core.Tracer) and int(spill_ct):
         raise ValueError(

@@ -165,27 +165,22 @@ def apply_step(table: XorHashTable,
 
 def run_stream(table: XorHashTable, ops: jnp.ndarray, keys: jnp.ndarray,
                vals: jnp.ndarray, backend: str | None = None,
-               fused: bool | None = None, bucket_tiles: int | None = None,
-               binned: bool | None = None
+               fused: bool | None = None
                ) -> Tuple[XorHashTable, StepResults]:
     """Stream a [T, N]-shaped query trace through the engine seam.
 
     ``fused=None`` routes to the resolved backend's StreamBackend
     implementation — the fused Pallas xor_stream kernel (table
-    VMEM-persistent across steps, bucket-blocked past the VMEM budget) on
-    the pallas backend, the scanned per-step oracle on jnp.  ``fused=True`` /
-    ``False`` force one side; ``bucket_tiles`` pins the fused kernel's
-    bucket-axis blocking and ``binned`` its tile-binned dispatch
-    (DESIGN.md §3.1)."""
+    VMEM-persistent across steps, swept in VMEM-sized passes past the VMEM
+    budget) on the pallas backend, the scanned per-step oracle on jnp.
+    ``fused=True`` / ``False`` force one side (DESIGN.md §3.1)."""
     from repro.core.engine import run_stream as _engine_run_stream
     return _engine_run_stream(table, ops, keys, vals, backend=backend,
-                              fused=fused, bucket_tiles=bucket_tiles,
-                              binned=binned)
+                              fused=fused)
 
 
 def bulk_build(table: XorHashTable, keys: jnp.ndarray, vals: jnp.ndarray,
-               live: jnp.ndarray | None = None, backend: str | None = None,
-               bucket_tiles: int | None = None):
+               live: jnp.ndarray | None = None, backend: str | None = None):
     """Construct an EMPTY table's state from a flat ``[n, Wk]``/``[n, Wv]``
     record batch in O(1) count-then-place sweeps instead of O(n) streamed
     insert steps — byte-identical to the serialized insert stream, with
@@ -193,22 +188,20 @@ def bulk_build(table: XorHashTable, keys: jnp.ndarray, vals: jnp.ndarray,
     ``(table, BulkBuildReport)``; see ``engine.bulk_build`` (DESIGN.md
     §3.2)."""
     from repro.core.engine import bulk_build as _engine_bulk_build
-    return _engine_bulk_build(table, keys, vals, live=live, backend=backend,
-                              bucket_tiles=bucket_tiles)
+    return _engine_bulk_build(table, keys, vals, live=live, backend=backend)
 
 
-def compact(table: XorHashTable, backend: str | None = None,
-            bucket_tiles: int | None = None) -> XorHashTable:
+def compact(table: XorHashTable, backend: str | None = None
+            ) -> XorHashTable:
     """Rewrite a fragmented table into dense slot occupancy — the bulk-build
     sweep over the table's own live records.  Idempotent; preserves every
     live record.  See ``engine.compact`` (DESIGN.md §3.2)."""
     from repro.core.engine import compact as _engine_compact
-    return _engine_compact(table, backend=backend, bucket_tiles=bucket_tiles)
+    return _engine_compact(table, backend=backend)
 
 
 def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
                 backend: str | None = None,
-                bucket_tiles: int | None = None,
                 rng=None) -> XorHashTable:
     """Migrate a live table into a different (k, replicate_reads) geometry
     or a different (buckets, slots) capacity — record-set-exact, canonical
@@ -218,8 +211,7 @@ def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
     growth); see ``engine.reconfigure`` (DESIGN.md §5, §6).
     """
     from repro.core.engine import reconfigure as _engine_reconfigure
-    return _engine_reconfigure(table, new_cfg, backend=backend,
-                               bucket_tiles=bucket_tiles, rng=rng)
+    return _engine_reconfigure(table, new_cfg, backend=backend, rng=rng)
 
 
 # ---------------------------------------------------------------------------

@@ -17,8 +17,7 @@ TPU model (v5e constants, used by benchmarks/roofline):
 
 Fused-stream model (:func:`stream_modeled_mops`): adds a commit-cost term
 (serial scalar chain vs the supersession-masked vectorized commit) and the
-blocked-regime terms (per-tile redundant lane work when unbinned, the
-per-stream table sweep over HBM) so benchmarks/roofline.py can report
+blocked-regime term (the per-stream table sweep over HBM) so benchmarks/roofline.py can report
 measured-vs-modeled for every BENCH_stream.json column.
 """
 from __future__ import annotations
@@ -223,7 +222,7 @@ def stream_commit_seconds(cfg: HashTableConfig,
 
 
 def stream_modeled_mops(cfg: HashTableConfig, steps: int,
-                        bucket_tiles: int = 1, binned: bool = True,
+                        bucket_tiles: int = 1,
                         vectorized_commit: bool = True, fused: bool = True,
                         mix: Union[OpMix, float, None] = None,
                         spec: TPUSpec = V5E) -> float:
@@ -232,11 +231,8 @@ def stream_modeled_mops(cfg: HashTableConfig, steps: int,
     Three terms per stream (DESIGN.md §3.1):
 
       lane work     per-query probe gather + encode bytes at VMEM bandwidth,
-                    run once per step — times the per-tile redundancy factor
-                    ``bucket_tiles`` when the blocked kernel is NOT binned
-                    (every tile re-runs the full N-wide dataflow and emits
-                    [BT, T, N] results), 1 when binned (each pass touches
-                    only its own lane window).
+                    run once per step (each sweep pass touches only its own
+                    lane window).
       commit        :func:`stream_commit_seconds` per step (serial scalar
                     chain vs supersession + burst).
       table traffic ``fused=False`` (the scanned per-step path): a full
@@ -253,8 +249,7 @@ def stream_modeled_mops(cfg: HashTableConfig, steps: int,
     gather = cfg.k * cfg.slots * entry_bytes
     scatter = mix.nsq_fraction * entry_bytes
     lane_bytes = n * (gather + scatter)
-    redundancy = 1 if (binned or bucket_tiles == 1) else bucket_tiles
-    lane_s = redundancy * lane_bytes / (spec.vmem_gbps * 1e9)
+    lane_s = lane_bytes / (spec.vmem_gbps * 1e9)
     commit_s = stream_commit_seconds(cfg, vectorized=vectorized_commit)
     replica = memory_bytes(cfg) / cfg.replicas
     if not fused:
@@ -664,8 +659,8 @@ def geometry_modeled_mops(cfg: HashTableConfig,
         vmem_budget = _planner_vmem_budget()
     replica = memory_bytes(cfg) // cfg.replicas
     tiles = _planner_bucket_tiles(replica, cfg.buckets, vmem_budget)
-    base = stream_modeled_mops(cfg, steps, bucket_tiles=tiles, binned=True,
-                               mix=mix, spec=spec)
+    base = stream_modeled_mops(cfg, steps, bucket_tiles=tiles, mix=mix,
+                               spec=spec)
     cap = cfg.nsq_ratio                       # k/p, paper Definition 1
     f = mix.nsq_fraction
     stretch = 1.0 if f <= cap else cap / f

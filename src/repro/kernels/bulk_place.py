@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: binned bulk placement (DESIGN.md §3.2).
+"""Pallas TPU kernel: bulk placement of planned records (DESIGN.md §3.2).
 
 The commit half of the count-then-place bulk build (``engine.bulk_build``).
 The plan (``engine.plan_bulk_build``) has already resolved duplicates and
@@ -7,26 +7,16 @@ assigned every surviving representative record a pairwise-distinct
 placement: no probe, no XOR encode (the target stores are empty, so the
 encode basis is zero and plaintext IS the encoding), no supersession mask.
 
-Layout reuses the fused stream kernel's tile-binned dispatch
-(``kernels/xor_stream.py``, the HashGraph bin-then-process move), shrunk to
-the write-only case:
-
-  * an XLA-side pre-pass stable-sorts the records by bucket tile and emits a
-    ``[passes + 1]`` offsets table (scalar-prefetch operand) — masked records
-    (``bucket == B``) sort behind every window;
-  * grid step ``p`` loads its packed span ``[B/passes, S, Wk+Wv+1]`` from
-    the ``ANY``-space plane refs ONCE, walks ONLY its own record window
-    ``[offs[p], offs[p+1])`` with per-record ``dynamic_update_slice`` commits,
-    and writes the span back once — one plane round trip for the whole
-    build, work proportional to the record count;
-  * the plane outputs are ``input_output_aliases`` pairs, so untouched spans
-    never round-trip through fresh buffers.
-
-TPU-lowering caveat: same as the binned stream kernel — the span load/store
-accesses ``ANY``-space refs with plain indexing, which Mosaic only accepts
-via ``pltpu.make_async_copy`` for HBM-resident refs; the (mechanical)
-substitution at the two sites below is blocked on real-TPU access.  On this
-container everything runs under ``interpret=True``.
+Layout: the plane is the packed ``[G, C, 128]`` array of
+``kernels/packed.py`` (one plane), left in HBM.  An XLA-side pre-pass sorts
+the records by bucket (masked records, ``bucket == B``, sort last) and
+splits them into chunks of ``chunk`` records; ``grid = (chunks,)`` streams
+each chunk's targets and payload into SMEM.  The kernel walks the records
+in order and keeps ONE bucket-group block in VMEM: when a record's group
+differs from the held one, the held block is DMA'd back and the new one
+DMA'd in.  Sorted records visit every group once, so the build moves each
+touched group block in and out once; untouched groups never move (the
+plane output aliases its input).
 """
 from __future__ import annotations
 
@@ -37,108 +27,102 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import packed as pk
 
-def _bulk_place_kernel(offs_ref, rec_ref, kin_ref, vin_ref, bin_ref,
-                       kout_ref, vout_ref, bout_ref, *, span_buckets: int,
-                       key_words: int, val_words: int):
-    p = pl.program_id(0)
-    Bs, Wk, Wv = span_buckets, key_words, val_words
-    wtot = Wk + Wv + 1
-
-    # span DMA: plane -> packed on-chip value once per pass, back once — the
-    # build's only full-plane traffic
-    tile0 = jnp.concatenate([
-        kin_ref[pl.ds(p * Bs, Bs)],
-        vin_ref[pl.ds(p * Bs, Bs)],
-        bin_ref[pl.ds(p * Bs, Bs)][..., None],
-    ], axis=-1)                                            # [Bs, S, Wtot]
-
-    rec = rec_ref[...]                                     # [n, 2+Wk+Wv]
-
-    def commit(i, tile):
-        r = jax.lax.dynamic_slice(rec, (i, 0), (1, 2 + Wk + Wv))[0]
-        b = r[0].astype(jnp.int32) - p * Bs
-        s = r[1].astype(jnp.int32)
-        row = jnp.concatenate(
-            [r[2:2 + Wk + Wv], jnp.ones((1,), jnp.uint32)]
-        ).reshape(1, 1, wtot)                              # key | val | valid
-        return jax.lax.dynamic_update_slice(tile, row, (b, s, 0))
-
-    tile = jax.lax.fori_loop(offs_ref[p], offs_ref[p + 1], commit, tile0)
-
-    kout_ref[pl.ds(p * Bs, Bs)] = tile[..., :Wk]
-    vout_ref[pl.ds(p * Bs, Bs)] = tile[..., Wk:Wk + Wv]
-    bout_ref[pl.ds(p * Bs, Bs)] = tile[..., wtot - 1]
+DEFAULT_CHUNK = 1024
 
 
-@functools.partial(jax.jit, static_argnames=("bin_passes", "interpret"))
+def _bulk_place_kernel(bkt_ref, slot_ref, pay_ref, tab_hbm, out_hbm, blk,
+                       held, sem, *, buckets: int, slots: int,
+                       payload_words: int, chunk: int):
+    c = pl.program_id(0)
+    W = payload_words + 1
+
+    @pl.when(c == 0)
+    def _():
+        held[0] = -1
+
+    def move(src, dst):
+        cp = pltpu.make_async_copy(src, dst, sem)
+        cp.start()
+        cp.wait()
+
+    def place(j, carry):
+        b = bkt_ref[j]
+
+        @pl.when(b < buckets)
+        def _():
+            g = b // pk.LANES
+
+            @pl.when(g != held[0])
+            def _():
+                @pl.when(held[0] >= 0)
+                def _():
+                    move(blk, out_hbm.at[held[0]])
+                move(tab_hbm.at[g], blk)
+                held[0] = g
+
+            words = [pay_ref[j * payload_words + w]
+                     for w in range(payload_words)] + [jnp.int32(1)]
+            pk.write_slot(blk, b % pk.LANES, 0, slot_ref[j], words,
+                          slots=slots, words=W)
+        return carry
+
+    jax.lax.fori_loop(0, chunk, place, 0)
+
+    @pl.when((c == pl.num_programs(0) - 1) & (held[0] >= 0))
+    def _():
+        move(blk, out_hbm.at[held[0]])
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def bulk_place_pallas(w_bucket: jnp.ndarray, w_slot: jnp.ndarray,
                       keys: jnp.ndarray, vals: jnp.ndarray,
                       plane_keys: jnp.ndarray, plane_vals: jnp.ndarray,
-                      plane_valid: jnp.ndarray, bin_passes: int = 1,
-                      interpret: bool = True):
+                      plane_valid: jnp.ndarray, chunk: int = DEFAULT_CHUNK,
+                      interpret=None):
     """Place ``n`` pre-planned records into the port-0 plane.
 
     ``w_bucket``/``w_slot`` ``[n]`` int32 (``bucket == B`` marks a masked
     record); ``keys [n, Wk]`` / ``vals [n, Wv]`` uint32 plaintext;
     ``plane_* [B, S, W*]`` (valid ``[B, S]``) ONE port's slice of one
-    replica.  ``bin_passes`` must be a power-of-two divisor of ``B`` —
-    residency-sized sweep passes, sized from the VMEM budget by
-    ``kernels.ops.bulk_place``.  Returns the updated planes.
+    replica.  ``chunk`` records stream through SMEM per grid step.  Returns
+    the updated planes.
     """
     B, S, Wk = plane_keys.shape
     Wv = plane_vals.shape[-1]
-    if bin_passes < 1 or B % bin_passes:
-        raise ValueError(f"bin_passes={bin_passes} must divide buckets={B}")
     n = w_bucket.shape[0]
-    wrec = 2 + Wk + Wv
+    nch = max(-(-n // chunk), 1)
+    pad = nch * chunk - n
 
-    # ---- XLA-side pre-pass: stable-sort records by bucket tile -----------
-    Bs = B // bin_passes
-    wb = w_bucket.astype(jnp.int32)
-    tile_id = jnp.where(wb < B, jnp.clip(wb, 0, B - 1) // Bs, bin_passes)
-    rec = jnp.concatenate([
-        wb.astype(jnp.uint32)[:, None], w_slot.astype(jnp.uint32)[:, None],
-        keys.astype(jnp.uint32), vals.astype(jnp.uint32)], axis=-1)
-    if n == 0:
-        rec = jnp.zeros((1, wrec), jnp.uint32)
-        offs = jnp.zeros((bin_passes + 1,), jnp.int32)
-    else:
-        order = jnp.argsort(tile_id, stable=True)
-        rec = rec[order]
-        # offs[j] == #records with tile id < j: pass p's window is
-        # [offs[p], offs[p+1]) and masked records fall past every window
-        offs = jnp.concatenate([
-            jnp.zeros((1,), jnp.int32),
-            jnp.sum(tile_id[:, None]
-                    < jnp.arange(1, bin_passes + 1, dtype=jnp.int32)[None, :],
-                    axis=0, dtype=jnp.int32)])
+    # ---- XLA-side pre-pass: sort records by bucket, pad to whole chunks --
+    wb = jnp.where(w_bucket < B, w_bucket.astype(jnp.int32), B)
+    order = jnp.argsort(wb, stable=True)
+    bkt = jnp.pad(wb[order], (0, pad), constant_values=B)
+    slot = jnp.pad(w_slot.astype(jnp.int32)[order], (0, pad))
+    pay = jnp.concatenate([keys, vals], axis=-1).astype(jnp.uint32)[order]
+    pay = pk.i32(jnp.pad(pay, ((0, pad), (0, 0)))).reshape(-1)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(bin_passes,),
-        in_specs=[
-            pl.BlockSpec((rec.shape[0], wrec), lambda p, offs: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),          # HBM-resident
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ),
-    )
-    out_shapes = (
-        jax.ShapeDtypeStruct(plane_keys.shape, plane_keys.dtype),
-        jax.ShapeDtypeStruct(plane_vals.shape, plane_vals.dtype),
-        jax.ShapeDtypeStruct(plane_valid.shape, plane_valid.dtype),
-    )
-    return pl.pallas_call(
-        functools.partial(_bulk_place_kernel, span_buckets=Bs,
-                          key_words=Wk, val_words=Wv),
-        grid_spec=grid_spec, out_shape=out_shapes,
-        # the plane updates in place — fresh buffers would double the
-        # build's only full-plane traffic
-        input_output_aliases={2: 0, 3: 1, 4: 2},
-        interpret=interpret,
-    )(offs, rec, plane_keys, plane_vals, plane_valid)
+    tab = pk.pack(plane_keys[None], plane_vals[None], plane_valid[None])
+    C = tab.shape[1]
+    smem = lambda size: pl.BlockSpec((size,), lambda c: (c,),
+                                     memory_space=pltpu.SMEM)
+    tab = pl.pallas_call(
+        functools.partial(_bulk_place_kernel, buckets=B, slots=S,
+                          payload_words=Wk + Wv, chunk=chunk),
+        grid=(nch,),
+        in_specs=[smem(chunk), smem(chunk), smem(chunk * (Wk + Wv)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(tab.shape, tab.dtype),
+        scratch_shapes=[pltpu.VMEM((C, pk.LANES), jnp.uint32),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+        # the plane updates in place: untouched groups never move
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pk.interpret_mode(interpret),
+    )(bkt, slot, pay, tab)
+    sk, sv, sb = pk.unpack(tab, 1, B, S, Wk, Wv)
+    return sk[0], sv[0], sb[0]

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.packed import interpret_mode
+
 DEFAULT_BLOCK_N = 1024
 
 
@@ -42,7 +44,7 @@ def _h3_kernel(keys_ref, q_ref, out_ref, *, index_bits: int, key_words: int):
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def h3_hash_pallas(keys_t: jnp.ndarray, q_masks: jnp.ndarray,
                    block_n: int = DEFAULT_BLOCK_N,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret=None) -> jnp.ndarray:
     """keys_t: [W, N] uint32 (word-transposed), q_masks: [J, W] uint32 -> [N]."""
     W, N = keys_t.shape
     J = q_masks.shape[0]
@@ -59,5 +61,5 @@ def h3_hash_pallas(keys_t: jnp.ndarray, q_masks: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(keys_t, q_masks)

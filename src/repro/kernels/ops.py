@@ -1,13 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels, with shape guards and a
-pure-jnp fallback (used when the table exceeds the VMEM-resident regime or on
-backends without Mosaic gather support).
+pure-jnp fallback for the per-step probe/commit kernels (used when the table
+exceeds the VMEM-resident regime).
 
 The jnp fallbacks ARE the engine's jnp backend (``repro.core.engine``) — there
-is exactly one jnp and one Pallas implementation of each stage; the former
-``kernels/ref.py`` oracles were collapsed into the engine.
+is exactly one jnp and one Pallas implementation of each stage.
 
-On this container the kernels execute under ``interpret=True`` (CPU); on TPU
-set ``interpret=False`` (the default flips on TPU backends).
+The kernels compile under Mosaic on a TPU backend and run the same kernel
+code in Pallas interpret mode everywhere else: ``packed.interpret_mode``
+is the one switch.
 """
 from __future__ import annotations
 
@@ -17,41 +17,56 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hashing import h3_hash as _h3_jnp
+from repro.kernels import packed as pk
 from repro.kernels.h3_hash import h3_hash_pallas
 from repro.kernels.xor_probe import xor_probe_pallas
 from repro.kernels.xor_commit import xor_commit_pallas
 from repro.kernels.xor_stream import xor_stream_pallas
 from repro.kernels.bulk_place import bulk_place_pallas
 
-# VMEM-resident table budget (one replica must fit alongside query blocks).
+# Scoped-VMEM limit handed to the kernels that hold a table in VMEM (v5e has
+# 128 MiB of VMEM; Mosaic's default scoped limit is far below it).
+VMEM_LIMIT_BYTES = 120 * 1024 * 1024
+# On-chip bytes one table span may take (``table_vmem_bytes``), leaving the
+# rest of the limit to query/result blocks and kernel temporaries.
 VMEM_TABLE_BUDGET_BYTES = 96 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def replica_bytes(store_keys, store_vals, store_valid) -> int:
     """Bytes of ONE replica of the XOR store arrays (4 bytes per uint32 word).
 
-    The single source of truth for every VMEM-budget check (engine backend
-    resolution, the probe/commit fallbacks, stream bucket-tiling).  Accepts
-    either the replicated 5D layout ``[R, k, B, S, W]`` or a single 4D
-    replica ``[k, B, S, W]``.
+    Accepts either the replicated 5D layout ``[R, k, B, S, W]`` or a single
+    4D replica ``[k, B, S, W]``.
     """
     total = 4 * (store_keys.size + store_vals.size + store_valid.size)
     reps = store_keys.shape[0] if store_keys.ndim == 5 else 1
     return total // reps
 
 
+def table_vmem_bytes(store_keys, store_vals, store_valid) -> int:
+    """On-chip bytes of ONE replica in the kernels' packed layout
+    (``kernels/packed.py``) — the single source of truth for every
+    VMEM-budget check (engine backend resolution, the probe/commit
+    fallbacks, stream sweep passes).  Same array forms as
+    :func:`replica_bytes`."""
+    k, B, S, Wk = store_keys.shape[-4:]
+    return pk.packed_bytes(k, B, S, Wk + store_vals.shape[-1] + 1)
+
+
+def fits_vmem(store_keys, store_vals, store_valid) -> bool:
+    return table_vmem_bytes(store_keys, store_vals,
+                            store_valid) <= VMEM_TABLE_BUDGET_BYTES
+
+
 def stream_bucket_tiles(store_keys, store_vals, store_valid) -> int:
-    """Bucket-axis blocking factor for the fused stream kernel: the smallest
-    power-of-two tile count whose tile fits ``VMEM_TABLE_BUDGET_BYTES`` (1 ==
-    the whole replica is VMEM-resident; capped at one bucket per tile)."""
-    rb = replica_bytes(store_keys, store_vals, store_valid)
-    buckets = store_keys.shape[-3]
+    """Sweep passes of the fused stream kernel: the smallest power-of-two
+    pass count whose span fits ``VMEM_TABLE_BUDGET_BYTES`` (1 == the whole
+    replica is VMEM-resident), capped at the packed table's bucket-group
+    count — one 128-bucket group is the smallest span the kernel moves."""
+    rb = table_vmem_bytes(store_keys, store_vals, store_valid)
+    groups = pk.packed_groups(store_keys.shape[-3])
     tiles = 1
-    while rb // tiles > VMEM_TABLE_BUDGET_BYTES and tiles < buckets:
+    while rb // tiles > VMEM_TABLE_BUDGET_BYTES and tiles < groups:
         tiles *= 2
     return tiles
 
@@ -59,32 +74,32 @@ def stream_bucket_tiles(store_keys, store_vals, store_valid) -> int:
 @functools.partial(jax.jit, static_argnames=("use_pallas", "block_n"))
 def h3_hash(keys: jnp.ndarray, q_masks: jnp.ndarray, use_pallas: bool = True,
             block_n: int = 1024) -> jnp.ndarray:
-    """Hash ``[N, W]`` uint32 keys -> ``[N]`` uint32 bucket indices."""
+    """Hash ``[N, W]`` uint32 keys -> ``[N]`` uint32 bucket indices.  The
+    batch is zero-padded to a whole number of kernel blocks."""
     n = keys.shape[0]
     # index_bits == 0 (single-bucket table) has an empty Q matrix — the
     # kernel's J-dim block would be zero-sized; the jnp path returns zeros.
-    if not use_pallas or q_masks.shape[0] == 0 or n % min(block_n, n):
+    if not use_pallas or q_masks.shape[0] == 0 or n == 0:
         return _h3_jnp(keys, q_masks)
-    return h3_hash_pallas(keys.T, q_masks, block_n=min(block_n, n),
-                          interpret=not _on_tpu())
+    bn = min(block_n, n)
+    pad = -n % bn
+    keys_t = jnp.pad(keys.T, ((0, 0), (0, pad)))
+    return h3_hash_pallas(keys_t, q_masks, block_n=bn)[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "block_q", "stagger"))
+@functools.partial(jax.jit, static_argnames=("use_pallas", "stagger"))
 def xor_probe(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
               store_keys: jnp.ndarray, store_vals: jnp.ndarray,
               store_valid: jnp.ndarray, use_pallas: bool = True,
-              block_q: int = 256, stagger: bool = False):
+              stagger: bool = False):
     """Fused decode+probe of one replica.  See xor_probe_pallas docstring."""
-    n = bucket.shape[0]
-    table_bytes = replica_bytes(store_keys, store_vals, store_valid)
-    if (not use_pallas or n % min(block_q, n)
-            or table_bytes > VMEM_TABLE_BUDGET_BYTES):
+    if not use_pallas or not fits_vmem(store_keys, store_vals, store_valid):
         from repro.core.engine import probe_jnp
         return probe_jnp(bucket, port, qkeys, store_keys[None],
                          store_vals[None], store_valid[None], stagger=stagger)
     return xor_probe_pallas(bucket, port, qkeys, store_keys, store_vals,
-                            store_valid, block_q=min(block_q, n),
-                            interpret=not _on_tpu(), stagger=stagger)
+                            store_valid, stagger=stagger,
+                            vmem_limit=VMEM_LIMIT_BYTES)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -100,79 +115,46 @@ def xor_commit(store_keys: jnp.ndarray, store_vals: jnp.ndarray,
     xor_commit_pallas).  ``bucket >= B`` marks a masked lane.  Falls back to
     the engine's jnp record scatter when the replica exceeds the VMEM budget.
     """
-    if (not use_pallas or replica_bytes(store_keys, store_vals, store_valid)
-            > VMEM_TABLE_BUDGET_BYTES):
+    if not use_pallas or not fits_vmem(store_keys, store_vals, store_valid):
         from repro.core.engine import _scatter_records
         rec = dict(port=port, bucket=bucket, slot=slot,
                    enc_k=enc_k, enc_v=enc_v, enc_b=enc_b)
         return _scatter_records(store_keys, store_vals, store_valid, rec)
     return xor_commit_pallas(store_keys, store_vals, store_valid, port, bucket,
                              slot, enc_k, enc_v, enc_b,
-                             interpret=not _on_tpu())
+                             vmem_limit=VMEM_LIMIT_BYTES)
 
 
 def xor_stream(bucket: jnp.ndarray, port: jnp.ndarray, legal: jnp.ndarray,
                ops: jnp.ndarray, qkeys: jnp.ndarray, qvals: jnp.ndarray,
                store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-               store_valid: jnp.ndarray, bucket_tiles: int = 1,
-               stagger: bool = False, bucket_base=0,
-               binned: bool | None = None):
+               store_valid: jnp.ndarray, stagger: bool = False,
+               bucket_base=0):
     """Fused in-kernel query streaming over one replica: probe + plan +
-    non-search XOR encode + supersession-masked last-wins commit for a whole
-    ``[T, N]`` stream in a single Pallas kernel, table VMEM-resident across
-    steps (bucket-tiled when one replica exceeds the VMEM budget — pick
-    ``bucket_tiles`` with :func:`stream_bucket_tiles`).  ``port``/``legal``
-    may be ``[N]`` lane vectors or ``[T, N]`` per-step rows (the bounded
-    router re-bins routed lanes, so a slot's origin varies by step —
-    engine.route_stream_bounded).  ``bucket_base``
-    (traced scalar) offsets a shard-local partition into the global bucket
-    space; lanes outside the partition are inert.  ``binned`` selects the
-    tile-binned dispatch: lanes stable-sorted by tile, lane windows via
-    scalar-prefetch offsets, the table swept in residency-sized passes with
-    an in-kernel step scan per pass — at ``bucket_tiles == 1`` the
-    degenerate single-pass form, whose grid collapses to ONE iteration
-    scanning all T steps of the VMEM-resident table (one kernel launch per
-    stream instead of T); ``binned=False`` keeps the per-step-grid
-    mask-all-N baseline.  ``binned=None``
-    defaults per backend: True off-TPU (interpret mode), False on TPU —
-    the binned kernel's ANY-ref span load/store still needs the
-    ``make_async_copy`` substitution to lower under Mosaic (see the
-    xor_stream_pallas module docstring), so TPU keeps the block-pipelined
-    layout until that lands.  The sweep pass count is sized here from the
-    VMEM budget — ``min(bucket_tiles, stream_bucket_tiles(...))`` — so a
-    genuinely over-budget table sweeps every tile while a budget-fitting
-    table pinned to a larger ``bucket_tiles`` coalesces adjacent tiles into
-    fewer passes (binning granularity and residency are separate knobs;
-    DESIGN.md §3.1).  See xor_stream_pallas.  Interpret mode on CPU; the
+    non-search XOR encode + last-wins commit for a whole ``[T, N]`` stream
+    in a single Pallas kernel, table VMEM-resident across steps (swept in
+    :func:`stream_bucket_tiles` spans when one replica exceeds the VMEM
+    budget; the budget is read at trace time).  ``port``/``legal`` may be ``[N]``
+    lane vectors or ``[T, N]`` per-step rows (the bounded router re-bins
+    routed lanes, so a slot's origin varies by step —
+    engine.route_stream_bounded).  ``bucket_base`` (traced scalar) offsets
+    a shard-local partition into the global bucket space; lanes outside the
+    partition are inert (DESIGN.md §3.1).  See xor_stream_pallas; the
     scanned per-step engine path is the semantic oracle.
     """
-    if binned is None:
-        binned = not _on_tpu()
-    passes = min(bucket_tiles,
-                 stream_bucket_tiles(store_keys, store_vals, store_valid))
+    passes = stream_bucket_tiles(store_keys, store_vals, store_valid)
     return xor_stream_pallas(bucket, port, legal, ops, qkeys, qvals,
                              store_keys, store_vals, store_valid,
-                             bucket_tiles=bucket_tiles,
-                             interpret=not _on_tpu(), stagger=stagger,
-                             bucket_base=bucket_base, binned=binned,
-                             bin_passes=passes)
+                             passes=passes, stagger=stagger,
+                             bucket_base=bucket_base,
+                             vmem_limit=VMEM_LIMIT_BYTES)
 
 
 def bulk_place(w_bucket: jnp.ndarray, w_slot: jnp.ndarray, keys: jnp.ndarray,
                vals: jnp.ndarray, plane_keys: jnp.ndarray,
-               plane_vals: jnp.ndarray, plane_valid: jnp.ndarray,
-               bucket_tiles: int | None = None):
-    """Binned bulk placement of pre-planned records into the port-0 plane
-    (the commit half of ``engine.bulk_build`` — see bulk_place_pallas).
-    ``bucket_tiles`` pins the residency-sized sweep-pass count (a
-    power-of-two divisor of B); None sizes it so one span plus headroom fits
-    the VMEM budget — the plane is 1/k of a replica, so budget-fitting
-    tables place in ONE pass.  Engine's jnp backend scatter is the oracle.
-    """
-    if bucket_tiles is None:
-        bucket_tiles = stream_bucket_tiles(plane_keys[None], plane_vals[None],
-                                           plane_valid[None])
+               plane_vals: jnp.ndarray, plane_valid: jnp.ndarray):
+    """Bulk placement of pre-planned records into the port-0 plane (the
+    commit half of ``engine.bulk_build`` — see bulk_place_pallas).
+    Engine's jnp backend scatter is the oracle."""
     return bulk_place_pallas(w_bucket, w_slot, keys, vals, plane_keys,
-                             plane_vals, plane_valid,
-                             bin_passes=bucket_tiles,
-                             interpret=not _on_tpu())
+                             plane_vals, plane_valid)

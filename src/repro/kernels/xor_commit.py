@@ -1,25 +1,22 @@
-"""Pallas TPU kernel: masked scatter of pre-encoded mutation records.
+"""Pallas TPU kernel: masked store of pre-encoded mutation records.
 
 The mutation half of the paper's PE pipeline (§IV-C.3): for every write lane
 the new plaintext entry is XOR-encoded against the *other* k-1 partial stores
-(the non-search XOR tree) and the encoding is scattered into the own-port
+(the non-search XOR tree) and the encoding is written into the own-port
 store of EVERY replica (inter-PE propagation).
 
 Replicas are byte-identical at step boundaries, so the encoding is the same
 for every replica — the engine computes it ONCE from the ``ProbeResult`` rem
 basis the probe stage already produced (``engine.encode_records``), and this
-kernel's per-replica grid is left with only the masked sequential scatter.
-(Earlier revisions re-ran the gather + XOR-tree encode inside the grid, once
-per replica — R identical encodes for R replicas.)
+kernel only stores it: into replica 0's packed table (``kernels/packed.py``),
+which the wrapper then broadcasts to all replicas.
 
 Timing matches the FPGA (and the jnp oracle) exactly: encodings come from the
 pre-step snapshot (via the probe), then all write ports commit.  The commit
-is a sequential masked scatter over lanes (lane order = program order, so
-duplicate (port, bucket, slot) targets resolve last-wins; the router
-guarantees write lanes have distinct ports at queries_per_pe=1).
-
-Grid: one step per replica; the replica block plus the lane vectors live in
-VMEM.  Tables beyond the VMEM budget take the jnp fallback in ops.py.
+is a sequential masked store over lanes (lane order = program order, so
+duplicate (port, bucket, slot) targets resolve last-wins).  Lane targets
+arrive as SMEM operands; the table is DMA'd whole into VMEM scratch and back.
+Tables beyond the VMEM budget take the jnp fallback in ops.py.
 """
 from __future__ import annotations
 
@@ -28,46 +25,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import packed as pk
 
 
-def _xor_commit_kernel(skeys_ref, svals_ref, svalid_ref, port_ref, bucket_ref,
-                       slot_ref, enck_ref, encv_ref, encb_ref,
-                       okeys_ref, ovals_ref, ovalid_ref,
-                       *, buckets: int, n: int):
-    # --- snapshot: read the pre-step replica, pass it through ---------------
-    okeys_ref[...] = skeys_ref[...]
-    ovals_ref[...] = svals_ref[...]
-    ovalid_ref[...] = svalid_ref[...]
+def _xor_commit_kernel(port_ref, bkt_ref, slot_ref, enc_ref, tab_hbm, out_hbm,
+                       tab, sem, *, buckets: int, slots: int, words: int,
+                       n: int):
+    load = pltpu.make_async_copy(tab_hbm, tab, sem)
+    load.start()
+    load.wait()
+    enc = pk.i32(enc_ref[...])                             # [W, N]
 
-    port = port_ref[:].astype(jnp.int32)                   # [N]
-    bucket = bucket_ref[:].astype(jnp.int32)               # [N] (OOB == masked)
-    slot = slot_ref[:].astype(jnp.int32)                   # [N]
-    enc_k = enck_ref[...]                                  # [N, Wk]
-    enc_v = encv_ref[...]                                  # [N, Wv]
-    enc_b = encb_ref[:]                                    # [N]
-
-    # --- masked sequential commit (encodings pre-computed by the engine) ----
-    def body(i, carry):
-        @pl.when(bucket[i] < buckets)
+    def body(i, c):
+        @pl.when(bkt_ref[i] < buckets)
         def _():
-            pt, bk, sl = port[i], bucket[i], slot[i]
-            okeys_ref[0, pt, bk, sl, :] = jax.lax.dynamic_index_in_dim(
-                enc_k, i, 0, keepdims=False)
-            ovals_ref[0, pt, bk, sl, :] = jax.lax.dynamic_index_in_dim(
-                enc_v, i, 0, keepdims=False)
-            ovalid_ref[0, pt, bk, sl] = enc_b[i]
-        return carry
+            b = bkt_ref[i]
+            e = pk.pick_lane(enc, i)                       # [W, 1]
+            pk.write_slot(tab.at[b // pk.LANES], b % pk.LANES,
+                          port_ref[i], slot_ref[i],
+                          [e[w:w + 1] for w in range(words)], slots=slots,
+                          words=words)
+        return c
 
     jax.lax.fori_loop(0, n, body, 0)
+    store = pltpu.make_async_copy(tab, out_hbm, sem)
+    store.start()
+    store.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "vmem_limit"))
 def xor_commit_pallas(store_keys: jnp.ndarray, store_vals: jnp.ndarray,
                       store_valid: jnp.ndarray, port: jnp.ndarray,
                       bucket: jnp.ndarray, slot: jnp.ndarray,
                       enc_k: jnp.ndarray, enc_v: jnp.ndarray,
-                      enc_b: jnp.ndarray, interpret: bool = True):
-    """Masked scatter of encoded records into all replicas.
+                      enc_b: jnp.ndarray, interpret=None,
+                      vmem_limit: int | None = None):
+    """Masked store of encoded records into all replicas.
 
     store_* ``[R, k, B, S, W*]``; port/bucket/slot ``[N]`` (``bucket >= B``
     marks a masked lane — dropped); enc_* the XOR-encoded rows
@@ -77,33 +72,25 @@ def xor_commit_pallas(store_keys: jnp.ndarray, store_vals: jnp.ndarray,
     R, k, B, S, Wk = store_keys.shape
     Wv = store_vals.shape[-1]
     N = port.shape[0]
-    grid = (R,)
-
-    rep = lambda shape: pl.BlockSpec((1,) + shape[1:],
-                                     lambda r: (r,) + (0,) * (len(shape) - 1))
-    lane1 = pl.BlockSpec((N,), lambda r: (0,))
-    lane2 = lambda w: pl.BlockSpec((N, w), lambda r: (0, 0))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct(store_keys.shape, store_keys.dtype),
-        jax.ShapeDtypeStruct(store_vals.shape, store_vals.dtype),
-        jax.ShapeDtypeStruct(store_valid.shape, store_valid.dtype),
-    )
-    return pl.pallas_call(
-        functools.partial(_xor_commit_kernel, buckets=B, n=N),
-        grid=grid,
-        in_specs=[
-            rep(store_keys.shape), rep(store_vals.shape), rep(store_valid.shape),
-            lane1, lane1, lane1,
-            lane2(Wk), lane2(Wv), lane1,
-        ],
-        out_specs=(rep(store_keys.shape), rep(store_vals.shape),
-                   rep(store_valid.shape)),
-        out_shape=out_shapes,
-        # stores update in place — without aliasing every step would round-trip
-        # the full table through fresh output buffers
-        input_output_aliases={0: 0, 1: 1, 2: 2},
-        interpret=interpret,
-    )(store_keys, store_vals, store_valid,
-      port.astype(jnp.int32), bucket.astype(jnp.int32), slot.astype(jnp.int32),
-      enc_k, enc_v, enc_b)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tab = pk.pack(store_keys[0], store_vals[0], store_valid[0])
+    enc = jnp.concatenate([enc_k, enc_v, enc_b[:, None]], axis=-1).T
+    tab = pl.pallas_call(
+        functools.partial(_xor_commit_kernel, buckets=B, slots=S,
+                          words=Wk + Wv + 1, n=N),
+        in_specs=[smem, smem, smem, pl.BlockSpec(memory_space=pltpu.VMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(tab.shape, tab.dtype),
+        scratch_shapes=[pltpu.VMEM(tab.shape, jnp.uint32),
+                        pltpu.SemaphoreType.DMA(())],
+        # stores update in place — without aliasing every step would
+        # round-trip the full table through a fresh output buffer
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=pk.interpret_mode(interpret),
+    )(port.astype(jnp.int32), bucket.astype(jnp.int32),
+      slot.astype(jnp.int32), enc.astype(jnp.uint32), tab)
+    sk, sv, sb = pk.unpack(tab, k, B, S, Wk, Wv)
+    bc = lambda x: jnp.broadcast_to(x[None], (R,) + x.shape)
+    return bc(sk), bc(sv), bc(sb)

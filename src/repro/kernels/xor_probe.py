@@ -2,12 +2,12 @@
 
 Fuses the PE stages of §IV-C.2 — parallel Partial-XOR-Store read, the two XOR
 reduction trees, and the result-resolution unit — into a single VMEM-resident
-kernel.  The table (one replica: k partial stores) is mapped unblocked into
-VMEM, exactly mirroring the FPGA's on-chip URAM residency; queries stream
-through the grid in blocks.
+kernel.  The table (one replica: k partial stores) is held whole in VMEM in
+the packed layout of ``kernels/packed.py``, mirroring the FPGA's on-chip
+URAM residency.
 
 Per query:
-  rows    = stores[:, bucket[q]]          k x S x words   (vector gather)
+  rows    = stores[:, bucket[q]]          k x S x words   (column gather)
   dec     = XOR-tree(rows)                S x words       (search XOR tree)
   match   = valid(dec) & key-compare      S
   found, match_slot, open_slot, value
@@ -15,9 +15,11 @@ Per query:
                                            XOR of all stores EXCEPT the
                                            querying port — the encode basis)
 
-Gathers use ``jnp.take`` along the bucket axis of a VMEM block (Mosaic
-``dynamic_gather``); validated via interpret mode on CPU.  Tables larger than
-VMEM take the jnp fallback in ops.py (HBM gathers, same semantics).
+Bucket indices arrive as a scalar-prefetch operand; each query reads its
+bucket's column from the packed group block (``packed.gather``).  The
+kernel lowers under Mosaic for TPU and runs in Pallas interpret mode off
+the chip.  Tables larger than the VMEM budget take the jnp fallback in
+ops.py (HBM gathers, same semantics).
 """
 from __future__ import annotations
 
@@ -26,80 +28,31 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 256
+from repro.kernels import packed as pk
 
 
-def _xor_probe_kernel(bucket_ref, port_ref, qkey_ref, skeys_ref, svals_ref,
-                      svalid_ref, found_ref, mslot_ref, oslot_ref, hopen_ref,
-                      value_ref, remk_ref, remv_ref, remb_ref,
-                      *, k: int, slots: int, key_words: int, val_words: int,
-                      stagger: bool):
-    idx = bucket_ref[:].astype(jnp.int32)                  # [BQ]
-    port = port_ref[:].astype(jnp.int32)                   # [BQ]
-
-    # --- parallel partial-store read (gather over bucket axis) -------------
-    # stores are [k, B, S, W]; take along axis=1 -> [k, BQ, S, W]
-    rows_k = jnp.take(skeys_ref[...], idx, axis=1)
-    rows_v = jnp.take(svals_ref[...], idx, axis=1)
-    rows_b = jnp.take(svalid_ref[...], idx, axis=1)
-
-    # --- search XOR reduction tree (static fold over k) --------------------
-    def xtree(x):
-        acc = x[0]
-        for i in range(1, k):
-            acc = acc ^ x[i]
-        return acc
-
-    dec_k = xtree(rows_k)                                  # [BQ, S, Wk]
-    dec_v = xtree(rows_v)                                  # [BQ, S, Wv]
-    dec_b = xtree(rows_b)                                  # [BQ, S]
-
-    # --- result resolution ---------------------------------------------------
-    qk = qkey_ref[...]                                     # [BQ, Wk]
-    key_eq = jnp.ones(dec_b.shape, dtype=jnp.bool_)
-    for w in range(key_words):
-        key_eq = key_eq & (dec_k[..., w] == qk[:, None, w])
-    occ = (dec_b & 1).astype(jnp.bool_)
-    match = key_eq & occ                                   # [BQ, S]
-    found = jnp.any(match, axis=-1)
-    mslot = jnp.argmax(match, axis=-1).astype(jnp.int32)
-    open_mask = ~occ
-    hopen = jnp.any(open_mask, axis=-1)
-    if stagger:
-        # one source of truth for the beyond-paper slot policy (pure jnp,
-        # traceable inside the kernel; trace-time import avoids a cycle)
-        from repro.core.engine import staggered_open_slot
-        oslot = staggered_open_slot(open_mask, port)
-    else:
-        oslot = jnp.argmax(open_mask, axis=-1).astype(jnp.int32)
-
-    value = jnp.take_along_axis(dec_v, mslot[:, None, None], axis=1)[:, 0]
-    value = jnp.where(found[:, None], value, jnp.uint32(0))
-
-    # --- non-search XOR tree: XOR of all stores except the querying port ----
-    # rem = dec ^ rows[port]  (gather own-port row per query)
-    own_k = jnp.take_along_axis(
-        rows_k, port[None, :, None, None], axis=0)[0]      # [BQ, S, Wk]
-    own_v = jnp.take_along_axis(rows_v, port[None, :, None, None], axis=0)[0]
-    own_b = jnp.take_along_axis(rows_b, port[None, :, None], axis=0)[0]
-    remk_ref[...] = dec_k ^ own_k
-    remv_ref[...] = dec_v ^ own_v
-    remb_ref[...] = dec_b ^ own_b
-
-    found_ref[:] = found
-    mslot_ref[:] = mslot
-    oslot_ref[:] = oslot
-    hopen_ref[:] = hopen
+def _xor_probe_kernel(bkt_ref, port_ref, qkey_ref, tab_ref, flags_ref,
+                      value_ref, rem_ref, *, k: int, slots: int,
+                      key_words: int, val_words: int, n: int, stagger: bool):
+    cols = pk.gather(tab_ref, lambda i: bkt_ref[i], 0, n, n)
+    found, mslot, oslot, hopen, value, rem = pk.probe(
+        cols, qkey_ref[...], port_ref[...], k=k, slots=slots,
+        key_words=key_words, val_words=val_words, stagger=stagger)
+    flags_ref[...] = jnp.concatenate(
+        [found.astype(jnp.int32), mslot, oslot, hopen.astype(jnp.int32)],
+        axis=0)
     value_ref[...] = value
+    rem_ref[...] = rem
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_q", "interpret", "stagger"))
+                   static_argnames=("interpret", "stagger", "vmem_limit"))
 def xor_probe_pallas(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
                      store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-                     store_valid: jnp.ndarray, block_q: int = DEFAULT_BLOCK_Q,
-                     interpret: bool = True, stagger: bool = False):
+                     store_valid: jnp.ndarray, interpret=None,
+                     stagger: bool = False, vmem_limit: int | None = None):
     """Probe one replica for a batch of queries.
 
     bucket [N] uint32, port [N] int32, qkeys [N, Wk] uint32,
@@ -110,47 +63,21 @@ def xor_probe_pallas(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
     N = bucket.shape[0]
     k, B, S, Wk = store_keys.shape
     Wv = store_vals.shape[-1]
-    bq = min(block_q, N)
-    if N % bq:
-        raise ValueError(f"N={N} % block_q={bq} != 0")
-    grid = (N // bq,)
-
-    full = lambda shape: pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape))
-    qspec1 = pl.BlockSpec((bq,), lambda i: (i,))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct((N,), jnp.bool_),
-        jax.ShapeDtypeStruct((N,), jnp.int32),
-        jax.ShapeDtypeStruct((N,), jnp.int32),
-        jax.ShapeDtypeStruct((N,), jnp.bool_),
-        jax.ShapeDtypeStruct((N, Wv), jnp.uint32),
-        jax.ShapeDtypeStruct((N, S, Wk), jnp.uint32),
-        jax.ShapeDtypeStruct((N, S, Wv), jnp.uint32),
-        jax.ShapeDtypeStruct((N, S), jnp.uint32),
-    )
-    out_specs = (
-        qspec1,
-        qspec1,
-        qspec1,
-        qspec1,
-        pl.BlockSpec((bq, Wv), lambda i: (i, 0)),
-        pl.BlockSpec((bq, S, Wk), lambda i: (i, 0, 0)),
-        pl.BlockSpec((bq, S, Wv), lambda i: (i, 0, 0)),
-        pl.BlockSpec((bq, S), lambda i: (i, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_xor_probe_kernel, k=k, slots=S,
-                          key_words=Wk, val_words=Wv, stagger=stagger),
-        grid=grid,
-        in_specs=[
-            qspec1,
-            qspec1,
-            pl.BlockSpec((bq, Wk), lambda i: (i, 0)),
-            full(store_keys.shape),
-            full(store_vals.shape),
-            full(store_valid.shape),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(bucket, port, qkeys, store_keys, store_vals, store_valid)
+    W = Wk + Wv + 1
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    flags, value, rem = pl.pallas_call(
+        functools.partial(_xor_probe_kernel, k=k, slots=S, key_words=Wk,
+                          val_words=Wv, n=N, stagger=stagger),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vmem, vmem, vmem],
+        out_specs=(vmem, vmem, vmem),
+        out_shape=(jax.ShapeDtypeStruct((4, N), jnp.int32),
+                   jax.ShapeDtypeStruct((Wv, N), jnp.uint32),
+                   jax.ShapeDtypeStruct((S * W, N), jnp.uint32)),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=pk.interpret_mode(interpret),
+    )(bucket.astype(jnp.int32), port.astype(jnp.int32)[None],
+      qkeys.astype(jnp.uint32).T,
+      pk.pack(store_keys, store_vals, store_valid))
+    rem = rem.reshape(W, S, N).transpose(2, 1, 0)          # [N, S, W]
+    return (flags[0] != 0, flags[1], flags[2], flags[3] != 0, value.T,
+            rem[..., :Wk], rem[..., Wk:Wk + Wv], rem[..., W - 1])

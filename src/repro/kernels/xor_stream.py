@@ -1,110 +1,62 @@
 """Pallas TPU kernel: fused in-kernel query streaming (DESIGN.md §3.1).
 
-The scanned path pays a full table HBM round-trip per step: every
-``lax.scan`` iteration launches ``xor_probe``, bounces ``ProbeResult`` /
-``MutationPlan`` through jnp elementwise stages, then launches ``xor_commit``.
-This kernel is the paper's PE pipeline proper — the table never leaves
-on-chip memory between cycles.  One ``pallas_call`` processes the whole
-``[T, N]`` query stream.  Per step the kernel fuses:
+The scanned path pays a full table round trip per step: every ``lax.scan``
+iteration launches ``xor_probe``, bounces ``ProbeResult``/``MutationPlan``
+through jnp stages, then launches ``xor_commit``.  This kernel is the
+paper's PE pipeline proper: one ``pallas_call`` processes the whole
+``[T, N]`` query stream while the table stays in VMEM.  Per step it fuses
 
-  probe    k-store read (vector gather over the tile's bucket axis)
-           + search XOR tree + slot resolution (match/open/stagger)
+  probe    k-store read of each lane's bucket + search XOR tree + slot
+           resolution (match / open / stagger)
   plan     op decode (insert/delete acceptance, slot choice)
-  encode   non-search XOR tree against the *pre-step* tile state
-  commit   supersession mask + stores of the surviving encodings
+  encode   non-search XOR tree against the *pre-step* table state
+  commit   per-lane stores of the encodings, in lane order
 
-Last-wins commit (the supersession-mask argument).  Same-step duplicate
-``(port, bucket, slot)`` write targets must resolve last-in-program-order,
-matching the jnp oracle's ``_scatter_records``.  Instead of making the
-store order carry the semantics, an ``[N, N]`` triangular same-target
-comparison marks every write lane that a LATER lane in the same step
-supersedes; surviving lanes then target pairwise-distinct cells, so the
-stores carry **no ordering constraint** — they can issue in any order or
-all at once.  The paper's PE array commits p writes per cycle for exactly
-this reason: conflict resolution happens before the write port, not at it.
-(The store phase itself stays a masked per-lane loop: XLA's gather/scatter
-on CPU costs ~6x a short store loop at these lane counts, and the loop is
-now order-free and, on the binned layout, work-proportional — it walks only
-the tile's own lane window.)
+Table layout: the kernel sees the lane-dense packed table of
+``kernels/packed.py`` (``[G, C, 128]``: bucket groups on the leading axis,
+plane/word/slot rows on sublanes, buckets on lanes).  The wrapper packs the
+``[k, B, S, W]`` stores before the call and unpacks after it.
 
-Two layouts share that dataflow:
+Last-wins commit.  Same-step duplicate ``(port, bucket, slot)`` write
+targets must resolve last-in-program-order, matching the jnp oracle's
+``_scatter_records``.  The commit walks its lanes in order, so a later lane
+overwrites an earlier one.  Lanes are sorted by sweep pass (below) with a
+stable sort, so two lanes that share a target (hence a bucket, hence a
+pass) keep their program order.
 
-**Per-step grid / unbinned** (``binned=False`` — the A/B baseline for both
-regimes, and the TPU default until the Mosaic caveat below lands),
-``grid = (bucket_tiles, T)`` with T minor.  The table tile is an ``input_output_aliases`` pair whose
-block index depends only on ``bt``: at ``t == 0`` the input tile is latched
-into the aliased output block, which stays VMEM-resident for all T
-consecutive steps (Pallas preserves output blocks across consecutive
-iterations with the same block index).  Every grid step masks the full
-N-lane row to its tile (``in_tile``) and emits per-tile results into
-``[BT, T, N]``, gathered by tile index outside the kernel.  Per-step query
-blocks are indexed by ``t``, so the standard Pallas pipeline double-buffers
-step t+1's queries while step t computes — the kernel-level expression of
-the FPGA's query FIFO.
+Sweep passes (the HashGraph bin-then-process move).  An XLA-side pre-pass
+stable-sorts each step's lanes by the pass that owns their bucket and
+hands the kernel a ``[P+1, T]`` table of per-(pass, step) lane offsets as
+a scalar-prefetch operand, together with the lanes' partition-relative
+buckets ``[T, N]``.  ``grid = (P,)``; grid step ``p``
 
-**Tile-binned** (``binned=True`` — the HashGraph bin-then-process move),
-``grid = (bin_passes,)``.  An XLA-side pre-pass stable-sorts each step's
-lanes by bucket tile (stable ⇒ sorted order within a tile == program order,
-so last-wins survives) and hands the kernel a ``[BT+1, T]`` table of
-per-(tile, step) lane offsets as a scalar-prefetch operand.  At
-``bucket_tiles == 1`` this degenerates to the single-pass in-kernel scan:
-the whole table is the span, the sort is the identity permutation, and the
-per-step grid dimension collapses to ONE grid iteration running all T steps
-as a ``lax.scan`` — the same collapse PR 4 applied to the blocked regime,
-now covering the VMEM-resident table too (one kernel launch per stream
-instead of T, which is also the fast path under ``interpret=True``).
+  * DMAs its span of ``G/P`` bucket groups from the HBM table into a VMEM
+    scratch buffer ONCE, runs all T steps against it with an in-kernel
+    loop, and DMAs it back once: one full-table round trip per stream;
+  * touches only its own lane window ``[offs[p, t], offs[p+1, t])`` per
+    step: gather and commit walk just those lanes, so their total work
+    equals the live lane count;
+  * reads queries from ONE packed ``[T, 1+Wk+Wv, N]`` operand (op|port|legal
+    word, key words, value words; lanes on the minor axis) and merges its
+    windows into a resident ``[T, 1+Wv, N]`` result block in sorted order —
+    the caller inverse-permutes back to program order.
 
-Bin granularity vs sweep passes: ``bucket_tiles`` fixes the BINNING (sort
-key, offsets table); ``bin_passes`` (a power-of-two divisor of it, sized by
-the caller from the VMEM budget — ``kernels.ops.xor_stream`` uses
-``min(bucket_tiles, stream_bucket_tiles(...))``) fixes how many
-residency-sized spans the kernel actually sweeps.  A tile sweep should
-coalesce adjacent tiles until the span fills on-chip memory — a genuinely
-HBM-oversized table sweeps every tile, while a budget-fitting table pinned
-to ``bucket_tiles=8`` runs one pass.  Because lanes are sorted by tile and
-tiles are contiguous in the bucket axis, a pass's lanes are one contiguous
-window ``[offs[p*W, t], offs[(p+1)*W, t])`` (``W = BT/bin_passes``), read
-straight from the same offsets table.  Grid step ``p`` then:
-
-  * loads its packed span ``[k, B/passes, S, Wk+Wv+1]`` from the ``ANY``/
-    HBM-resident table refs ONCE, runs all T steps as an in-kernel
-    ``lax.scan`` with the span as carry, and writes it back once — one
-    full-table round trip per stream, not per step;
-  * touches only its own lane window per step: the commit loop walks just
-    those lanes, so total commit work across passes equals the live lane
-    count (no BT-fold redundancy), and the probe/plan/encode dataflow runs
-    ``bin_passes * T`` times, not ``bucket_tiles * T``;
-  * reads queries from ONE packed ``[T, N, 2+Wk+Wv]`` operand (relative
-    bucket, op|port|legal word, key, value);
-  * merges results once per pass into a packed ``[T, N, 1+Wv]`` resident
-    output in routed (sorted) order — the ``[BT, T, N(,Wv)]`` output
-    inflation and the post-kernel tile-index gather disappear; the caller
-    inverse-permutes back to program order.
-
-Correctness of the sweep is unchanged: a lane's bucket determines both
-where it probes and where it commits, so mutations in one pass's span never
-touch another span; duplicate same-step write targets share a bucket, hence
-a tile, hence a pass, and within a tile the stable sort preserves program
-order (lanes of *different* tiles inside one pass can interleave, but they
-can never share a write target).
-
-TPU-lowering caveat (binned layout): the span load/store reads and writes
-the ``ANY``-space table refs with plain indexing; Mosaic requires explicit
-``pltpu.make_async_copy`` for HBM-resident refs, so compiling the binned
-kernel on a real TPU needs that (mechanical) substitution at the three
-load/store sites — untestable from this CPU container, where all kernels
-run under ``interpret=True`` (the repo-wide convention).  The unbinned
-layout uses only block-pipelined VMEM refs and has no such caveat.
+``P == 1`` is the VMEM-resident table (the whole table is the span).  A
+table larger than VMEM sweeps ``P > 1`` spans, each sized to the VMEM
+budget by the caller (``kernels.ops.xor_stream``).  A lane's bucket fixes
+both where it probes and where it commits, so mutations in one span never
+touch another.
 
 Bucket-base offset (the sharded regime, DESIGN.md §2): ``bucket_base`` is a
-*traced* scalar — under ``shard_map`` it is ``axis_index * local_buckets`` —
-marking the global bucket range ``[base, base + B)`` this table partition
-owns.  Lane buckets stay GLOBAL; the kernel probes/commits at ``bucket -
-base`` and lanes outside the partition are inert for every tile (no writes,
-found/ok False, value 0): the unbinned kernel masks them per tile, the
-binned pre-pass sorts them behind every real tile window (sentinel tile id
-BT) so no window ever covers them.  ``base == 0`` with a full table recovers
-the single-domain kernel bit-exactly.
+*traced* scalar — under ``shard_map`` it is ``axis_index * local_buckets``
+— marking the global bucket range ``[base, base + B)`` this table partition
+owns.  Lane buckets stay GLOBAL; lanes outside the partition sort behind
+every pass window (sentinel pass id P), so they are inert: no writes,
+found/ok False, value 0.  ``base == 0`` with a full table recovers the
+single-domain kernel bit-exactly.
+
+The kernel lowers under Mosaic for TPU and runs in Pallas interpret mode off
+the chip (``packed.interpret_mode`` picks which).
 """
 from __future__ import annotations
 
@@ -116,290 +68,104 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hash_table import OP_DELETE, OP_INSERT, OP_SEARCH
+from repro.kernels import packed as pk
 
 
-def _plan_lanes(op, legal, found, hopen, mslot, oslot, qk, qv, in_tile):
+def _plan_lanes(op, legal, found, hopen, mslot, oslot, qk, qv, active):
     """Mutation plan for one step's lanes (op decode + slot choice + new
-    record words + per-lane ok) — pure elementwise, shared by both kernel
-    layouts so op-acceptance semantics cannot drift between them.  Mirrors
+    record words + per-lane ok) on ``[1, n]`` rows.  Mirrors
     ``engine.mutation_plan`` exactly."""
     is_ins = op == OP_INSERT
     is_del = op == OP_DELETE
     ins_ok = is_ins & (found | hopen) & legal
     del_ok = is_del & found & legal
-    do_write = (ins_ok | del_ok) & in_tile
+    do_write = (ins_ok | del_ok) & active
     slot = jnp.where(is_del | found, mslot, oslot)
-    new_key = jnp.where(is_del[:, None], jnp.uint32(0), qk)
-    new_val = jnp.where(is_del[:, None], jnp.uint32(0), qv)
-    new_valid = jnp.where(is_del, jnp.uint32(0), jnp.uint32(1))
-    lane_ok = jnp.where(is_ins, ins_ok,
-                        jnp.where(is_del, del_ok, op == OP_SEARCH))
-    return do_write, slot, new_key, new_val, new_valid, lane_ok
+    new = jnp.concatenate([
+        jnp.where(is_del, jnp.uint32(0), qk),
+        jnp.where(is_del, jnp.uint32(0), qv),
+        jnp.where(is_del, jnp.uint32(0), jnp.uint32(1)).astype(jnp.uint32)],
+        axis=0)                                            # [Wtot, n]
+    # the three op classes are disjoint, so this is the oracle's nested where
+    lane_ok = ins_ok | del_ok | (op == OP_SEARCH)
+    return do_write, slot, new, lane_ok
 
 
-def _last_wins_survivors(do_write, port, local, slot, *,
-                         tile_buckets: int, slots: int):
-    """The vectorized last-wins pass: a write survives iff no LATER lane in
-    the same step targets the same ``(port, bucket, slot)`` cell — the same
-    key the jnp oracle's ``_scatter_records`` supersedes on.  ``[N, N]``
-    triangular comparison (N is small); survivors target pairwise-distinct
-    cells, so their stores need no ordering."""
-    n = do_write.shape[0]
-    tgt = (port * tile_buckets + local) * slots + slot     # [N] cell id
-    li = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)    # lane i (rows)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)    # lane j (cols)
-    later_same = (tgt[:, None] == tgt[None, :]) & do_write[None, :] & (lj > li)
-    return do_write & ~jnp.any(later_same, axis=1)
-
-
-# ---------------------------------------------------------------------------
-# Unbinned kernel: VMEM-resident pipelined tiles, full-N masking (and the
-# A/B baseline for the binned dispatch when bucket_tiles > 1)
-# ---------------------------------------------------------------------------
-
-def _xor_stream_kernel(bucket_ref, op_ref, port_ref, legal_ref, base_ref,
-                       qkey_ref, qval_ref, skeys_ref, svals_ref, svalid_ref,
-                       okeys_ref, ovals_ref, ovalid_ref,
-                       found_ref, ok_ref, value_ref,
-                       *, k: int, tile_buckets: int, buckets: int, n: int,
+def _xor_stream_kernel(offs_ref, bkt_ref, q_ref, tab_hbm, out_hbm, res_ref,
+                       span, sem, *, k: int, slots: int, key_words: int,
+                       val_words: int, span_groups: int, steps: int, n: int,
                        stagger: bool):
-    bt = pl.program_id(0)
-    t = pl.program_id(1)
-
-    # Latch the tile once per sweep; steps 1..T-1 reuse the VMEM-resident
-    # output block (same block index on consecutive iterations).
-    @pl.when(t == 0)
-    def _():
-        okeys_ref[...] = skeys_ref[...]
-        ovals_ref[...] = svals_ref[...]
-        ovalid_ref[...] = svalid_ref[...]
-
-    bucket = bucket_ref[0].astype(jnp.int32)               # [N] GLOBAL index
-    op = op_ref[0]                                         # [N]
-    port = port_ref[0].astype(jnp.int32)                   # [N] (step t's row)
-    legal = legal_ref[0] != 0                              # [N]
-    # partition-relative bucket: lanes outside [base, base + buckets) never
-    # claim a tile, so they are inert (router pads / foreign shards)
-    rel = bucket - base_ref[0]
-    in_part = (rel >= 0) & (rel < buckets)
-    rel_c = jnp.clip(rel, 0, buckets - 1)
-    in_tile = in_part & ((rel_c // tile_buckets) == bt)
-    local = jnp.clip(rel_c - bt * tile_buckets, 0, tile_buckets - 1)
-
-    # step-t snapshot of this tile == output refs after steps 0..t-1
-    sk = okeys_ref[...]                                    # [k, Bt, S, Wk]
-    sv = ovals_ref[...]
-    sb = ovalid_ref[...]
-    key_words = sk.shape[-1]
-    slots = sk.shape[2]
-
-    # --- probe: parallel partial-store read + search XOR trees --------------
-    rows_k = jnp.take(sk, local, axis=1)                   # [k, N, S, Wk]
-    rows_v = jnp.take(sv, local, axis=1)
-    rows_b = jnp.take(sb, local, axis=1)
-
-    def xtree(x):                                          # static fold over k
-        acc = x[0]
-        for i in range(1, k):
-            acc = acc ^ x[i]
-        return acc
-
-    dec_k = xtree(rows_k)                                  # [N, S, Wk]
-    dec_v = xtree(rows_v)                                  # [N, S, Wv]
-    dec_b = xtree(rows_b)                                  # [N, S]
-
-    qk = qkey_ref[0]                                       # [N, Wk]
-    qv = qval_ref[0]                                       # [N, Wv]
-    key_eq = jnp.ones(dec_b.shape, dtype=jnp.bool_)
-    for w in range(key_words):
-        key_eq = key_eq & (dec_k[..., w] == qk[:, None, w])
-    occ = (dec_b & 1).astype(jnp.bool_)
-    match = key_eq & occ                                   # [N, S]
-    found = jnp.any(match, axis=-1)
-    mslot = jnp.argmax(match, axis=-1).astype(jnp.int32)
-    open_mask = ~occ
-    hopen = jnp.any(open_mask, axis=-1)
-    if stagger:
-        from repro.core.engine import staggered_open_slot
-        oslot = staggered_open_slot(open_mask, port)
-    else:
-        oslot = jnp.argmax(open_mask, axis=-1).astype(jnp.int32)
-    value = jnp.take_along_axis(dec_v, mslot[:, None, None], axis=1)[:, 0]
-    value = jnp.where(found[:, None], value, jnp.uint32(0))
-
-    # non-search XOR tree basis: XOR of all stores except the own port
-    own_k = jnp.take_along_axis(rows_k, port[None, :, None, None], axis=0)[0]
-    own_v = jnp.take_along_axis(rows_v, port[None, :, None, None], axis=0)[0]
-    own_b = jnp.take_along_axis(rows_b, port[None, :, None], axis=0)[0]
-    rem_k = dec_k ^ own_k                                  # [N, S, Wk]
-    rem_v = dec_v ^ own_v
-    rem_b = dec_b ^ own_b
-
-    # --- plan: op decode + slot choice (mutation_plan, in-kernel) -----------
-    do_write, slot, new_key, new_val, new_valid, lane_ok = _plan_lanes(
-        op, legal, found, hopen, mslot, oslot, qk, qv, in_tile)
-
-    # --- encode: non-search XOR tree output for the chosen slot -------------
-    enc_k = new_key ^ jnp.take_along_axis(rem_k, slot[:, None, None],
-                                          axis=1)[:, 0]
-    enc_v = new_val ^ jnp.take_along_axis(rem_v, slot[:, None, None],
-                                          axis=1)[:, 0]
-    enc_b = new_valid ^ jnp.take_along_axis(rem_b, slot[:, None], axis=1)[:, 0]
-
-    # --- per-tile results (gathered by tile index outside the kernel) -------
-    found_ref[0, 0] = found & in_tile
-    ok_ref[0, 0] = lane_ok & in_tile
-    value_ref[0, 0] = jnp.where((found & in_tile)[:, None], value,
-                                jnp.uint32(0))
-
-    # --- commit: supersession mask, then order-free masked stores -----------
-    surv = _last_wins_survivors(do_write, port, local, slot,
-                                tile_buckets=tile_buckets, slots=slots)
-
-    def body(i, carry):
-        @pl.when(surv[i])
-        def _():
-            pt, bk, sl = port[i], local[i], slot[i]
-            okeys_ref[pt, bk, sl, :] = jax.lax.dynamic_index_in_dim(
-                enc_k, i, 0, keepdims=False)
-            ovals_ref[pt, bk, sl, :] = jax.lax.dynamic_index_in_dim(
-                enc_v, i, 0, keepdims=False)
-            ovalid_ref[pt, bk, sl] = enc_b[i]
-        return carry
-
-    jax.lax.fori_loop(0, n, body, 0)
-
-
-# ---------------------------------------------------------------------------
-# Binned kernel: HBM-resident table, one tile sweep per grid step, the T-step
-# loop fused as an in-kernel scan over the packed tile
-# ---------------------------------------------------------------------------
-
-def _xor_stream_binned_kernel(offs_ref, q_ref,
-                              skeys_ref, svals_ref, svalid_ref,
-                              okeys_ref, ovals_ref, ovalid_ref, out_ref,
-                              *, k: int, span_buckets: int,
-                              tiles_per_pass: int, n: int,
-                              key_words: int, val_words: int,
-                              slots: int, stagger: bool):
     p = pl.program_id(0)
-    Bs = span_buckets
-    Wk, Wv, S = key_words, val_words, slots
-    wtot = Wk + Wv + 1
+    S, Wk, Wv = slots, key_words, val_words
+    W = Wk + Wv + 1
+    g0 = p * span_groups
+    b0 = g0 * pk.LANES
 
-    # span DMA: HBM -> packed on-chip value once per pass, back once — the
-    # stream's only full-table traffic
-    tile0 = jnp.concatenate([
-        skeys_ref[:, pl.ds(p * Bs, Bs)],
-        svals_ref[:, pl.ds(p * Bs, Bs)],
-        svalid_ref[:, pl.ds(p * Bs, Bs)][..., None],
-    ], axis=-1)                                            # [k, Bs, S, Wtot]
-
-    # this pass's per-step lane windows: sorted-by-tile lanes make a pass's
-    # tiles one contiguous range in the offsets table (scalar prefetch)
-    off_t = offs_ref[p * tiles_per_pass]                   # [T]
-    end_t = offs_ref[(p + 1) * tiles_per_pass]             # [T]
-    q_all = q_ref[...]                                     # [T, N, 2+Wk+Wv]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
-
-    def step(tile, xs):
-        q, off, end = xs
-        active = (pos >= off) & (pos < end)
-        rel = q[:, 0].astype(jnp.int32)                    # partition-relative
-        opw = q[:, 1].astype(jnp.int32)
-        op = opw & 0xFF
-        port = (opw >> 8) & 0xFF
-        legal = ((opw >> 16) & 1) != 0
-        qk = q[:, 2:2 + Wk]
-        qv = q[:, 2 + Wk:]
-        local = jnp.clip(rel - p * Bs, 0, Bs - 1)
-
-        # probe: ONE packed gather + XOR trees (decode componentwise)
-        rows = jnp.take(tile, local, axis=1)               # [k, N, S, Wtot]
-        dec = rows[0]
-        for i in range(1, k):
-            dec = dec ^ rows[i]
-        dec_k = dec[..., :Wk]
-        dec_v = dec[..., Wk:Wk + Wv]
-        dec_b = dec[..., -1]
-        key_eq = jnp.ones(dec_b.shape, dtype=jnp.bool_)
-        for w in range(Wk):
-            key_eq = key_eq & (dec_k[..., w] == qk[:, None, w])
-        occ = (dec_b & 1).astype(jnp.bool_)
-        match = key_eq & occ
-        found = jnp.any(match, axis=-1)
-        mslot = jnp.argmax(match, axis=-1).astype(jnp.int32)
-        open_mask = ~occ
-        hopen = jnp.any(open_mask, axis=-1)
-        if stagger:
-            from repro.core.engine import staggered_open_slot
-            oslot = staggered_open_slot(open_mask, port)
-        else:
-            oslot = jnp.argmax(open_mask, axis=-1).astype(jnp.int32)
-        value = jnp.take_along_axis(dec_v, mslot[:, None, None], axis=1)[:, 0]
-        value = jnp.where(found[:, None], value, jnp.uint32(0))
-
-        own = jnp.take_along_axis(rows, port[None, :, None, None], axis=0)[0]
-        rem = dec ^ own                                    # [N, S, Wtot]
-
-        # plan (shared with the unbinned kernel; in_tile := active window)
-        do_write, slot, new_key, new_val, new_valid, lane_ok = _plan_lanes(
-            op, legal, found, hopen, mslot, oslot, qk, qv, active)
-
-        # encode: packed non-search XOR output for the chosen slot
-        new = jnp.concatenate([new_key, new_val, new_valid[:, None]], axis=-1)
-        enc = new ^ jnp.take_along_axis(rem, slot[:, None, None], axis=1)[:, 0]
-
-        # commit: supersession mask, then a work-proportional store loop over
-        # ONLY this pass's lane window (order-free: survivors are distinct)
-        surv = _last_wins_survivors(do_write, port, local, slot,
-                                    tile_buckets=Bs, slots=S)
-
-        def commit(i, tile):
-            cur = jax.lax.dynamic_slice(
-                tile, (port[i], local[i], slot[i], 0), (1, 1, 1, wtot))
-            row = jnp.where(surv[i], enc[i].reshape(1, 1, 1, wtot), cur)
-            return jax.lax.dynamic_update_slice(
-                tile, row, (port[i], local[i], slot[i], 0))
-
-        tile = jax.lax.fori_loop(off, end, commit, tile)
-
-        res = jnp.concatenate(
-            [(found.astype(jnp.uint32) | (lane_ok.astype(jnp.uint32) << 1)
-              )[:, None], value], axis=-1)
-        return tile, jnp.where(active[:, None], res, jnp.uint32(0))
-
-    tile, res = jax.lax.scan(step, tile0, (q_all, off_t, end_t))
-
-    # merge this pass's lane windows into the resident packed result buffer:
-    # every (step, lane) cell belongs to exactly one pass, sentinel-binned
-    # (out-of-partition) lanes to none — zero == inert
-    mask = (pos[None, :] >= off_t[:, None]) & (pos[None, :] < end_t[:, None])
+    load = pltpu.make_async_copy(tab_hbm.at[pl.ds(g0, span_groups)], span,
+                                 sem)
+    load.start()
+    load.wait()
 
     @pl.when(p == 0)
     def _():
-        out_ref[...] = res
+        res_ref[...] = jnp.zeros(res_ref.shape, res_ref.dtype)
 
-    @pl.when(p > 0)
-    def _():
-        out_ref[...] = jnp.where(mask[..., None], res, out_ref[...])
+    col = pk.lane_ids(1, n)
 
-    okeys_ref[:, pl.ds(p * Bs, Bs)] = tile[..., :Wk]
-    ovals_ref[:, pl.ds(p * Bs, Bs)] = tile[..., Wk:Wk + Wv]
-    ovalid_ref[:, pl.ds(p * Bs, Bs)] = tile[..., wtot - 1]
+    def step(t, carry):
+        off = offs_ref[p, t]
+        end = offs_ref[p + 1, t]
+        cols = pk.gather(span, lambda i: bkt_ref[t, i] - b0, off, end, n)
+        q = q_ref[t]                                       # [1+Wk+Wv, n]
+        opw = pk.i32(q[0:1])
+        op = opw & 0xFF
+        port = (opw >> 8) & 0xFF
+        legal = ((opw >> 16) & 1) != 0
+        qk, qv = q[1:1 + Wk], q[1 + Wk:]
+        active = (col >= off) & (col < end)
+
+        found, mslot, oslot, hopen, value, rem = pk.probe(
+            cols, qk, port, k=k, slots=S, key_words=Wk, val_words=Wv,
+            stagger=stagger)
+        do_write, slot, new, lane_ok = _plan_lanes(
+            op, legal, found, hopen, mslot, oslot, qk, qv, active)
+        enc = pk.i32(new ^ pk.select_slot(rem, slot, S))   # [Wtot, n]
+
+        flags = found.astype(jnp.uint32) | (lane_ok.astype(jnp.uint32) << 1)
+        res = jnp.concatenate([flags, value], axis=0)      # [1+Wv, n]
+        res_ref[t] = jnp.where(active, res, res_ref[t])
+
+        dw = do_write.astype(jnp.int32)
+
+        def commit(i, c):
+            b = bkt_ref[t, i] - b0
+            e = pk.pick_lane(enc, i)                       # [Wtot, 1]
+            pk.write_slot(span.at[b // pk.LANES], b % pk.LANES,
+                          pk.pick_scalar(port, i), pk.pick_scalar(slot, i),
+                          [e[w:w + 1] for w in range(W)], slots=S, words=W,
+                          enable=pk.pick_scalar(dw, i) != 0)
+            return c
+
+        jax.lax.fori_loop(off, end, commit, 0)
+        return carry
+
+    jax.lax.fori_loop(0, steps, step, 0)
+
+    store = pltpu.make_async_copy(span, out_hbm.at[pl.ds(g0, span_groups)],
+                                  sem)
+    store.start()
+    store.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("bucket_tiles", "interpret",
-                                             "stagger", "binned",
-                                             "bin_passes"))
+@functools.partial(jax.jit, static_argnames=("interpret", "stagger",
+                                             "passes", "vmem_limit"))
 def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
                       legal: jnp.ndarray, ops: jnp.ndarray,
                       qkeys: jnp.ndarray, qvals: jnp.ndarray,
                       store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-                      store_valid: jnp.ndarray, bucket_tiles: int = 1,
-                      interpret: bool = True, stagger: bool = False,
-                      bucket_base=0, binned: bool = True,
-                      bin_passes: int = 1):
+                      store_valid: jnp.ndarray, passes: int = 1,
+                      interpret=None, stagger: bool = False,
+                      bucket_base=0, vmem_limit: int | None = None):
     """Stream T steps of N queries through one fused kernel.
 
     bucket/ops ``[T, N]``; port/legal ``[N]`` (step-invariant lanes) or
@@ -407,152 +173,84 @@ def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
     routed slot's origin varies by step); qkeys ``[T, N, Wk]``;
     qvals ``[T, N, Wv]``; store_* one replica ``[k, B, S, W*]``.  Returns
     ``(store_keys', store_vals', store_valid', found[T, N] bool,
-    ok[T, N] bool, value[T, N, Wv])``.  ``bucket_tiles`` must be a
-    power-of-two divisor of B (1 == fully VMEM-resident table).
-    ``bucket_base`` (traced scalar) marks the global bucket range this
-    table partition owns; lanes outside ``[base, base + B)`` are inert.
-    ``binned`` selects the tile-binned dispatch (sorted lanes, windowed
-    sweep, in-kernel step scan; at ``bucket_tiles == 1`` the degenerate
-    single-pass form whose grid is ONE iteration scanning all T steps);
-    ``binned=False`` keeps the per-step-grid mask-all-N baseline.
-    ``bin_passes`` (binned only) is the number of residency-sized sweep
-    passes — a power-of-two divisor of ``bucket_tiles``, sized from the
-    VMEM budget by ``kernels.ops.xor_stream`` (module docstring).
+    ok[T, N] bool, value[T, N, Wv])``.  ``bucket_base`` (traced scalar)
+    marks the global bucket range this table partition owns; lanes outside
+    ``[base, base + B)`` are inert.  ``passes`` — a divisor of the packed
+    table's bucket-group count, sized from the VMEM budget by
+    ``kernels.ops.stream_bucket_tiles`` — is the number of sweep passes
+    (module docstring).
     """
     T, N = ops.shape
     k, B, S, Wk = store_keys.shape
     Wv = store_vals.shape[-1]
-    BT = bucket_tiles
-    if BT < 1 or B % BT:
-        raise ValueError(f"bucket_tiles={BT} must divide buckets={B}")
-    if bin_passes < 1 or BT % bin_passes:
-        raise ValueError(f"bin_passes={bin_passes} must divide "
-                         f"bucket_tiles={BT}")
-    Bt = B // BT
-    base = jnp.reshape(jnp.asarray(bucket_base).astype(jnp.int32), (1,))
+    G = pk.packed_groups(B)
+    if passes < 1 or G % passes:
+        raise ValueError(f"passes={passes} must divide the {G} bucket groups")
     if T == 0:
         return (store_keys, store_vals, store_valid,
                 jnp.zeros((0, N), jnp.bool_), jnp.zeros((0, N), jnp.bool_),
                 jnp.zeros((0, N, Wv), jnp.uint32))
+    P = passes
+    Gs = G // P
+    base = jnp.asarray(bucket_base).astype(jnp.int32)
     if port.ndim == 1:
         port = jnp.broadcast_to(port[None], (T, N))
     if legal.ndim == 1:
         legal = jnp.broadcast_to(legal[None], (T, N))
 
-    if binned:
-        # ---- XLA-side pre-pass: stable-sort each step's lanes by tile ----
-        rel = bucket.astype(jnp.int32) - base[0]
-        in_part = (rel >= 0) & (rel < B)
-        tile_id = jnp.where(in_part, jnp.clip(rel, 0, B - 1) // Bt, BT)
-        perm = jnp.argsort(tile_id, axis=1, stable=True)        # [T, N]
-        # offs[j, t] == #lanes of step t with tile id < j (so tile bt's
-        # window is [offs[bt, t], offs[bt+1, t]) and sentinel lanes fall
-        # past every window)
-        offs = jnp.sum(tile_id[:, :, None] <
-                       jnp.arange(1, BT + 1, dtype=jnp.int32)[None, None, :],
-                       axis=1, dtype=jnp.int32)
-        offs = jnp.concatenate([jnp.zeros((T, 1), jnp.int32), offs],
-                               axis=1).T                        # [BT+1, T]
-        opw = (ops.astype(jnp.uint32) & 0xFF) \
-            | (port.astype(jnp.uint32) << 8) \
-            | (legal.astype(jnp.uint32) << 16)
-        q = jnp.concatenate([
-            jnp.where(in_part, rel, 0).astype(jnp.uint32)[..., None],
-            opw[..., None],
-            qkeys.astype(jnp.uint32), qvals.astype(jnp.uint32)], axis=-1)
-        q_s = jnp.take_along_axis(q, perm[..., None], axis=1)
+    # ---- XLA-side pre-pass: stable-sort each step's lanes by sweep pass ----
+    rel = bucket.astype(jnp.int32) - base
+    in_part = (rel >= 0) & (rel < B)
+    pass_id = jnp.where(in_part, rel // (Gs * pk.LANES), P)
+    perm = jnp.argsort(pass_id, axis=1, stable=True)             # [T, N]
+    # offs[j, t] == #lanes of step t with pass id < j: pass p's window is
+    # [offs[p, t], offs[p+1, t]) and sentinel lanes fall past every window
+    offs = jnp.sum(pass_id[:, :, None]
+                   < jnp.arange(1, P + 1, dtype=jnp.int32)[None, None, :],
+                   axis=1, dtype=jnp.int32)
+    offs = jnp.concatenate([jnp.zeros((T, 1), jnp.int32), offs], axis=1).T
+    opw = (ops.astype(jnp.uint32) & 0xFF) \
+        | (port.astype(jnp.uint32) << 8) \
+        | (legal.astype(jnp.uint32) << 16)
+    q = jnp.concatenate([opw[..., None], qkeys.astype(jnp.uint32),
+                         qvals.astype(jnp.uint32)], axis=-1)     # [T, N, Wq]
+    q_s = jnp.take_along_axis(q, perm[..., None], axis=1).transpose(0, 2, 1)
+    bkt_s = jnp.take_along_axis(jnp.where(in_part, rel, 0), perm, axis=1)
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(bin_passes,),
-            in_specs=[
-                pl.BlockSpec((T, N, 2 + Wk + Wv), lambda p, offs: (0, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),      # HBM-resident
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=(
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec((T, N, 1 + Wv), lambda p, offs: (0, 0, 0)),
-            ),
-        )
-        out_shapes = (
-            jax.ShapeDtypeStruct(store_keys.shape, store_keys.dtype),
-            jax.ShapeDtypeStruct(store_vals.shape, store_vals.dtype),
-            jax.ShapeDtypeStruct(store_valid.shape, store_valid.dtype),
-            jax.ShapeDtypeStruct((T, N, 1 + Wv), jnp.uint32),
-        )
-        sk, sv, sb, out = pl.pallas_call(
-            functools.partial(_xor_stream_binned_kernel, k=k,
-                              span_buckets=B // bin_passes,
-                              tiles_per_pass=BT // bin_passes,
-                              n=N, key_words=Wk, val_words=Wv,
-                              slots=S, stagger=stagger),
-            grid_spec=grid_spec, out_shape=out_shapes,
-            # the table updates in place — fresh HBM buffers would double
-            # the stream's only full-table traffic
-            input_output_aliases={2: 0, 3: 1, 4: 2},
-            interpret=interpret,
-        )(offs, q_s, store_keys, store_vals, store_valid)
-
-        inv = jnp.argsort(perm, axis=1)                    # sorted -> program
-        out = jnp.take_along_axis(out, inv[..., None], axis=1)
-        found = (out[..., 0] & 1) != 0
-        ok = (out[..., 0] >> 1) != 0
-        return sk, sv, sb, found, ok, out[..., 1:]
-
-    grid = (BT, T)
-    qspec2 = pl.BlockSpec((1, N), lambda bt, t: (t, 0))
-    base1 = pl.BlockSpec((1,), lambda bt, t: (0,))
-    tile = lambda shape: pl.BlockSpec(
-        (shape[0], Bt) + shape[2:],
-        lambda bt, t: (0, bt) + (0,) * (len(shape) - 2))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct(store_keys.shape, store_keys.dtype),
-        jax.ShapeDtypeStruct(store_vals.shape, store_vals.dtype),
-        jax.ShapeDtypeStruct(store_valid.shape, store_valid.dtype),
-        jax.ShapeDtypeStruct((BT, T, N), jnp.bool_),
-        jax.ShapeDtypeStruct((BT, T, N), jnp.bool_),
-        jax.ShapeDtypeStruct((BT, T, N, Wv), jnp.uint32),
-    )
-    out_specs = (
-        tile(store_keys.shape), tile(store_vals.shape), tile(store_valid.shape),
-        pl.BlockSpec((1, 1, N), lambda bt, t: (bt, t, 0)),
-        pl.BlockSpec((1, 1, N), lambda bt, t: (bt, t, 0)),
-        pl.BlockSpec((1, 1, N, Wv), lambda bt, t: (bt, t, 0, 0)),
-    )
-    sk, sv, sb, found_full, ok_full, value_full = pl.pallas_call(
-        functools.partial(_xor_stream_kernel, k=k, tile_buckets=Bt, buckets=B,
-                          n=N, stagger=stagger),
-        grid=grid,
+    tab = pk.pack(store_keys, store_vals, store_valid)
+    C = tab.shape[1]
+    Wq = 1 + Wk + Wv
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(P,),
         in_specs=[
-            qspec2,                                        # bucket
-            qspec2,                                        # op
-            qspec2,                                        # port (per-step row)
-            qspec2,                                        # legal
-            base1,                                         # bucket_base
-            pl.BlockSpec((1, N, Wk), lambda bt, t: (t, 0, 0)),
-            pl.BlockSpec((1, N, Wv), lambda bt, t: (t, 0, 0)),
-            tile(store_keys.shape), tile(store_vals.shape),
-            tile(store_valid.shape),
+            pl.BlockSpec((T, Wq, N), lambda p, offs, bkt: (0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),             # HBM-resident
         ],
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        # the table updates in place — without aliasing every tile sweep
-        # would round-trip the full table through fresh output buffers
-        input_output_aliases={7: 0, 8: 1, 9: 2},
-        interpret=interpret,
-    )(bucket.astype(jnp.uint32), ops.astype(jnp.int32),
-      port.astype(jnp.int32), legal.astype(jnp.int32), base, qkeys, qvals,
-      store_keys, store_vals, store_valid)
+        out_specs=(
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((T, 1 + Wv, N), lambda p, offs, bkt: (0, 0, 0)),
+        ),
+        scratch_shapes=[pltpu.VMEM((Gs, C, pk.LANES), jnp.uint32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    tab, out = pl.pallas_call(
+        functools.partial(_xor_stream_kernel, k=k, slots=S, key_words=Wk,
+                          val_words=Wv, span_groups=Gs, steps=T, n=N,
+                          stagger=stagger),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct(tab.shape, tab.dtype),
+                   jax.ShapeDtypeStruct((T, 1 + Wv, N), jnp.uint32)),
+        # the table updates in place — a fresh HBM buffer would double the
+        # stream's only full-table traffic
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_limit),
+        interpret=pk.interpret_mode(interpret),
+    )(offs, bkt_s, q_s, tab)
 
-    # every lane's real result lives in its bucket's tile (out-of-partition
-    # lanes are masked False/0 in every tile, so any gather index works)
-    rel = jnp.clip(bucket.astype(jnp.int32) - base[0], 0, B - 1)
-    tile_idx = (rel // Bt)[None]                           # [1, T, N]
-    found = jnp.take_along_axis(found_full, tile_idx, axis=0)[0]
-    ok = jnp.take_along_axis(ok_full, tile_idx, axis=0)[0]
-    value = jnp.take_along_axis(value_full, tile_idx[..., None], axis=0)[0]
-    return sk, sv, sb, found, ok, value
+    sk, sv, sb = pk.unpack(tab, k, B, S, Wk, Wv)
+    inv = jnp.argsort(perm, axis=1)                        # sorted -> program
+    out = jnp.take_along_axis(out, inv[:, None, :], axis=2)
+    found = (out[:, 0] & 1) != 0
+    ok = ((out[:, 0] >> 1) & 1) != 0
+    return sk, sv, sb, found, ok, out[:, 1:].transpose(0, 2, 1)

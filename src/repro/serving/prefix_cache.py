@@ -110,8 +110,7 @@ class PrefixCache:
         else:
             self.table = init_table(self.cfg, jax.random.key(seed))
             self._stream = jax.jit(engine.run_stream,
-                                   static_argnames=("backend", "fused",
-                                                    "bucket_tiles", "binned"))
+                                   static_argnames=("backend", "fused"))
         self.block_tokens = block_tokens
         self.free_pages: List[int] = list(range(num_pages - 1, -1, -1))
         self.lru: Dict[int, int] = {}       # key64 -> last-touch counter

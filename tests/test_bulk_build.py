@@ -92,16 +92,30 @@ def test_bulk_build_matches_serialized_insert_oracle(backend, rng, key):
     assert (rep.spill_indices() == np.nonzero(~oracle_ok)[0]).all()
 
 
-@pytest.mark.parametrize("tiles", [2, 4])
-def test_bulk_build_pallas_multipass_bit_exact(tiles, rng, key):
-    """Blocked tables: the binned placement kernel sweeps the plane in
-    ``tiles`` residency-sized passes and must stay byte-identical."""
-    cfg = _cfg()
-    keys, vals = _records(rng, 300, cfg)
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_bulk_build_pallas_multipass_bit_exact(chunk, rng, key):
+    """Multi-group planes: the placement kernel streams its records through
+    SMEM in ``chunk``-record grid steps and moves each touched 128-bucket
+    group through VMEM once — byte-identical to the jnp scatter, and
+    ``bulk_build`` end to end to the jnp backend."""
+    from repro.core.engine import get_backend, plan_bulk_build
+    from repro.core.hashing import h3_hash
+    from repro.kernels.bulk_place import bulk_place_pallas
+    cfg = _cfg(buckets=512)
+    keys, vals = _records(rng, 300, cfg, key_space=400)
     ref, _ = bulk_build(init_table(cfg, key), keys, vals, backend="jnp")
-    tab, _ = bulk_build(init_table(cfg, key), keys, vals, backend="pallas",
-                        bucket_tiles=tiles)
-    _assert_tables_equal(tab, ref, tiles)
+    tab, _ = bulk_build(init_table(cfg, key), keys, vals, backend="pallas")
+    _assert_tables_equal(tab, ref, chunk)
+    t0 = init_table(cfg, key)
+    plan = plan_bulk_build(jnp.array(keys), jnp.array(vals),
+                           h3_hash(jnp.array(keys), t0.q_masks),
+                           buckets=cfg.buckets, slots=cfg.slots)
+    planes = (t0.store_keys[0, 0], t0.store_vals[0, 0], t0.store_valid[0, 0])
+    args = (plan["w_bucket"], plan["w_slot"], jnp.array(keys), plan["val_w"])
+    want = get_backend("jnp").bulk_place(*planes, *args)
+    got = bulk_place_pallas(*args, *planes, chunk=chunk)
+    for x, y in zip(got, want):
+        assert (np.asarray(x) == np.asarray(y)).all(), chunk
 
 
 def test_bulk_build_empty_batch(key):

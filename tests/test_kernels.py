@@ -69,7 +69,7 @@ def test_xor_probe_kernel_sweep(k, slots, kw, vw, rng):
     port = jnp.array(rng.integers(0, k, N, dtype=np.int32))
     args = (bucket, port, jnp.array(qkeys), tab.store_keys[0],
             tab.store_vals[0], tab.store_valid[0])
-    outs_k = xor_probe_pallas(*args, block_q=64)
+    outs_k = xor_probe_pallas(*args)
     outs_r = probe_jnp(args[0], args[1], args[2], args[3][None], args[4][None],
                        args[5][None])
     names = ["found", "mslot", "oslot", "hopen", "value", "remk", "remv",
@@ -83,7 +83,8 @@ def test_xor_probe_kernel_sweep(k, slots, kw, vw, rng):
 
 
 def test_ops_wrappers_fallback(rng):
-    """ops.py falls back to the jnp oracle for non-divisible batch sizes."""
+    """ops.h3_hash pads a batch that is not a whole number of kernel blocks
+    and still matches the jnp oracle."""
     q = jnp.array(rng.integers(0, 2 ** 32, size=(8, 1), dtype=np.uint32))
     keys = jnp.array(rng.integers(0, 2 ** 32, size=(77, 1), dtype=np.uint32))
     out = h3_op(keys, q)                         # 77 not divisible

@@ -68,8 +68,7 @@ def test_step_bytes_scales():
 def test_stream_model_regime_ordering():
     """The stream model's terms order the regimes the way the kernels do
     (DESIGN.md §3.1): vectorized commit beats serial, fused beats the
-    scanned per-step dispatch, binned beats unbinned in the blocked regime,
-    and the blocked sweep amortizes with T."""
+    scanned per-step dispatch, and the blocked sweep amortizes with T."""
     cfg = HashTableConfig(p=8, k=8, buckets=1 << 12, slots=4,
                           replicate_reads=False, queries_per_pe=8)
     assert stream_commit_seconds(cfg, vectorized=True) < \
@@ -79,8 +78,6 @@ def test_stream_model_regime_ordering():
     assert stream_modeled_mops(cfg, steps=32) > \
         stream_modeled_mops(cfg, steps=32, vectorized_commit=False,
                             fused=False)
-    assert stream_modeled_mops(cfg, steps=32, bucket_tiles=8, binned=True) > \
-        stream_modeled_mops(cfg, steps=32, bucket_tiles=8, binned=False)
     assert stream_modeled_mops(cfg, steps=32, bucket_tiles=8) > \
         stream_modeled_mops(cfg, steps=2, bucket_tiles=8)
 

@@ -71,7 +71,6 @@ def test_compressed_psum_multidev():
     script = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim import init_ef, make_compressed_psum
         mesh = jax.make_mesh((4,), ('dp',))
         g = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0
@@ -80,8 +79,8 @@ def test_compressed_psum_multidev():
         def f(gs, err):
             red, ef2 = cpsum({'w': gs}, type(ef)(err={'w': err}))
             return red['w'], ef2.err['w']
-        out, _ = shard_map(f, mesh=mesh, in_specs=(P('dp'), P('dp')),
-                           out_specs=(P('dp'), P('dp')))(
+        out, _ = jax.shard_map(f, mesh=mesh, in_specs=(P('dp'), P('dp')),
+                               out_specs=(P('dp'), P('dp')))(
             g, jnp.zeros_like(g))
         want = np.asarray(g).sum(0)
         got = np.asarray(out)[0]

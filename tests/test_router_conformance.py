@@ -141,7 +141,6 @@ CARRY = textwrap.dedent("""
 ROUNDTRIP = textwrap.dedent("""
     import sys
     import numpy as np, jax, jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import *
     from repro.core.distributed import *
@@ -190,10 +189,10 @@ ROUNDTRIP = textwrap.dedent("""
             return tuple(engine.inverse_route_bounded('ht', carry, *routed))
 
         for name, fn in (('skewproof', skew_rt), ('bounded', bounded_rt)):
-            rt = shard_map(fn, mesh=mesh,
-                           in_specs=(P(None, 'ht'),) * 3,
-                           out_specs=(P(None, 'ht'),) * 3,
-                           check_rep=False)
+            rt = jax.shard_map(fn, mesh=mesh,
+                               in_specs=(P(None, 'ht'),) * 3,
+                               out_specs=(P(None, 'ht'),) * 3,
+                               check_vma=False)
             o2, k2, v2 = rt(ops, keys, vals)
             assert (np.asarray(o2) == np.asarray(ops)).all(), (kind, name)
             assert (np.asarray(k2) == np.asarray(keys)).all(), (kind, name)

@@ -2,7 +2,7 @@
 bit-exact with the scanned per-step jnp oracle — same per-step StepResults
 AND same final table — on long randomized S/I/U/D traces, for both replica
 layouts, stagger on/off, and tables below/above the VMEM budget (the
-bucket-blocked path).  Also covers the StreamBackend dispatch and the
+multi-pass sweep).  Also covers the StreamBackend dispatch and the
 replica_bytes / stream_bucket_tiles helpers."""
 import dataclasses
 
@@ -29,12 +29,12 @@ def _assert_same(tab_a, res_a, tab_b, res_b, what=""):
             f"{what}: table.{name} diverged ({(a != b).sum()} words)"
 
 
-def _oracle_and_fused(cfg, ops, kk, vv, seed=0, binned=None):
+def _oracle_and_fused(cfg, ops, kk, vv, seed=0):
     tab = init_table(cfg, jax.random.key(seed))
     oj = run_stream(tab, jnp.array(ops), jnp.array(kk), jnp.array(vv),
                     backend="jnp", fused=False)
     of = run_stream(tab, jnp.array(ops), jnp.array(kk), jnp.array(vv),
-                    fused=True, binned=binned)
+                    fused=True)
     return oj, of
 
 
@@ -53,45 +53,60 @@ def test_fused_stream_bit_exact_on_random_trace(replicate, stagger, kw,
                  f"replicate={replicate} stagger={stagger} kw={kw}")
 
 
-@pytest.mark.parametrize("binned", [True, False])
+@pytest.mark.parametrize("buckets", [512, 1024])
 @pytest.mark.parametrize("stagger", [False, True])
-def test_fused_stream_bucket_blocked_bit_exact(stagger, binned, trace_gen,
+def test_fused_stream_bucket_blocked_bit_exact(stagger, buckets, trace_gen,
                                                monkeypatch):
-    """Tables above the VMEM budget run the bucket-blocked kernel — the
-    tile-binned dispatch (multi-pass sweep: the shrunken budget makes
-    bin_passes == bucket_tiles) and the mask-all-N baseline — and stay
-    bit-exact (the supersession-mask last-wins argument)."""
-    cfg = HashTableConfig(p=4, k=2, buckets=128, slots=4,
+    """Tables above the VMEM budget run the multi-pass sweep (the shrunken
+    budget asks for 8 passes; the count is capped at the packed table's
+    128-bucket groups: 4 passes at 512 buckets, 8 at 1024) and stay
+    bit-exact (lane-order last-wins commit)."""
+    cfg = HashTableConfig(p=4, k=2, buckets=buckets, slots=4,
                           replicate_reads=False, stagger_slots=stagger)
     op, keys, vals = trace_gen.mixed(128, 1)
     ops, kk, vv = schedule_queries(op, keys, vals, cfg)
     tab = init_table(cfg, jax.random.key(0))
-    rb = kops.replica_bytes(tab.store_keys, tab.store_vals, tab.store_valid)
+    rb = kops.table_vmem_bytes(tab.store_keys, tab.store_vals,
+                               tab.store_valid)
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", rb // 7)
     assert kops.stream_bucket_tiles(tab.store_keys, tab.store_vals,
-                                    tab.store_valid) == 8
-    (tab_j, res_j), (tab_f, res_f) = _oracle_and_fused(cfg, ops, kk, vv,
-                                                       binned=binned)
+                                    tab.store_valid) == buckets // 128
+    (tab_j, res_j), (tab_f, res_f) = _oracle_and_fused(cfg, ops, kk, vv)
     _assert_same(tab_j, res_j, tab_f, res_f,
-                 f"blocked stagger={stagger} binned={binned}")
+                 f"blocked stagger={stagger} buckets={buckets}")
 
 
-def test_fused_stream_explicit_bucket_tiles(trace_gen):
-    """bucket_tiles pinned through the seam (the jit-static knob the
-    benchmarks use) is bit-exact with auto tiling and with the oracle."""
-    cfg = HashTableConfig(p=4, k=2, buckets=64, slots=4, stagger_slots=True)
+@pytest.mark.parametrize("passes", [1, 2])
+def test_fused_stream_pass_count_follows_budget(passes, trace_gen,
+                                                monkeypatch):
+    """The sweep pass count is derived from the VMEM budget alone (the 4-
+    and 8-pass counts are test_fused_stream_bucket_blocked_bit_exact's):
+    the budget resolves to the count asked for, bit-exact with the
+    oracle."""
+    cfg = HashTableConfig(p=4, k=2, buckets=1024, slots=4,
+                          replicate_reads=False, stagger_slots=True)
     op, keys, vals = trace_gen.mixed(64, 1)
     ops, kk, vv = schedule_queries(op, keys, vals, cfg)
     tab = init_table(cfg, jax.random.key(0))
-    oj = run_stream(tab, jnp.array(ops), jnp.array(kk), jnp.array(vv),
-                    backend="jnp", fused=False)
-    for tiles in (1, 4):
-        of = run_stream(tab, jnp.array(ops), jnp.array(kk), jnp.array(vv),
-                        fused=True, bucket_tiles=tiles)
-        _assert_same(*oj, *of, what=f"bucket_tiles={tiles}")
+    args = (tab.store_keys, tab.store_vals, tab.store_valid)
+    monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES",
+                        kops.table_vmem_bytes(*args) // passes)
+    assert kops.stream_bucket_tiles(*args) == passes
+    (tab_j, res_j), (tab_f, res_f) = _oracle_and_fused(cfg, ops, kk, vv)
+    _assert_same(tab_j, res_j, tab_f, res_f, f"passes={passes}")
+
+
+def test_xor_stream_rejects_passes_that_split_a_group():
+    """A sweep span is a whole number of 128-bucket groups."""
+    from repro.kernels.xor_stream import xor_stream_pallas
+    cfg = HashTableConfig(p=2, k=2, buckets=512, slots=2)
+    tab = init_table(cfg, jax.random.key(0))
+    z = jnp.zeros((1, 2), jnp.int32)
+    zq = jnp.zeros((1, 2, 1), jnp.uint32)
     with pytest.raises(ValueError):
-        run_stream(tab, jnp.array(ops), jnp.array(kk), jnp.array(vv),
-                   fused=True, bucket_tiles=3)       # must divide buckets
+        xor_stream_pallas(z.astype(jnp.uint32), z[0], z[0] + 1, z, zq, zq,
+                          tab.store_keys[0], tab.store_vals[0],
+                          tab.store_valid[0], passes=3)
 
 
 def test_fused_stream_matches_scanned_pallas(trace_gen):
@@ -129,55 +144,65 @@ def test_fused_stream_duplicate_write_targets_last_wins():
     _assert_same(tab_j, res_j, tab_f, res_f, "duplicate targets")
 
 
-def _layout_kwargs(layout, tab, monkeypatch):
-    """fused-path layout under test: unblocked, blocked-binned (single-pass
-    and multi-pass — the latter via a shrunken VMEM budget), blocked
-    mask-all-N baseline."""
-    if layout == "unblocked":
-        return dict(bucket_tiles=1)
-    if layout == "blocked_binned_multipass":
-        rb = kops.replica_bytes(tab.store_keys, tab.store_vals,
-                                tab.store_valid)
-        monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", max(rb // 3, 1))
-        return dict(bucket_tiles=4, binned=True)
-    if layout == "blocked_binned":
-        return dict(bucket_tiles=4, binned=True)
-    return dict(bucket_tiles=4, binned=False)
+# fused-path layout under test -> sweep passes (a shrunken VMEM budget
+# forces the multi-pass sweep; the 512-bucket tables below hold four
+# 128-bucket groups, so four passes really sweep four spans)
+_LAYOUTS = {"resident_1pass": 1, "swept_2pass": 2, "swept_4pass": 4}
+RACE_BUCKETS = 512
 
 
-_LAYOUTS = ["unblocked", "blocked_binned", "blocked_binned_multipass",
-            "blocked_nobinned"]
+def _set_layout(layout, tab, monkeypatch):
+    passes = _LAYOUTS[layout]
+    args = (tab.store_keys, tab.store_vals, tab.store_valid)
+    monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES",
+                        kops.table_vmem_bytes(*args) // passes)
+    assert kops.stream_bucket_tiles(*args) == passes
 
 
-@pytest.mark.parametrize("layout", _LAYOUTS)
+def _keys_in_last_pass(tab, n):
+    """``n`` one-word keys whose buckets lie in the table's top quarter —
+    the last sweep pass at 2 and 4 passes — so in each step their lanes
+    sort behind the NOP lanes (key 0 hashes to bucket 0, pass 0)."""
+    from repro.core.hashing import h3_hash as h3
+    cand = np.arange(1, 4096, dtype=np.uint32)
+    b = np.asarray(h3(jnp.asarray(cand[:, None]), tab.q_masks))
+    B = tab.store_keys.shape[2]
+    return [int(x) for x in cand[b >= 3 * B // 4][:n]]
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
 def test_fused_stream_cross_port_duplicate_bucket_slot(layout, monkeypatch):
     """Same-step writes from DIFFERENT ports to one (bucket, slot) must both
     land — the supersession key is (port, bucket, slot), matching the
     oracle's _scatter_records, NOT (bucket, slot) — bit-exact on every
     layout including the XOR-scrambled decode the collision produces."""
-    cfg = HashTableConfig(p=2, k=2, buckets=32, slots=2)     # stagger OFF
+    cfg = HashTableConfig(p=2, k=2, buckets=RACE_BUCKETS,
+                          slots=2)                           # stagger OFF
     tab = init_table(cfg, jax.random.key(0))
+    (kx,) = _keys_in_last_pass(tab, 1)
     # step 0: PE 0 and PE 1 insert the same fresh key -> same bucket, same
     # argmax open slot, different write ports; step 1: search it.
     ops = np.array([[OP_INSERT, OP_INSERT], [OP_SEARCH, 0]], np.int32)
-    keys = np.array([[[9], [9]], [[9], [0]]], np.uint32)
+    keys = np.array([[[kx], [kx]], [[kx], [0]]], np.uint32)
     vals = np.array([[[111], [222]], [[0], [0]]], np.uint32)
     tab_j, res_j = run_stream(tab, jnp.array(ops), jnp.array(keys),
                               jnp.array(vals), backend="jnp", fused=False)
+    _set_layout(layout, tab, monkeypatch)
     tab_f, res_f = run_stream(tab, jnp.array(ops), jnp.array(keys),
-                              jnp.array(vals), fused=True,
-                              **_layout_kwargs(layout, tab, monkeypatch))
+                              jnp.array(vals), fused=True)
     _assert_same(tab_j, res_j, tab_f, res_f, f"cross-port dup {layout}")
 
 
-@pytest.mark.parametrize("layout", _LAYOUTS)
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
 def test_fused_stream_insert_delete_race(layout, monkeypatch):
     """Inserts racing deletes on one key in one step: cross-port (both
     encodings land in distinct partial stores) and same-port (the later
     lane supersedes the earlier), bit-exact with the oracle on every
     layout; the same-port race must resolve insert-wins in program order."""
-    cfg = HashTableConfig(p=2, k=2, buckets=32, slots=2, queries_per_pe=2)
+    cfg = HashTableConfig(p=2, k=2, buckets=RACE_BUCKETS, slots=2,
+                          queries_per_pe=2)
     tab = init_table(cfg, jax.random.key(0))                 # N=4, PE=lane%2
+    k7, k8 = _keys_in_last_pass(tab, 2)
     ops = np.array([
         [OP_INSERT, 0, 0, 0],                  # key 7 in (port 0)
         [OP_DELETE, OP_INSERT, 0, 0],          # del 7 (port 0) || upd 7 (port 1)
@@ -187,8 +212,8 @@ def test_fused_stream_insert_delete_race(layout, monkeypatch):
         [OP_SEARCH, 0, 0, 0],                  # insert (later lane) must win
     ], np.int32)
     keys = np.array([
-        [[7], [0], [0], [0]], [[7], [7], [0], [0]], [[7], [0], [0], [0]],
-        [[8], [0], [0], [0]], [[8], [0], [8], [0]], [[8], [0], [0], [0]],
+        [[k7], [0], [0], [0]], [[k7], [k7], [0], [0]], [[k7], [0], [0], [0]],
+        [[k8], [0], [0], [0]], [[k8], [0], [k8], [0]], [[k8], [0], [0], [0]],
     ], np.uint32)
     vals = np.array([
         [[50], [0], [0], [0]], [[0], [60], [0], [0]], [[0], [0], [0], [0]],
@@ -196,9 +221,9 @@ def test_fused_stream_insert_delete_race(layout, monkeypatch):
     ], np.uint32)
     tab_j, res_j = run_stream(tab, jnp.array(ops), jnp.array(keys),
                               jnp.array(vals), backend="jnp", fused=False)
+    _set_layout(layout, tab, monkeypatch)
     tab_f, res_f = run_stream(tab, jnp.array(ops), jnp.array(keys),
-                              jnp.array(vals), fused=True,
-                              **_layout_kwargs(layout, tab, monkeypatch))
+                              jnp.array(vals), fused=True)
     _assert_same(tab_j, res_j, tab_f, res_f, f"ins/del race {layout}")
     # same-port same-slot race (step 4): the later insert supersedes the
     # delete, so step 5 must find key 8 with the raced value
@@ -265,29 +290,31 @@ def test_replica_bytes_helper():
 
 
 def test_stream_bucket_tiles_power_of_two(monkeypatch):
-    cfg = HashTableConfig(p=2, k=2, buckets=64, slots=2)
+    cfg = HashTableConfig(p=2, k=2, buckets=1024, slots=2)
     tab = init_table(cfg, jax.random.key(0))
     args = (tab.store_keys, tab.store_vals, tab.store_valid)
     assert kops.stream_bucket_tiles(*args) == 1
-    rb = kops.replica_bytes(*args)
+    rb = kops.table_vmem_bytes(*args)
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", rb // 3)
     assert kops.stream_bucket_tiles(*args) == 4
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", 1)
-    # capped at one bucket per tile
-    assert kops.stream_bucket_tiles(*args) == cfg.buckets
+    # capped at one 128-bucket group per pass
+    assert kops.stream_bucket_tiles(*args) == cfg.buckets // 128
 
 
 def test_run_stream_local_partitions_merge_to_oracle(trace_gen, monkeypatch):
     """The shard-local stream (engine.run_stream_local): manually partition a
     table's bucket axis, run the SAME global-bucket stream against every
-    partition with its bucket-base offset (fused kernel — unblocked, binned
-    single- and multi-pass blocked, unbinned blocked — and scanned jnp), and
-    merge — bit-exact with the unsharded oracle; out-of-partition lanes are
-    inert (the binned pre-pass sentinel-sorts them past every window).  This
+    partition with its bucket-base offset (fused kernel — one pass and a
+    four-pass sweep — and scanned jnp), and merge —
+    bit-exact with the unsharded oracle; out-of-partition lanes are inert
+    (the pre-pass sentinel-sorts them past every pass window).  This
     is the single-device half of the sharded distributed path
     (routing/all_to_all is covered by tests/test_distributed_sharded.py)."""
     from repro.core.hashing import h3_hash as h3
-    cfg = HashTableConfig(p=4, k=2, buckets=64, slots=4,
+    # 2048 buckets: each partition holds four 128-bucket groups, so the
+    # shrunken budget really sweeps four passes
+    cfg = HashTableConfig(p=4, k=2, buckets=2048, slots=4,
                           replicate_reads=False, stagger_slots=True)
     scfg = dataclasses.replace(cfg, shards=4)
     op, keys, vals = trace_gen.mixed(64, 1)
@@ -299,13 +326,10 @@ def test_run_stream_local_partitions_merge_to_oracle(trace_gen, monkeypatch):
     bucket = h3(jnp.array(kk).reshape(T * N, 1), tab.q_masks).reshape(T, N)
     pe = jnp.arange(N, dtype=jnp.int32) % cfg.p     # == the oracle's lane map
     Bl = scfg.local_buckets
-    # (fused, bucket_tiles, binned, shrink_budget): scanned jnp, unblocked
-    # fused, binned blocked single-pass, binned blocked multi-pass, unbinned
-    combos = [(False, None, None, False), (True, None, None, False),
-              (True, 4, True, False), (True, 4, True, True),
-              (True, 4, False, False)]
-    for fused, tiles, binned, shrink in combos:
-        label = f"fused={fused} tiles={tiles} binned={binned} shrink={shrink}"
+    # (fused, shrink_budget): scanned jnp, one-pass fused, four-pass sweep
+    combos = [(False, False), (True, False), (True, True)]
+    for fused, shrink in combos:
+        label = f"fused={fused} shrink={shrink}"
         parts = {"store_keys": [], "store_vals": [], "store_valid": []}
         got_f = np.zeros((T, N), bool)
         got_ok = np.zeros((T, N), bool)
@@ -316,15 +340,14 @@ def test_run_stream_local_partitions_merge_to_oracle(trace_gen, monkeypatch):
                     tab.store_vals[:, :, lo:lo + Bl],
                     tab.store_valid[:, :, lo:lo + Bl])
             with monkeypatch.context() as m:
-                if shrink:     # multi-pass: bin_passes == bucket_tiles == 4
-                    rb = kops.replica_bytes(*part)
-                    m.setattr(kops, "VMEM_TABLE_BUDGET_BYTES",
-                              max(rb // 3, 1))
+                if shrink:     # multi-pass: 4 sweep passes
+                    rb = kops.table_vmem_bytes(*part)
+                    m.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", rb // 3)
+                    assert kops.stream_bucket_tiles(*part) == 4
                 sk, sv, sb, f, ok, val = engine.run_stream_local(
                     scfg, *part,
                     pe, bucket, jnp.array(ops), jnp.array(kk), jnp.array(vv),
-                    bucket_base=lo, fused=fused, bucket_tiles=tiles,
-                    binned=binned)
+                    bucket_base=lo, fused=fused)
             parts["store_keys"].append(np.asarray(sk))
             parts["store_vals"].append(np.asarray(sv))
             parts["store_valid"].append(np.asarray(sb))
