@@ -459,18 +459,20 @@ class PallasBackend:
         pe = _lane_pe(cfg, N)
         port = jnp.minimum(pe, cfg.k - 1).astype(jnp.int32)
         legal = (pe < cfg.k).astype(jnp.int32)
-        bucket = kops.h3_hash(keys.reshape(T * N, cfg.key_words),
-                              table.q_masks).reshape(T, N)
+        with jax.named_scope("kv.h3"):
+            bucket = kops.h3_hash(keys.reshape(T * N, cfg.key_words),
+                                  table.q_masks).reshape(T, N)
         sk, sv, sb, found, ok, value = kops.xor_stream(
             bucket, port, legal, ops, keys, vals, table.store_keys[0],
             table.store_vals[0], table.store_valid[0],
             stagger=cfg.stagger_slots)
         R = table.store_keys.shape[0]
-        new_table = XorHashTable(
-            table.q_masks,
-            jnp.broadcast_to(sk[None], (R,) + sk.shape),
-            jnp.broadcast_to(sv[None], (R,) + sv.shape),
-            jnp.broadcast_to(sb[None], (R,) + sb.shape), cfg)
+        with jax.named_scope("kv.table_unpack"):
+            new_table = XorHashTable(
+                table.q_masks,
+                jnp.broadcast_to(sk[None], (R,) + sk.shape),
+                jnp.broadcast_to(sv[None], (R,) + sv.shape),
+                jnp.broadcast_to(sb[None], (R,) + sb.shape), cfg)
         return new_table, StepResults(found=found, value=value, ok=ok,
                                       bucket=bucket)
 

@@ -199,25 +199,28 @@ def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
         legal = jnp.broadcast_to(legal[None], (T, N))
 
     # ---- XLA-side pre-pass: stable-sort each step's lanes by sweep pass ----
-    rel = bucket.astype(jnp.int32) - base
-    in_part = (rel >= 0) & (rel < B)
-    pass_id = jnp.where(in_part, rel // (Gs * pk.LANES), P)
-    perm = jnp.argsort(pass_id, axis=1, stable=True)             # [T, N]
-    # offs[j, t] == #lanes of step t with pass id < j: pass p's window is
-    # [offs[p, t], offs[p+1, t]) and sentinel lanes fall past every window
-    offs = jnp.sum(pass_id[:, :, None]
-                   < jnp.arange(1, P + 1, dtype=jnp.int32)[None, None, :],
-                   axis=1, dtype=jnp.int32)
-    offs = jnp.concatenate([jnp.zeros((T, 1), jnp.int32), offs], axis=1).T
-    opw = (ops.astype(jnp.uint32) & 0xFF) \
-        | (port.astype(jnp.uint32) << 8) \
-        | (legal.astype(jnp.uint32) << 16)
-    q = jnp.concatenate([opw[..., None], qkeys.astype(jnp.uint32),
-                         qvals.astype(jnp.uint32)], axis=-1)     # [T, N, Wq]
-    q_s = jnp.take_along_axis(q, perm[..., None], axis=1).transpose(0, 2, 1)
-    bkt_s = jnp.take_along_axis(jnp.where(in_part, rel, 0), perm, axis=1)
+    with jax.named_scope("kv.prepass"):
+        rel = bucket.astype(jnp.int32) - base
+        in_part = (rel >= 0) & (rel < B)
+        pass_id = jnp.where(in_part, rel // (Gs * pk.LANES), P)
+        perm = jnp.argsort(pass_id, axis=1, stable=True)         # [T, N]
+        # offs[j, t] == #lanes of step t with pass id < j: pass p's window is
+        # [offs[p, t], offs[p+1, t]) and sentinel lanes fall past every window
+        offs = jnp.sum(pass_id[:, :, None]
+                       < jnp.arange(1, P + 1, dtype=jnp.int32)[None, None, :],
+                       axis=1, dtype=jnp.int32)
+        offs = jnp.concatenate([jnp.zeros((T, 1), jnp.int32), offs], axis=1).T
+        opw = (ops.astype(jnp.uint32) & 0xFF) \
+            | (port.astype(jnp.uint32) << 8) \
+            | (legal.astype(jnp.uint32) << 16)
+        q = jnp.concatenate([opw[..., None], qkeys.astype(jnp.uint32),
+                             qvals.astype(jnp.uint32)], axis=-1)  # [T, N, Wq]
+        q_s = jnp.take_along_axis(q, perm[..., None],
+                                  axis=1).transpose(0, 2, 1)
+        bkt_s = jnp.take_along_axis(jnp.where(in_part, rel, 0), perm, axis=1)
 
-    tab = pk.pack(store_keys, store_vals, store_valid)
+    with jax.named_scope("kv.table_pack"):
+        tab = pk.pack(store_keys, store_vals, store_valid)
     C = tab.shape[1]
     Wq = 1 + Wk + Wv
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -246,11 +249,15 @@ def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_limit),
         interpret=pk.interpret_mode(interpret),
+        name="xor_stream",
     )(offs, bkt_s, q_s, tab)
 
-    sk, sv, sb = pk.unpack(tab, k, B, S, Wk, Wv)
-    inv = jnp.argsort(perm, axis=1)                        # sorted -> program
-    out = jnp.take_along_axis(out, inv[:, None, :], axis=2)
-    found = (out[:, 0] & 1) != 0
-    ok = ((out[:, 0] >> 1) & 1) != 0
-    return sk, sv, sb, found, ok, out[:, 1:].transpose(0, 2, 1)
+    with jax.named_scope("kv.table_unpack"):
+        sk, sv, sb = pk.unpack(tab, k, B, S, Wk, Wv)
+    with jax.named_scope("kv.unpermute"):
+        inv = jnp.argsort(perm, axis=1)                    # sorted -> program
+        out = jnp.take_along_axis(out, inv[:, None, :], axis=2)
+        found = (out[:, 0] & 1) != 0
+        ok = ((out[:, 0] >> 1) & 1) != 0
+        value = out[:, 1:].transpose(0, 2, 1)
+    return sk, sv, sb, found, ok, value

@@ -34,6 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.config import GrowthPolicy
 from repro.core.hash_table import OP_DELETE, OP_INSERT
 from repro.models.lm import init_cache, lm_decode_step, lm_prefill
@@ -376,7 +377,7 @@ class TableServer:
         if dbl is None:                 # auto: overlap needs a host core of
             dbl = (os.cpu_count() or 1) > 1     # its own to be a win
         self._window = 2 if dbl else 1
-        self._inflight = collections.deque()    # (slab, device results)
+        self._inflight = collections.deque()    # (seq, slab, results)
         self._qm_host: Optional[np.ndarray] = None
         self._next_rid = 0
         self._closed = False
@@ -419,6 +420,12 @@ class TableServer:
         ``vals [n, Wv]`` — vals default to zeros for read-only traffic).
         Returns the :class:`SlabRequest` whose result arrays fill as its
         slabs retire."""
+        if tracing.enabled:             # the per-request site: one flag test
+            with tracing.span("kv.submit"):
+                return self._submit(ops, keys, vals)
+        return self._submit(ops, keys, vals)
+
+    def _submit(self, ops, keys, vals) -> SlabRequest:
         if self._closed:
             raise RuntimeError("TableServer.run() already drained this "
                                "server; a request submitted now would be "
@@ -472,7 +479,7 @@ class TableServer:
                 self.table, res = self._stream(self.table, *args)
         else:
             self.table, res = self._stream(self.table, *args)
-        self._inflight.append((slab, res))
+        self._inflight.append((self.slabs, slab, res))
         self.slabs += 1
         self.live_lanes += slab.live
         self.pad_lanes += slab.ops.size - slab.live
@@ -480,37 +487,39 @@ class TableServer:
         self._op_counts += np.bincount(ops[ops > 0], minlength=4)
 
     def _retire_one(self) -> List[SlabRequest]:
-        slab, res = self._inflight.popleft()
-        jax.block_until_ready(res)
-        T, N = slab.ops.shape
-        found = np.asarray(res.found).reshape(T * N)
-        ok = np.asarray(res.ok).reshape(T * N)
-        value = np.asarray(res.value).reshape(T * N, -1)
-        # occupancy tracking for the growth trigger, on the PHYSICAL layout
-        # (slab.ops is physical; the perm gather below reorders results to
-        # logical).  Counts accepted first-time inserts minus accepted
-        # deletes — same-step duplicate inserts of one new key each count
-        # (both probed the pre-step snapshot), so this can overcount under
-        # duplicate-heavy ingest: fine for a grow trigger, which only needs
-        # to err toward growing earlier
-        ops_phys = slab.ops.reshape(T * N)
-        self.live_records += int(((ops_phys == OP_INSERT) & ok
-                                  & ~found).sum())
-        self.live_records -= int(((ops_phys == OP_DELETE) & ok).sum())
-        if slab.perm is not None:       # NSQ-aware packing: logical -> phys
-            found = found[slab.perm]
-            ok = ok[slab.perm]
-            value = value[slab.perm]
-        finished, now = [], time.perf_counter()
-        for req, r_off, f_off, cnt in slab.spans:
-            req.found[r_off:r_off + cnt] = found[f_off:f_off + cnt]
-            req.ok[r_off:r_off + cnt] = ok[f_off:f_off + cnt]
-            req.value[r_off:r_off + cnt] = value[f_off:f_off + cnt]
-            req.lanes_done += cnt
-            if req.done:
-                req.done_s = now
-                finished.append(req)
-        return finished
+        seq, slab, res = self._inflight.popleft()
+        with tracing.span("kv.retire", slab=seq):
+            with tracing.span("kv.retire.wait", slab=seq):
+                jax.block_until_ready(res)
+            T, N = slab.ops.shape
+            found = np.asarray(res.found).reshape(T * N)
+            ok = np.asarray(res.ok).reshape(T * N)
+            value = np.asarray(res.value).reshape(T * N, -1)
+            # occupancy tracking for the growth trigger, on the PHYSICAL
+            # layout (slab.ops is physical; the perm gather below reorders
+            # results to logical).  Counts accepted first-time inserts minus
+            # accepted deletes — same-step duplicate inserts of one new key
+            # each count (both probed the pre-step snapshot), so this can
+            # overcount under duplicate-heavy ingest: fine for a grow
+            # trigger, which only needs to err toward growing earlier
+            ops_phys = slab.ops.reshape(T * N)
+            self.live_records += int(((ops_phys == OP_INSERT) & ok
+                                      & ~found).sum())
+            self.live_records -= int(((ops_phys == OP_DELETE) & ok).sum())
+            if slab.perm is not None:   # NSQ-aware packing: logical -> phys
+                found = found[slab.perm]
+                ok = ok[slab.perm]
+                value = value[slab.perm]
+            finished, now = [], time.perf_counter()
+            for req, r_off, f_off, cnt in slab.spans:
+                req.found[r_off:r_off + cnt] = found[f_off:f_off + cnt]
+                req.ok[r_off:r_off + cnt] = ok[f_off:f_off + cnt]
+                req.value[r_off:r_off + cnt] = value[f_off:f_off + cnt]
+                req.lanes_done += cnt
+                if req.done:
+                    req.done_s = now
+                    finished.append(req)
+            return finished
 
     # ------------------------------------------------- geometry replanning
     @property
@@ -539,22 +548,23 @@ class TableServer:
             return
         if self.slabs < self.scfg.geometry_min_slabs:
             return
-        plan = plan_geometry(self.cfg, self.served_mix,
-                             vmem_budget=self.scfg.geometry_vmem_budget)
-        self.geometry_plan = plan
-        if (self.cfg.mesh_devices > 1 or not plan.changed
-                or plan.improvement < self.scfg.geometry_hysteresis
-                or self._resize_state is not None):
-            return
-        new_cfg = plan.apply(self.cfg)
-        self.table = _core_engine.reconfigure(self.table, new_cfg)
-        self.cfg = new_cfg
-        self._queue.set_nsq_lanes(self._nsq_mask(new_cfg))
-        if self.plan_cache is not None:     # routed widths keyed on old k
-            self.plan_cache = PlanCache(new_cfg,
-                                        plans=self.scfg.plan_cache_plans,
-                                        slack=self.plan_cache.slack)
-        self.migrations += 1
+        with tracing.span("kv.migrate"):
+            plan = plan_geometry(self.cfg, self.served_mix,
+                                 vmem_budget=self.scfg.geometry_vmem_budget)
+            self.geometry_plan = plan
+            if (self.cfg.mesh_devices > 1 or not plan.changed
+                    or plan.improvement < self.scfg.geometry_hysteresis
+                    or self._resize_state is not None):
+                return
+            new_cfg = plan.apply(self.cfg)
+            self.table = _core_engine.reconfigure(self.table, new_cfg)
+            self.cfg = new_cfg
+            self._queue.set_nsq_lanes(self._nsq_mask(new_cfg))
+            if self.plan_cache is not None:     # routed widths keyed on old k
+                self.plan_cache = PlanCache(new_cfg,
+                                            plans=self.scfg.plan_cache_plans,
+                                            slack=self.plan_cache.slack)
+            self.migrations += 1
 
     # ------------------------------------------------------- online growth
     def _maybe_grow(self) -> None:
@@ -588,54 +598,60 @@ class TableServer:
         for the new capacity."""
         if self._resize_state is None:
             return
-        self._resize_state = self._resize.migrate(
-            self._resize_state, self.scfg.growth.migrate_buckets_per_slab)
-        if not self._resize_state.done:
-            return
-        self.table = self._resize.finish(self._resize_state)
-        self._resize_state = None
-        self._resize = None
-        self.cfg = self.table.cfg
-        self._qm_host = None            # host mirror of the OLD q_masks
-        if self._stream_factory is not None:
-            self._stream = self._stream_factory(self.cfg)
-            self._bounded = getattr(self._stream, "router", None) == "bounded"
-        if self._bounded:               # cached widths measured at old B
-            slack = getattr(self._stream, "slack",
-                            None if self.plan_cache is None
-                            else self.plan_cache.slack)
-            self.plan_cache = PlanCache(self.cfg,
-                                        plans=self.scfg.plan_cache_plans,
-                                        slack=slack)
-        self.resizes += 1
+        with tracing.span("kv.migrate"):
+            self._resize_state = self._resize.migrate(
+                self._resize_state, self.scfg.growth.migrate_buckets_per_slab)
+            if not self._resize_state.done:
+                return
+            self.table = self._resize.finish(self._resize_state)
+            self._resize_state = None
+            self._resize = None
+            self.cfg = self.table.cfg
+            self._qm_host = None            # host mirror of the OLD q_masks
+            if self._stream_factory is not None:
+                self._stream = self._stream_factory(self.cfg)
+                self._bounded = (getattr(self._stream, "router", None)
+                                 == "bounded")
+            if self._bounded:               # cached widths measured at old B
+                slack = getattr(self._stream, "slack",
+                                None if self.plan_cache is None
+                                else self.plan_cache.slack)
+                self.plan_cache = PlanCache(self.cfg,
+                                            plans=self.scfg.plan_cache_plans,
+                                            slack=slack)
+            self.resizes += 1
 
     # ------------------------------------------------------------------ step
     def step(self) -> StepReport:
         """Pack + dispatch at most one slab, then retire anything past the
         in-flight window (all of it once the queue is quiescent).  Returns
         the :class:`StepReport` ``run()`` terminates on."""
-        finished: List[SlabRequest] = []
-        if self._queue.pending_requests:
-            self._dispatch(self._queue.next_slab())
-            self._advance_resize()      # one migration slab per dispatch
-            self._maybe_replan()
-        # double-buffer discipline: block only on slabs leaving the window,
-        # so the newest dispatch keeps executing while the host packs on
-        while len(self._inflight) >= self._window:
-            finished.extend(self._retire_one())
-        if not self._queue.pending_requests:
-            while self._inflight:               # quiescent queue: drain
+        with tracing.span("kv.step"):
+            finished: List[SlabRequest] = []
+            if self._queue.pending_requests:
+                with tracing.span("kv.pack", slab=self.slabs):
+                    slab = self._queue.next_slab()
+                with tracing.span("kv.dispatch", slab=self.slabs):
+                    self._dispatch(slab)
+                self._advance_resize()      # one migration slab per dispatch
+                self._maybe_replan()
+            # double-buffer discipline: block only on slabs leaving the window,
+            # so the newest dispatch keeps executing while the host packs on
+            while len(self._inflight) >= self._window:
                 finished.extend(self._retire_one())
-            # idle: ONE background slab, never a drain-it-all loop — a
-            # request arriving mid-drain would eat the very stop-the-world
-            # stall the watermark walk exists to avoid.  The report says
-            # ``resizing`` so run() keeps stepping until the walk closes.
-            self._advance_resize()
-        self._maybe_grow()
-        return StepReport(finished=finished,
-                          queued=self._queue.pending_requests,
-                          occupied=len(self._inflight),
-                          resizing=self._resize_state is not None)
+            if not self._queue.pending_requests:
+                while self._inflight:               # quiescent queue: drain
+                    finished.extend(self._retire_one())
+                # idle: ONE background slab, never a drain-it-all loop — a
+                # request arriving mid-drain would eat the very stop-the-world
+                # stall the watermark walk exists to avoid.  The report says
+                # ``resizing`` so run() keeps stepping until the walk closes.
+                self._advance_resize()
+            self._maybe_grow()
+            return StepReport(finished=finished,
+                              queued=self._queue.pending_requests,
+                              occupied=len(self._inflight),
+                              resizing=self._resize_state is not None)
 
     # ------------------------------------------------------------------- run
     def run(self) -> List[SlabRequest]:

@@ -2,6 +2,8 @@
 equivalence, slab packing, the LRU plan cache, and TableServer's bit-exact
 agreement with the one-shot stream — plus the sharded conformance run in a
 fake-device subprocess."""
+import functools
+import glob
 import os
 import subprocess
 import sys
@@ -11,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core import (HashTableConfig, OP_DELETE, OP_INSERT, OP_NOP,
                         OP_SEARCH, engine, h3_hash, init_table, make_h3_params)
 from repro.serving import (PlanCache, ServeConfig, SlabQueue, SlabRequest,
@@ -336,6 +339,106 @@ def test_step_report_quiescence(trace_gen):
     # termination came from the report, not an extra empty sweep: the final
     # report is the one that retired the last slab
     assert reports[-1].finished or reports[-2].finished
+
+
+# --------------------------------------------------------------------------
+# spans and scopes (repro.tracing)
+# --------------------------------------------------------------------------
+
+def _small_server(k):
+    cfg = HashTableConfig(p=4, k=k, buckets=1 << 8, slots=4, key_words=2,
+                          val_words=2, backend="jnp")
+    stream = jax.jit(engine.run_stream, static_argnames=("backend",))
+    return TableServer(cfg, init_table(cfg, jax.random.key(0)), stream,
+                       ServeConfig(slab_steps=2, serve_double_buffer=True))
+
+
+def _recorded_spans(log_dir):
+    """``(name, start, end, stats)`` of every ``kv.*`` host event."""
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    pd = ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("kv.")]
+
+
+@pytest.mark.parametrize("k", [4, 2], ids=["plain", "classed"])
+def test_traced_server_records_its_spans(k, tmp_path, trace_gen):
+    """One ``kv.submit`` per request, one ``kv.pack``/``kv.dispatch``/
+    ``kv.retire`` per slab, each inside a ``kv.step`` and tagged with its
+    slab's number, and every ``kv.retire.wait`` inside its ``kv.retire``."""
+    srv = _small_server(k)
+    trace = [trace_gen.mixed(n, key_words=2, key_space=64, val_words=2)
+             for n in (9, 14, 5, 1, 30)]
+    tracing.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for t in trace:
+            srv.submit(*t)
+        srv.run()
+    finally:
+        jax.profiler.stop_trace()
+        tracing.disable()
+    ev = _recorded_spans(str(tmp_path))
+
+    def named(n):
+        return [e for e in ev if e[0] == n]
+
+    def inside(e, outer):
+        return outer[1] <= e[1] and e[2] <= outer[2]
+
+    assert len(named("kv.submit")) == len(trace)
+    steps = named("kv.step")
+    for n in ("kv.pack", "kv.dispatch", "kv.retire"):
+        got = named(n)
+        assert sorted(e[3]["slab"] for e in got) == list(range(srv.slabs))
+        assert all(any(inside(e, s) for s in steps) for e in got)
+    retires = named("kv.retire")
+    waits = named("kv.retire.wait")
+    assert len(waits) == len(retires)
+    assert all(any(inside(w, r) and w[3] == r[3] for r in retires)
+               for w in waits)
+    # the geometry replan works from the second dispatch on; no resize
+    migrate = named("kv.migrate")
+    assert len(migrate) == srv.slabs - 1
+    assert all(any(inside(e, s) for s in steps) for e in migrate)
+
+
+def test_spans_off_construct_no_annotation(monkeypatch, trace_gen):
+    class Refused:
+        def __init__(self, *a, **kw):
+            raise AssertionError("TraceAnnotation built with recording off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+    assert not tracing.enabled
+    srv = _small_server(2)
+    req = srv.submit(*trace_gen.mixed(11, key_words=2, val_words=2))
+    srv.run()
+    assert req.done
+    tracing.enable()
+    try:        # the patched class is the one a recorded span would build
+        with pytest.raises(AssertionError, match="recording off"):
+            srv.step()
+    finally:
+        tracing.disable()
+
+
+def test_served_stream_carries_each_scope():
+    cfg = HashTableConfig(p=4, k=4, buckets=1 << 8, slots=4, key_words=2,
+                          val_words=2, backend="pallas")
+    stream = jax.jit(functools.partial(engine.run_stream, backend="pallas"),
+                     donate_argnums=(0,))
+    T, N = 2, cfg.queries_per_step
+    hlo = stream.lower(init_table(cfg, jax.random.key(0)),
+                       jnp.zeros((T, N), jnp.int32),
+                       jnp.zeros((T, N, 2), jnp.uint32),
+                       jnp.zeros((T, N, 2), jnp.uint32)).compile().as_text()
+    for scope in ("kv.h3", "kv.prepass", "kv.table_pack", "kv.table_unpack",
+                  "kv.unpermute", "xor_stream"):
+        assert f"/{scope}/" in hlo, scope
 
 
 # --------------------------------------------------------------------------

@@ -14,6 +14,7 @@ needs it.
 from __future__ import annotations
 
 import functools
+import re
 
 import pytest
 import jax
@@ -77,6 +78,13 @@ def test_xor_stream_compiles_for_v5e(spec, buckets, passes):
                         spec((T, N, WK)), spec((T, N, WV)),
                         *_store(spec, buckets))
     assert "tpu_custom_call" in hlo
+    # the kernel's stable name, which the device trace's op events carry,
+    # and the seam's scopes around it
+    assert re.search(r"%xor_stream\.\d+ = .*custom_call_target="
+                     r"\"tpu_custom_call\"", hlo)
+    for scope in ("kv.prepass", "kv.table_pack", "kv.table_unpack",
+                  "kv.unpermute"):
+        assert f"/{scope}/" in hlo, scope
 
 
 def test_bulk_place_compiles_for_v5e(spec):
