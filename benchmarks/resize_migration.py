@@ -82,7 +82,7 @@ def _serve_once(cfg, scfg, trace, seed_rng, stream, dt_s):
     from repro.serving import TableServer
 
     table = init_table(cfg, jax.random.key(0))
-    jax.block_until_ready(table.store_keys)
+    jax.block_until_ready(table.packed)
     srv = TableServer(cfg, table, stream, scfg, rng=seed_rng)
     reqs, steps = [], []
     i, report = 0, None

@@ -85,7 +85,7 @@ def _oneshot_once(cfg, mesh, stream, trace):
     from repro.core.distributed import init_distributed_table
 
     table = init_distributed_table(cfg, jax.random.key(0), mesh)
-    jax.block_until_ready(table.store_keys)
+    jax.block_until_ready(table.packed)
     N = cfg.queries_per_step
     live = pad = 0
     lats = []
@@ -116,7 +116,7 @@ def _serve_once(cfg, mesh, stream, scfg, trace):
     from repro.serving import TableServer
 
     table = init_distributed_table(cfg, jax.random.key(0), mesh)
-    jax.block_until_ready(table.store_keys)
+    jax.block_until_ready(table.packed)
     srv = TableServer(cfg, table, stream, scfg)
     t0 = time.perf_counter()
     reqs = [srv.submit(op, keys, vals) for op, keys, vals in trace]

@@ -148,8 +148,7 @@ def serve(table, stream, requests, steps: int):
 
 def tables_equal(a, b) -> bool:
     import jax.numpy as jnp
-    return all(bool(jnp.array_equal(getattr(a, nm), getattr(b, nm)))
-               for nm in ("store_keys", "store_vals", "store_valid"))
+    return bool(jnp.array_equal(a.packed, b.packed))
 
 
 def run_phase(name: str, buckets: int, slabs: int, steps: int, seed: int,
@@ -178,8 +177,7 @@ def run_phase(name: str, buckets: int, slabs: int, steps: int, seed: int,
         f"({int(spilled.sum())} spilled), {time.perf_counter() - t0:.3f} s "
         f"including compile")
 
-    sk, sv, sb = table.store_keys, table.store_vals, table.store_valid
-    passes = kops.stream_bucket_tiles(sk, sv, sb)
+    passes = kops.stream_bucket_tiles(table.packed)
     layout = ("resident (one VMEM pass)" if passes == 1
               else f"swept in {passes} VMEM passes")
     fused, fused_s = compile_stream(table, "pallas", steps)
@@ -187,7 +185,7 @@ def run_phase(name: str, buckets: int, slabs: int, steps: int, seed: int,
     custom = "tpu_custom_call" in fused.as_text()
     log(f"[{name}] served path: engine.run_stream backend={backend} "
         f"kernel=xor_stream layout={layout} "
-        f"table_vmem_bytes={kops.table_vmem_bytes(sk, sv, sb)} "
+        f"table_vmem_bytes={kops.table_vmem_bytes(table.packed)} "
         f"tpu_custom_call={custom}")
     check(custom, f"[{name}] no tpu_custom_call in the served stream's HLO")
     log(f"[{name}] compile_s fused={fused_s:.3f} jnp_oracle={oracle_s:.3f}")
@@ -275,12 +273,12 @@ def shard_table(cfg, mesh, table):
     from repro.core import XorHashTable, engine
     shard_of = engine.replica_layout(cfg)[0]
     lb = cfg.local_buckets
-    on_mesh = NamedSharding(mesh, P(None, None, "ht"))
-    lay = lambda x: jax.device_put(jnp.concatenate(
-        [x[:, :, s * lb:(s + 1) * lb] for s in shard_of], axis=2), on_mesh)
-    return XorHashTable(jax.device_put(table.q_masks, NamedSharding(mesh, P())),
-                        lay(table.store_keys), lay(table.store_vals),
-                        lay(table.store_valid), cfg)
+    lay = lambda x: jnp.concatenate(
+        [x[:, :, s * lb:(s + 1) * lb] for s in shard_of], axis=2)
+    q_masks = jax.device_put(table.q_masks, NamedSharding(mesh, P()))
+    laid = XorHashTable.from_stores(q_masks, *map(lay, table.stores()), cfg)
+    return XorHashTable(q_masks, jax.device_put(
+        laid.packed, NamedSharding(mesh, P(None, "ht"))), cfg)
 
 
 def run_sharded(name: str, cfg, oracle, trace) -> None:
@@ -293,12 +291,13 @@ def run_sharded(name: str, cfg, oracle, trace) -> None:
     Dv = cfg.mesh_devices
     mesh = dist.make_ht_mesh(Dv)
     table = shard_table(cfg, mesh, table_1)
-    devs = {s.device for s in table.store_keys.addressable_shards}
+    from repro.kernels.packed import packed_groups
+    devs = {s.device for s in table.packed.addressable_shards}
     check(len(devs) == Dv, f"[{name}] partitions on {len(devs)} devices, "
                            f"want {Dv}")
     lb = cfg.local_buckets
-    check(all(s.data.shape[2] == lb
-              for s in table.store_keys.addressable_shards),
+    check(all(s.data.shape[1] == packed_groups(lb)
+              for s in table.packed.addressable_shards),
           f"[{name}] a device holds other than its {lb}-bucket partition")
     load = np.bincount(np.asarray(engine.shard_owner(cfg, rr.bucket)).ravel(),
                        minlength=cfg.shards)
@@ -321,8 +320,7 @@ def run_sharded(name: str, cfg, oracle, trace) -> None:
                              np.asarray(getattr(rr, f))),
               f"[{name}] results.{f} differ from the shards=1 oracle")
     shard_of = np.asarray(engine.replica_layout(cfg)[0])
-    for f in ("store_keys", "store_vals", "store_valid"):
-        a, b = getattr(ts, f), getattr(tr, f)
+    for f, a, b in zip(("keys", "vals", "valid"), ts.stores(), tr.stores()):
         for d in range(Dv):
             s = int(shard_of[d])
             check(bool(jnp.array_equal(a[:, :, d * lb:(d + 1) * lb],

@@ -75,10 +75,10 @@ def main():
     # ---- bucket-sharded mapping: capacity scales with the mesh -------------
     scfg = dataclasses.replace(cfg, shards=n_dev)
     stab = init_distributed_table(scfg, jax.random.key(0), mesh)
-    local_shape = stab.store_keys.sharding.shard_shape(stab.store_keys.shape)
+    local_groups = stab.packed.sharding.shard_shape(stab.packed.shape)[1]
     print(f"\nsharded: {scfg.buckets} global buckets, each device owns "
-          f"{local_shape[2]} ({scfg.local_buckets}) — routed all_to_all "
-          f"stream, one launch for a whole [T, N] trace")
+          f"{scfg.local_buckets} ({local_groups} packed bucket groups) — "
+          f"routed all_to_all stream, one launch for a whole [T, N] trace")
     stream = make_distributed_stream(mesh, scfg)
     T = 4
     n_ins = cfg.k * n_local                 # only NSQ-capable origins land

@@ -288,8 +288,8 @@ class HashTableConfig:
         """Bytes of ONE read replica of this geometry (k partial-store
         planes of buckets x slots entries) — the unit the VMEM residency
         check tiles against.  Computable for a planned-but-not-yet-built
-        geometry: no arrays needed, and for a built table it equals
-        ``kernels.ops.replica_bytes`` on the store arrays."""
+        geometry: no arrays needed, and for a built table it equals the
+        bytes of one replica's logical stores (``XorHashTable.stores``)."""
         return self.k * self.buckets * self.slots * 4 * self.entry_words
 
     @property

@@ -77,13 +77,28 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.core import engine as _engine
 from repro.core.config import HashTableConfig
 from repro.core.hash_table import (QueryBatch, StepResults, XorHashTable,
-                                   init_table)
+                                   init_table, packed_shape)
 from repro.core.hashing import h3_hash as _h3, make_h3_params
 
 __all__ = ["make_ht_mesh", "init_distributed_table", "make_distributed_step",
            "make_distributed_stream", "make_distributed_bulk_build",
            "make_distributed_compact", "make_distributed_reconfigure",
            "make_distributed_resize", "DistributedResize"]
+
+
+def _parts(axis: str) -> P:
+    """The stored array's sharding: its bucket-group axis (dim 1) split
+    over the mesh, one packed partition per device."""
+    return P(None, axis)
+
+
+def _empty_partitions(mesh: Mesh, axis: str, cfg: HashTableConfig):
+    """A zero stored array of ``cfg``'s geometry with one packed
+    ``cfg.local_buckets`` partition on each device of ``axis`` (under
+    ``replica_groups`` every group member holds its shard's copy)."""
+    shape = packed_shape(cfg, mesh.shape[axis] * cfg.local_buckets)
+    return jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
+                   out_shardings=NamedSharding(mesh, _parts(axis)))()
 
 
 def make_ht_mesh(n_devices: int | None = None, axis: str = "ht",
@@ -133,18 +148,11 @@ def init_distributed_table(cfg: HashTableConfig, rng: jax.Array,
                          "mesh to place its partitions")
     n_dev = mesh.shape[axis]
     cfg.validate_mesh(n_dev, axis)
-    R, k, S = cfg.replicas, cfg.k, cfg.slots
-    B = n_dev * cfg.local_buckets       # == cfg.buckets when unreplicated
-    shard_b = NamedSharding(mesh, P(None, None, axis))   # bucket axis (dim 2)
-    rep = NamedSharding(mesh, P())
-    zeros = lambda shape: jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
-                                  out_shardings=shard_b)()
     return XorHashTable(
         q_masks=jax.device_put(
-            make_h3_params(rng, cfg.key_words, cfg.index_bits), rep),
-        store_keys=zeros((R, k, B, S, cfg.key_words)),
-        store_vals=zeros((R, k, B, S, cfg.val_words)),
-        store_valid=zeros((R, k, B, S)),
+            make_h3_params(rng, cfg.key_words, cfg.index_bits),
+            NamedSharding(mesh, P())),
+        packed=_empty_partitions(mesh, axis, cfg),
         cfg=cfg,
     )
 
@@ -197,7 +205,7 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
 
             return jax.lax.scan(body, table, (ops, keys, vals))
 
-        table_spec = XorHashTable(P(), P(), P(), P(), cfg)
+        table_spec = XorHashTable(P(), P(), cfg)
 
         fn = jax.shard_map(
             local_stream, mesh=mesh,
@@ -208,8 +216,7 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
         )
         return jax.jit(fn)
 
-    table_spec = XorHashTable(P(), P(None, None, axis),
-                              P(None, None, axis), P(None, None, axis), cfg)
+    table_spec = XorHashTable(P(), _parts(axis), cfg)
     shmap = lambda body: jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(table_spec, P(None, axis), P(None, axis), P(None, axis)),
@@ -238,13 +245,12 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
                     cfg, axis, bucket, ops, keys, vals, bucket)
             # routed lane r belongs to origin device r // n == its PE
             pe = jnp.repeat(jnp.arange(n_dev, dtype=jnp.int32), n)
-            sk, sv, sb, found, ok, value = _engine.run_stream_local(
-                cfg, table.store_keys, table.store_vals, table.store_valid,
-                pe, r_bkt, r_op, r_key, r_val,
+            packed, found, ok, value = _engine.run_stream_local(
+                cfg, table.packed, pe, r_bkt, r_op, r_key, r_val,
                 bucket_base=_shard_of[d] * cfg.local_buckets,
                 fused=fused)
             f_l, ok_l, v_l = _engine.inverse_route(axis, tgt, found, ok, value)
-            table = XorHashTable(table.q_masks, sk, sv, sb, cfg)
+            table = XorHashTable(table.q_masks, packed, cfg)
             return table, StepResults(found=f_l, value=v_l, ok=ok_l,
                                       bucket=bucket)
 
@@ -321,14 +327,13 @@ def make_distributed_stream(mesh: Mesh, cfg: HashTableConfig,
                     cfg, axis, bucket, ops, keys, vals, bucket,
                     pair_capacity=q_cap, routed_width=nr, routed_steps=tr)
             r_op, r_key, r_val, r_bkt = routed
-            sk, sv, sb, found, ok, value = _engine.run_stream_local(
-                cfg, table.store_keys, table.store_vals, table.store_valid,
-                pe, r_bkt, r_op, r_key, r_val,
+            packed, found, ok, value = _engine.run_stream_local(
+                cfg, table.packed, pe, r_bkt, r_op, r_key, r_val,
                 bucket_base=_shard_of[d] * cfg.local_buckets,
                 fused=fused)
             f_l, ok_l, v_l = _engine.inverse_route_bounded(
                 axis, carry, found, ok, value)
-            table = XorHashTable(table.q_masks, sk, sv, sb, cfg)
+            table = XorHashTable(table.q_masks, packed, cfg)
             return table, StepResults(found=f_l, value=v_l, ok=ok_l,
                                       bucket=bucket)
 
@@ -428,8 +433,7 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
     # the serving copy carries the report home
     _shard_of = jnp.asarray(_engine.replica_layout(cfg)[0], jnp.int32)
 
-    table_spec = XorHashTable(P(), P(None, None, axis),
-                              P(None, None, axis), P(None, None, axis), cfg)
+    table_spec = XorHashTable(P(), _parts(axis), cfg)
     shmap = lambda body: jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(table_spec, P(None, axis), P(None, axis), P(None, axis)),
@@ -446,13 +450,14 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
         fk = r_key.reshape(-1, Wk)
         fv = r_val.reshape(-1, Wv)
         fl = r_live.reshape(-1)
-        sk, sv, sb, placed, spilled, slot, first, max_load = \
+        packed, placed, spilled, slot, first, max_load = \
             _engine.bulk_place_records(
-                cfg, table.store_keys, table.store_vals, table.store_valid,
-                fb, fk, fv, fl, bucket_base=_shard_of[d] * cfg.local_buckets,
+                cfg, table.packed, fb, fk, fv, fl,
+                bucket_base=_shard_of[d] * cfg.local_buckets,
                 backend=backend)
         shape = r_bkt.shape
-        return (sk, sv, sb, placed.reshape(shape), spilled.reshape(shape),
+        return (XorHashTable(table.q_masks, packed, cfg),
+                placed.reshape(shape), spilled.reshape(shape),
                 slot.reshape(shape), first.reshape(shape),
                 jax.lax.pmax(max_load, axis))
 
@@ -471,11 +476,10 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
             else:
                 (r_key, r_val, r_bkt, r_live), tgt = _engine.route_stream(
                     cfg, axis, bucket, keys, vals, bucket, live)
-            sk, sv, sb, placed, spilled, slot, first, max_load = _local_sweep(
+            table, placed, spilled, slot, first, max_load = _local_sweep(
                 table, r_bkt, r_key, r_val, r_live, d)
             p_l, s_l, sl_l, f_l = _engine.inverse_route(axis, tgt, placed,
                                                         spilled, slot, first)
-            table = XorHashTable(table.q_masks, sk, sv, sb, cfg)
             return table, p_l, s_l, sl_l, f_l, bucket, max_load
 
         return shmap(body)
@@ -499,11 +503,10 @@ def make_distributed_bulk_build(mesh: Mesh, cfg: HashTableConfig,
             r_key, r_val, r_bkt, r_live = routed
             # dead routed padding carries pe == D (zeros elsewhere too, but
             # the explicit live word is the single source of truth)
-            sk, sv, sb, placed, spilled, slot, first, max_load = _local_sweep(
+            table, placed, spilled, slot, first, max_load = _local_sweep(
                 table, r_bkt, r_key, r_val, r_live & (pe < n_dev), d)
             p_l, s_l, sl_l, f_l = _engine.inverse_route_bounded(
                 axis, carry, placed, spilled, slot, first)
-            table = XorHashTable(table.q_masks, sk, sv, sb, cfg)
             return table, p_l, s_l, sl_l, f_l, bucket, max_load
 
         return shmap(body)
@@ -560,13 +563,10 @@ def make_distributed_compact(mesh: Mesh, cfg: HashTableConfig,
     end; same semantics per partition as ``engine.compact``."""
     n_dev = mesh.shape[axis]
     cfg.validate_mesh(n_dev, axis)
-    table_spec = XorHashTable(P(), P(None, None, axis),
-                              P(None, None, axis), P(None, None, axis), cfg)
+    table_spec = XorHashTable(P(), _parts(axis), cfg)
 
     def body(table):
-        local = XorHashTable(table.q_masks, table.store_keys,
-                             table.store_vals, table.store_valid, cfg)
-        return _engine.compact(local, backend=backend)
+        return _engine.compact(table, backend=backend)
 
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(table_spec,),
                                  out_specs=table_spec, check_vma=False))
@@ -584,15 +584,11 @@ def make_distributed_reconfigure(mesh: Mesh, cfg: HashTableConfig,
     n_dev = mesh.shape[axis]
     cfg.validate_mesh(n_dev, axis)
     new_cfg.validate_mesh(n_dev, axis)
-    in_spec = XorHashTable(P(), P(None, None, axis),
-                           P(None, None, axis), P(None, None, axis), cfg)
-    out_spec = XorHashTable(P(), P(None, None, axis),
-                            P(None, None, axis), P(None, None, axis), new_cfg)
+    in_spec = XorHashTable(P(), _parts(axis), cfg)
+    out_spec = XorHashTable(P(), _parts(axis), new_cfg)
 
     def body(table):
-        local = XorHashTable(table.q_masks, table.store_keys,
-                             table.store_vals, table.store_valid, cfg)
-        return _engine.reconfigure(local, new_cfg, backend=backend)
+        return _engine.reconfigure(table, new_cfg, backend=backend)
 
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
                                  out_specs=out_spec, check_vma=False))
@@ -657,13 +653,11 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
     lib = cfg.local_index_bits
     g = new_cfg.index_bits - cfg.index_bits
     bl_old = cfg.local_buckets
-    Wk, Wv, S = cfg.key_words, cfg.val_words, cfg.slots
+    Wk = cfg.key_words
     _shard_of = jnp.asarray(_engine.replica_layout(cfg)[0], jnp.int32)
 
-    pred_spec = XorHashTable(P(), P(None, None, axis),
-                             P(None, None, axis), P(None, None, axis), cfg)
-    succ_spec = XorHashTable(P(), P(None, None, axis), P(None, None, axis),
-                             P(None, None, axis), new_cfg)
+    pred_spec = XorHashTable(P(), _parts(axis), cfg)
+    succ_spec = XorHashTable(P(), _parts(axis), new_cfg)
 
     def begin(table: XorHashTable, rng: jax.Array | None = None):
         """Open the resize: allocate the empty sharded successor (extended
@@ -675,18 +669,9 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
             rng = jax.random.PRNGKey(new_buckets)
         qm = _engine.successor_masks(
             jnp.asarray(jax.device_get(table.q_masks)), cfg, new_cfg, rng)
-        rep = NamedSharding(mesh, P())
-        shard_b = NamedSharding(mesh, P(None, None, axis))
-        R, k = new_cfg.replicas, new_cfg.k
-        B = n_dev * new_cfg.local_buckets   # replica groups: copies per dev
-        zeros = lambda shape: jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
-                                      out_shardings=shard_b)()
         succ = XorHashTable(
-            q_masks=jax.device_put(qm, rep),
-            store_keys=zeros((R, k, B, S, Wk)),
-            store_vals=zeros((R, k, B, S, Wv)),
-            store_valid=zeros((R, k, B, S)),
-            cfg=new_cfg)
+            q_masks=jax.device_put(qm, NamedSharding(mesh, P())),
+            packed=_empty_partitions(mesh, axis, new_cfg), cfg=new_cfg)
         return _engine.ResizeState(pred=table, succ=succ, watermark=0)
 
     def _local_stream(pred, succ, w, ops, keys, vals):
@@ -709,22 +694,19 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
         mig = (r_bo & jnp.uint32(bl_old - 1)) < w
         # each side sees the other's lanes as dead NOP padding (routing
         # padding already rides as op 0 — the same contract)
-        pk, pv, pb, f_p, ok_p, v_p = _engine.run_stream_local(
-            cfg, pred.store_keys, pred.store_vals, pred.store_valid,
-            pe, r_bo, jnp.where(mig, 0, r_op), r_key, r_val,
-            bucket_base=_shard_of[d] * bl_old,
-            fused=fused)
-        sk, sv, sb, f_s, ok_s, v_s = _engine.run_stream_local(
-            new_cfg, succ.store_keys, succ.store_vals, succ.store_valid,
-            pe, r_bn, jnp.where(mig, r_op, 0), r_key, r_val,
-            bucket_base=_shard_of[d] * new_cfg.local_buckets,
+        p_tab, f_p, ok_p, v_p = _engine.run_stream_local(
+            cfg, pred.packed, pe, r_bo, jnp.where(mig, 0, r_op), r_key,
+            r_val, bucket_base=_shard_of[d] * bl_old, fused=fused)
+        s_tab, f_s, ok_s, v_s = _engine.run_stream_local(
+            new_cfg, succ.packed, pe, r_bn, jnp.where(mig, r_op, 0), r_key,
+            r_val, bucket_base=_shard_of[d] * new_cfg.local_buckets,
             fused=fused)
         found = jnp.where(mig, f_s, f_p)
         ok = jnp.where(mig, ok_s, ok_p)
         value = jnp.where(mig[..., None], v_s, v_p)
         f_l, ok_l, v_l = _engine.inverse_route(axis, tgt, found, ok, value)
-        pred = XorHashTable(pred.q_masks, pk, pv, pb, cfg)
-        succ = XorHashTable(succ.q_masks, sk, sv, sb, new_cfg)
+        pred = XorHashTable(pred.q_masks, p_tab, cfg)
+        succ = XorHashTable(succ.q_masks, s_tab, new_cfg)
         return pred, succ, StepResults(found=f_l, value=v_l, ok=ok_l,
                                        bucket=b_new)
 
@@ -749,30 +731,16 @@ def make_distributed_resize(mesh: Mesh, cfg: HashTableConfig,
     def _migrate_jit(n: int):
         def body(pred, succ, w):
             d = jax.lax.axis_index(axis)
-            sl = lambda x: jax.lax.dynamic_slice_in_dim(x, w, n, axis=2)
-            pk = _engine.xor_reduce(sl(pred.store_keys)[0], axis=0)
-            pv = _engine.xor_reduce(sl(pred.store_vals)[0], axis=0)
-            pb = _engine.xor_reduce(sl(pred.store_valid)[0], axis=0)
-            keys = pk.reshape(n * S, Wk)
-            vals = pv.reshape(n * S, Wv)
-            live = (pb & 1).reshape(n * S).astype(jnp.bool_)
-            local = (w + jnp.repeat(jnp.arange(n, dtype=jnp.uint32), S))
-            b_old = (_shard_of[d].astype(jnp.uint32) << lib) | local
+            keys, vals, live, rows = _engine.slab_records(pred, w, n)
+            b_old = (_shard_of[d].astype(jnp.uint32) << lib) | (w + rows)
             extra = _h3(keys, succ.q_masks[lib:lib + g])
             b_new = _engine.resize_buckets(b_old, extra, lib, g, bl_old)
-            sk, sv, sb, _, _, _, _, _ = _engine.bulk_place_records(
-                new_cfg, succ.store_keys, succ.store_vals, succ.store_valid,
-                b_new, keys, vals, live,
+            packed = _engine.bulk_place_records(
+                new_cfg, succ.packed, b_new, keys, vals, live,
                 bucket_base=_shard_of[d] * new_cfg.local_buckets,
-                backend=backend)
-            zero = lambda x: jax.lax.dynamic_update_slice_in_dim(
-                x, jnp.zeros(x.shape[:2] + (n,) + x.shape[3:], x.dtype),
-                w, axis=2)
-            pred = XorHashTable(pred.q_masks, zero(pred.store_keys),
-                                zero(pred.store_vals),
-                                zero(pred.store_valid), cfg)
-            succ = XorHashTable(succ.q_masks, sk, sv, sb, new_cfg)
-            return pred, succ
+                backend=backend)[0]
+            return (_engine.zero_slab(pred, w, n),
+                    XorHashTable(succ.q_masks, packed, new_cfg))
 
         return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(pred_spec, succ_spec, P()),

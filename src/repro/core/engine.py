@@ -62,7 +62,8 @@ import jax.numpy as jnp
 
 from repro.core.config import HashTableConfig, round_up_lanes as _round_up_lanes
 from repro.core.hash_table import (OP_DELETE, OP_INSERT, OP_SEARCH,
-                                   QueryBatch, StepResults, XorHashTable)
+                                   QueryBatch, StepResults, XorHashTable,
+                                   packed_shape)
 from repro.core.hashing import h3_hash as _h3_jnp, make_h3_params
 from repro.core.xor_memory import xor_reduce
 
@@ -82,7 +83,7 @@ __all__ = [
     "bulk_build", "extract_records", "compact", "reconfigure",
     "RECONFIGURE_FROZEN_FIELDS",
     "ResizeState", "successor_masks", "begin_resize", "run_stream_resize",
-    "migrate_slab", "finish_resize",
+    "migrate_slab", "finish_resize", "slab_records", "zero_slab",
     "register_backend", "get_backend", "resolve_backend", "available_backends",
 ]
 
@@ -165,23 +166,25 @@ def staggered_open_slot(open_mask: jnp.ndarray, port: jnp.ndarray) -> jnp.ndarra
 
 
 def probe_jnp(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
-              store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-              store_valid: jnp.ndarray, replica: Optional[jnp.ndarray] = None,
+              packed: jnp.ndarray, *, k: int, slots: int, val_words: int,
+              part_buckets: int, replica: Optional[jnp.ndarray] = None,
               stagger: bool = False):
     """The jnp probe stage (semantic oracle for ``xor_probe_pallas``).
 
-    store_* carry the full replica axis ``[R, k, B, S, W]``; ``replica`` maps
-    each lane to the replica it reads (None == replica 0 for all lanes).
-    Returns the same tuple as the Pallas kernel: (found, match_slot,
-    open_slot, has_open, value, rem_keys, rem_vals, rem_valid).
+    ``packed`` is the stored array ``[R, G, C, 128]`` in partitions of
+    ``part_buckets`` buckets; each lane gathers its bucket's column only.
+    ``replica`` maps each lane to the replica it reads (None == replica 0
+    for all lanes).  Returns the same tuple as the Pallas kernel: (found,
+    match_slot, open_slot, has_open, value, rem_keys, rem_vals, rem_valid).
     """
-    idx = bucket.astype(jnp.int32)
-    if replica is None:
-        replica = jnp.zeros_like(idx)
+    from repro.kernels import packed as pk
+    Wk = qkeys.shape[-1]
+    W = Wk + val_words + 1
+    col = pk.stored_column(bucket.astype(jnp.int32), part_buckets)
     # parallel partial-store read: [N, k, S, W] gather
-    enc_keys = store_keys[replica, :, idx]
-    enc_vals = store_vals[replica, :, idx]
-    enc_valid = store_valid[replica, :, idx]
+    enc = pk.planes(pk.columns(packed, col, replica), k, slots, W)
+    enc_keys, enc_vals = enc[..., :Wk], enc[..., Wk:W - 1]
+    enc_valid = enc[..., W - 1]
     # search XOR reduction trees
     dec_keys = xor_reduce(enc_keys, axis=1)                        # [N, S, Wk]
     dec_vals = xor_reduce(enc_vals, axis=1)                        # [N, S, Wv]
@@ -253,9 +256,13 @@ def encode_records(pr: ProbeResult, plan: MutationPlan) -> Dict[str, jnp.ndarray
                 enc_k=enc_k, enc_v=enc_v, enc_b=enc_b)
 
 
-def _scatter_records(store_keys, store_vals, store_valid, rec):
-    """Masked scatter of encoded records into every replica (the inter-PE
-    propagation).  Masked lanes carry an out-of-range bucket -> dropped.
+def _scatter_records(packed, rec, *, buckets: int, slots: int,
+                     part_buckets: int):
+    """Masked scatter of encoded records into every replica of the stored
+    array ``packed`` (``[R, G, C, 128]`` or one replica ``[G, C, 128]``,
+    ``buckets`` buckets in partitions of ``part_buckets``) — the inter-PE
+    propagation, writing only the records' own rows.  Masked lanes carry an
+    out-of-range bucket (``>= buckets``) -> dropped.
 
     Duplicate (port, bucket, slot) targets resolve **last-wins in record
     order** (program order), matching the Pallas commit kernel's sequential
@@ -264,9 +271,9 @@ def _scatter_records(store_keys, store_vals, store_valid, rec):
     queries_per_pe == 1 write lanes have distinct ports and this is a no-op;
     duplicates only arise beyond the paper's one-write-per-port-per-cycle
     regime.)"""
+    from repro.kernels import packed as pk
     port, bucket, slot = rec["port"], rec["bucket"], rec["slot"]
-    R = store_keys.shape[0]
-    B, S = store_keys.shape[2], store_keys.shape[3]
+    B, S = buckets, slots
     tgt = (port * (B + 1) + bucket) * S + slot                      # [N]
     live = bucket < B                                               # write lanes
     # lane i is superseded iff it is not the segment-max lane index of its
@@ -282,36 +289,42 @@ def _scatter_records(store_keys, store_vals, store_valid, rec):
     sup_sorted = jnp.concatenate(
         [stgt[:-1] == stgt[1:], jnp.zeros((1,), jnp.bool_)])
     superseded = jnp.zeros(tgt.shape, jnp.bool_).at[order].set(sup_sorted)
-    bucket = jnp.where(superseded, jnp.int32(B), bucket)
-    sk = store_keys.at[:, port, bucket, slot, :].set(
-        jnp.broadcast_to(rec["enc_k"], (R,) + rec["enc_k"].shape), mode="drop")
-    sv = store_vals.at[:, port, bucket, slot, :].set(
-        jnp.broadcast_to(rec["enc_v"], (R,) + rec["enc_v"].shape), mode="drop")
-    sb = store_valid.at[:, port, bucket, slot].set(
-        jnp.broadcast_to(rec["enc_b"], (R,) + rec["enc_b"].shape), mode="drop")
-    return sk, sv, sb
+    write = live & ~superseded
+    words = jnp.concatenate(
+        [rec["enc_k"], rec["enc_v"], rec["enc_b"][:, None]], axis=-1)
+    col = jnp.where(write, pk.stored_column(bucket.astype(jnp.int32),
+                                            part_buckets),
+                    packed.shape[-3] * pk.LANES)                    # drop
+    rows = pk.entry_rows(port.astype(jnp.int32), slot.astype(jnp.int32), S,
+                         words.shape[-1])
+    return pk.set_words(packed, col, rows, words)
 
 
 def commit_records(table: XorHashTable, rec: Dict[str, jnp.ndarray]
                    ) -> XorHashTable:
-    """Apply a flat batch of encoded mutation records to a table."""
-    sk, sv, sb = _scatter_records(table.store_keys, table.store_vals,
-                                  table.store_valid, rec)
-    return XorHashTable(table.q_masks, sk, sv, sb, table.cfg)
+    """Apply a flat batch of encoded mutation records to a table (the jnp
+    oracle: an O(records) scatter into the stored array)."""
+    cfg = table.cfg
+    packed = _scatter_records(table.packed, rec, buckets=table.buckets,
+                              slots=cfg.slots,
+                              part_buckets=cfg.local_buckets)
+    return XorHashTable(table.q_masks, packed, cfg)
 
 
-def commit_jnp(store_keys, store_vals, store_valid, port, bucket, slot,
-               do_write, new_key, new_val, new_valid):
+def commit_jnp(packed, port, bucket, slot, do_write, new_key, new_val,
+               new_valid, *, k: int, buckets: int, slots: int):
     """Raw-array jnp encode+commit (semantic oracle for ``xor_commit_pallas``).
 
-    store_* ``[R, k, B, S, W*]``; lane vectors as in the kernel (``bucket ==
-    B`` marks a masked lane).  Recomputes the encode basis from the snapshot —
-    use :func:`encode_records` when a ProbeResult is already in hand.
+    ``packed`` the stored array ``[R, G, C, 128]`` of ``buckets`` buckets;
+    lane vectors as in the kernel (``bucket == buckets`` marks a masked
+    lane).  Recomputes the encode basis from the snapshot — use
+    :func:`encode_records` when a ProbeResult is already in hand.
     """
-    B = store_keys.shape[2]
+    B = buckets
     idx = jnp.minimum(bucket, B - 1).astype(jnp.int32)
     _, _, _, _, _, remk, remv, remb = probe_jnp(
-        idx, port, new_key, store_keys, store_vals, store_valid)
+        idx, port, new_key, packed, k=k, slots=slots,
+        val_words=new_val.shape[-1], part_buckets=B)
     rec = dict(port=port,
                bucket=jnp.where(do_write, bucket.astype(jnp.int32),
                                 jnp.int32(B)),
@@ -319,7 +332,8 @@ def commit_jnp(store_keys, store_vals, store_valid, port, bucket, slot,
                enc_k=new_key ^ _pick_slot(remk, slot),
                enc_v=new_val ^ _pick_slot(remv, slot),
                enc_b=new_valid ^ _pick_slot(remb, slot))
-    return _scatter_records(store_keys, store_vals, store_valid, rec)
+    return _scatter_records(packed, rec, buckets=B, slots=slots,
+                            part_buckets=B)
 
 
 # ---------------------------------------------------------------------------
@@ -354,6 +368,22 @@ def _empty_stream_results(cfg: HashTableConfig, n: int) -> StepResults:
                        bucket=jnp.zeros((0, n), jnp.uint32))
 
 
+def _kernel_buckets(table: XorHashTable) -> int:
+    """Buckets of the one partition a single-domain kernel addresses as
+    group ``b // 128``, lane ``b % 128``.  A sharded table whose partitions
+    do not fill whole groups runs its partitions under shard_map
+    (core.distributed) instead."""
+    from repro.kernels.packed import packed_groups
+    B = table.buckets
+    if table.packed.shape[1] != packed_groups(B):
+        raise ValueError(
+            f"the table's {table.packed.shape[1]} bucket groups hold "
+            f"{B // table.cfg.local_buckets} partitions of "
+            f"{table.cfg.local_buckets} buckets; stream each partition "
+            f"through core.distributed (shard_map)")
+    return B
+
+
 class JnpBackend:
     """Pure jax.numpy dataflow — current semantics, the bit-exact oracle."""
 
@@ -368,9 +398,10 @@ class JnpBackend:
         replica = pe if cfg.replicate_reads else jnp.zeros_like(pe)
         port = jnp.minimum(pe, cfg.k - 1).astype(jnp.int32)
         bucket = _h3_jnp(batch.key, table.q_masks)
-        outs = probe_jnp(bucket, port, batch.key, table.store_keys,
-                         table.store_vals, table.store_valid,
-                         replica=replica, stagger=cfg.stagger_slots)
+        outs = probe_jnp(bucket, port, batch.key, table.packed, k=cfg.k,
+                         slots=cfg.slots, val_words=cfg.val_words,
+                         part_buckets=cfg.local_buckets, replica=replica,
+                         stagger=cfg.stagger_slots)
         return ProbeResult(bucket, pe, *outs)
 
     def commit(self, table: XorHashTable, pr: ProbeResult, batch: QueryBatch,
@@ -383,22 +414,22 @@ class JnpBackend:
                    ) -> Tuple[XorHashTable, StepResults]:
         return _scan_stream(table, ops, keys, vals, backend=self.name)
 
-    def bulk_place(self, plane_k, plane_v, plane_b, w_bucket, w_slot,
-                   keys, vals):
+    def bulk_place(self, table, w_bucket, w_slot, keys, vals, *,
+                   buckets: int, slots: int):
         """Plaintext placement of pre-planned records into the port-0 plane
-        (``[B, S, W*]``) — the jnp oracle for the placement kernel.
-        Targets are pairwise distinct by construction (plan_bulk_build), so
-        a plain masked scatter needs no supersession pass.  The three planes
-        scatter as ONE packed ``[B, S, Wk+Wv+1]`` write: a scatter's cost is
-        dominated by its per-row index handling, so fusing pays ~3x."""
-        Wk, Wv = keys.shape[-1], vals.shape[-1]
-        packed = jnp.concatenate(
-            [plane_k, plane_v, plane_b[..., None]], axis=-1)
-        rows = jnp.concatenate(
+        of one replica's stored array ``[G, C, 128]`` — the jnp oracle for
+        the placement kernel.  ``w_bucket`` addresses the array's columns
+        (``packed.stored_column``; ``>= buckets`` masks a record).  Targets
+        are pairwise distinct by construction (plan_bulk_build), so a plain
+        masked scatter needs no supersession pass."""
+        from repro.kernels import packed as pk
+        words = jnp.concatenate(
             [keys, vals, jnp.ones((keys.shape[0], 1), jnp.uint32)], axis=-1)
-        packed = packed.at[w_bucket, w_slot, :].set(rows, mode="drop")
-        return (packed[..., :Wk], packed[..., Wk:Wk + Wv],
-                packed[..., Wk + Wv])
+        b = w_bucket.astype(jnp.int32)
+        col = jnp.where(b < buckets, b, table.shape[0] * pk.LANES)  # drop
+        rows = pk.entry_rows(0, w_slot.astype(jnp.int32), slots,
+                             words.shape[1])
+        return pk.set_words(table, col, rows, words)
 
 
 class PallasBackend:
@@ -417,8 +448,9 @@ class PallasBackend:
         bucket = kops.h3_hash(batch.key, table.q_masks)
         # Replicas are byte-identical (commit writes all of them), so the
         # kernel probes replica 0 — one VMEM-resident table per core.
-        outs = kops.xor_probe(bucket, port, batch.key, table.store_keys[0],
-                              table.store_vals[0], table.store_valid[0],
+        outs = kops.xor_probe(bucket, port, batch.key, table.packed[0],
+                              k=cfg.k, buckets=_kernel_buckets(table),
+                              slots=cfg.slots, val_words=cfg.val_words,
                               stagger=cfg.stagger_slots)
         return ProbeResult(bucket, pe, *outs)
 
@@ -432,11 +464,14 @@ class PallasBackend:
         # identical gather+XOR-tree encodes); ops.xor_commit falls back to
         # the jnp scatter past the VMEM budget.
         rec = encode_records(pr, plan)
-        sk, sv, sb = kops.xor_commit(
-            table.store_keys, table.store_vals, table.store_valid,
-            rec["port"], rec["bucket"], rec["slot"],
-            rec["enc_k"], rec["enc_v"], rec["enc_b"])
-        return XorHashTable(table.q_masks, sk, sv, sb, table.cfg)
+        cfg = table.cfg
+        tab = kops.xor_commit(
+            table.packed[0], rec["port"], rec["bucket"], rec["slot"],
+            rec["enc_k"], rec["enc_v"], rec["enc_b"], k=cfg.k,
+            buckets=_kernel_buckets(table), slots=cfg.slots)
+        return XorHashTable(
+            table.q_masks, jnp.broadcast_to(tab[None], table.packed.shape),
+            cfg)
 
     def run_stream(self, table: XorHashTable, ops: jnp.ndarray,
                    keys: jnp.ndarray, vals: jnp.ndarray
@@ -448,9 +483,11 @@ class PallasBackend:
         (``kernels.ops.stream_bucket_tiles``; the budget is read at trace
         time).
 
-        Replicas are byte-identical at step boundaries (commit writes all of
-        them), so the kernel streams over replica 0 and the result is
-        broadcast back to all R replicas."""
+        The kernel takes replica 0 of the stored array as it is and updates
+        it in place: at ``R == 1`` no layout op touches the table.  Replicas
+        are byte-identical at step boundaries (commit writes all of them),
+        so at ``R > 1`` the one stream over replica 0 is broadcast back to
+        all R replicas."""
         from repro.kernels import ops as kops
         cfg = table.cfg
         T, N = ops.shape
@@ -462,28 +499,25 @@ class PallasBackend:
         with jax.named_scope("kv.h3"):
             bucket = kops.h3_hash(keys.reshape(T * N, cfg.key_words),
                                   table.q_masks).reshape(T, N)
-        sk, sv, sb, found, ok, value = kops.xor_stream(
-            bucket, port, legal, ops, keys, vals, table.store_keys[0],
-            table.store_vals[0], table.store_valid[0],
+        tab, found, ok, value = kops.xor_stream(
+            bucket, port, legal, ops, keys, vals, table.packed[0], k=cfg.k,
+            buckets=_kernel_buckets(table), slots=cfg.slots,
             stagger=cfg.stagger_slots)
-        R = table.store_keys.shape[0]
-        with jax.named_scope("kv.table_unpack"):
-            new_table = XorHashTable(
-                table.q_masks,
-                jnp.broadcast_to(sk[None], (R,) + sk.shape),
-                jnp.broadcast_to(sv[None], (R,) + sv.shape),
-                jnp.broadcast_to(sb[None], (R,) + sb.shape), cfg)
+        # one replica: a reshape; more: the replica invariant's broadcast
+        new_table = XorHashTable(
+            table.q_masks, jnp.broadcast_to(tab[None], table.packed.shape),
+            cfg)
         return new_table, StepResults(found=found, value=value, ok=ok,
                                       bucket=bucket)
 
-    def bulk_place(self, plane_k, plane_v, plane_b, w_bucket, w_slot,
-                   keys, vals):
+    def bulk_place(self, table, w_bucket, w_slot, keys, vals, *,
+                   buckets: int, slots: int):
         """The placement kernel (kernels.bulk_place): records sorted by
-        bucket, each touched bucket group moved in and out of VMEM once.
-        Interpret mode off-TPU."""
+        stored column, each touched bucket group moved in and out of VMEM
+        once.  Interpret mode off-TPU."""
         from repro.kernels import ops as kops
-        return kops.bulk_place(w_bucket, w_slot, keys, vals,
-                               plane_k, plane_v, plane_b)
+        return kops.bulk_place(w_bucket, w_slot, keys, vals, table,
+                               buckets=buckets, slots=slots)
 
 
 _BACKENDS: Dict[str, object] = {}
@@ -532,8 +566,7 @@ def resolve_backend(cfg: HashTableConfig, table: Optional[XorHashTable] = None):
     from repro.kernels import ops as kops
     name = _resolve_name(cfg)
     if name == "pallas" and table is not None:
-        if not kops.fits_vmem(table.store_keys, table.store_vals,
-                              table.store_valid):
+        if not kops.fits_vmem(table.packed):
             name = "jnp"
     return get_backend(name)
 
@@ -688,54 +721,53 @@ def inverse_route(axis: str, tgt: jnp.ndarray, *arrays: jnp.ndarray):
     return _unpack_u32(jnp.take_along_axis(back, idx, axis=1), meta)
 
 
-def run_stream_local(cfg: HashTableConfig, store_keys: jnp.ndarray,
-                     store_vals: jnp.ndarray, store_valid: jnp.ndarray,
+def run_stream_local(cfg: HashTableConfig, packed: jnp.ndarray,
                      pe: jnp.ndarray, bucket: jnp.ndarray, ops: jnp.ndarray,
                      keys: jnp.ndarray, vals: jnp.ndarray, *,
                      bucket_base, backend: Optional[str] = None,
                      fused: Optional[bool] = None):
     """Stream ``[T, Nr]`` routed queries through ONE bucket-shard partition.
 
-    ``store_*`` ``[R, k, local_buckets, S, W]`` hold the global bucket range
-    ``[bucket_base, bucket_base + local_buckets)``; ``bucket`` carries the
-    precomputed GLOBAL indices.  Lanes outside the partition (router padding
-    or foreign shards) are inert: no writes, found/ok False, value 0.  ``pe``
+    ``packed`` ``[R, G, C, 128]`` is the partition's stored array, holding
+    the ``cfg.local_buckets`` global buckets ``[bucket_base, bucket_base +
+    local_buckets)``; ``bucket`` carries the precomputed GLOBAL indices.
+    Lanes outside the partition (router padding or foreign shards) are
+    inert: no writes, found/ok False, value 0.  ``pe``
     is per routed lane — ``[Nr]`` (skew-proof routing: lane -> origin is
     step-invariant) or ``[T, Nr]`` (bounded routing: rows are re-binned
     mixtures, so the origin varies per step).  On the pallas backend this is
     the fused ``xor_stream`` kernel with the bucket-base offset (the
     sweep-pass sizing reused unchanged); elsewhere the scanned jnp oracle
-    with the same partition masking.  Returns ``(store_keys', store_vals',
-    store_valid', found, ok, value)``.
+    with the same partition masking, on the stored array.
+    Returns ``(packed', found, ok, value)``.
     """
     name = _resolve_name(cfg, backend)
     use_fused = fused if fused is not None else (name == "pallas")
-    k = cfg.k
+    k, Bl = cfg.k, cfg.local_buckets
     port = jnp.minimum(pe, k - 1).astype(jnp.int32)
     base = jnp.asarray(bucket_base).astype(jnp.int32)
-    R = store_keys.shape[0]
     if use_fused:
         from repro.kernels import ops as kops
         legal = (pe < k).astype(jnp.int32)
-        sk, sv, sb, found, ok, value = kops.xor_stream(
-            bucket, port, legal, ops, keys, vals, store_keys[0],
-            store_vals[0], store_valid[0], stagger=cfg.stagger_slots,
+        tab, found, ok, value = kops.xor_stream(
+            bucket, port, legal, ops, keys, vals, packed[0], k=k,
+            buckets=Bl, slots=cfg.slots, stagger=cfg.stagger_slots,
             bucket_base=base)
-        bc = lambda x: jnp.broadcast_to(x[None], (R,) + x.shape)
-        return bc(sk), bc(sv), bc(sb), found, ok, value
+        return jnp.broadcast_to(tab[None], packed.shape), found, ok, value
 
-    Bl = store_keys.shape[2]
     pe_t = jnp.broadcast_to(pe, ops.shape) if pe.ndim == 1 else pe
     port_t = jnp.broadcast_to(port, ops.shape) if port.ndim == 1 else port
 
-    def body(carry, xs):
-        sk, sv, sb = carry
+    def body(tab, xs):
         op, key, val, bkt, pe_s, port_s = xs
         rel = bkt.astype(jnp.int32) - base
         in_part = (rel >= 0) & (rel < Bl)
         idx = jnp.clip(rel, 0, Bl - 1)
         (found, mslot, oslot, hopen, value,
-         remk, remv, remb) = probe_jnp(idx, port_s, key, sk, sv, sb,
+         remk, remv, remb) = probe_jnp(idx, port_s, key, tab, k=k,
+                                       slots=cfg.slots,
+                                       val_words=cfg.val_words,
+                                       part_buckets=Bl,
                                        stagger=cfg.stagger_slots)
         # mask the probe to the partition, then reuse the single-domain
         # mutation semantics verbatim (one source of truth): out-of-partition
@@ -751,13 +783,13 @@ def run_stream_local(cfg: HashTableConfig, store_keys: jnp.ndarray,
                          rem_valid=remb)
         plan = mutation_plan(cfg, QueryBatch(op, key, val), pr)
         ok = plan.ok & jnp.where(op == OP_SEARCH, in_part, True)
-        sk, sv, sb = _scatter_records(sk, sv, sb, encode_records(pr, plan))
-        return (sk, sv, sb), (found, ok, value)
+        tab = _scatter_records(tab, encode_records(pr, plan), buckets=Bl,
+                               slots=cfg.slots, part_buckets=Bl)
+        return tab, (found, ok, value)
 
-    (sk, sv, sb), (found, ok, value) = jax.lax.scan(
-        body, (store_keys, store_vals, store_valid),
-        (ops, keys, vals, bucket, pe_t, port_t))
-    return sk, sv, sb, found, ok, value
+    packed, (found, ok, value) = jax.lax.scan(
+        body, packed, (ops, keys, vals, bucket, pe_t, port_t))
+    return packed, found, ok, value
 
 
 # ---------------------------------------------------------------------------
@@ -1540,47 +1572,54 @@ def plan_bulk_build(keys: jnp.ndarray, vals: jnp.ndarray, bucket: jnp.ndarray,
         max_load=jnp.max(jnp.where(is_rep, rank + 1, 0)).astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("backend_name",))
-def _place_into_stores(store_keys, store_vals, store_valid, w_bucket, w_slot,
-                       keys, val_w, *, backend_name: str):
-    """The device half of a bulk placement: scatter the planned records into
-    the port-0 plane and broadcast it to every replica.  Jitted so the
-    eager host-planned path (``bulk_build`` outside ``jax.jit``) pays one
-    fused dispatch, not one per op; under an outer trace it inlines."""
-    be = get_backend(backend_name)
-    pk, pv, pb = be.bulk_place(
-        store_keys[0, 0], store_vals[0, 0], store_valid[0, 0],
-        w_bucket, w_slot, keys, val_w)
+@functools.partial(jax.jit, static_argnames=("backend_name", "buckets",
+                                             "part_buckets", "slots"))
+def _place_into_stores(packed, w_bucket, w_slot, keys, val_w, *,
+                       backend_name: str, buckets: int, part_buckets: int,
+                       slots: int):
+    """The device half of a bulk placement: scatter the planned records
+    (``w_bucket == buckets`` masks one) into the port-0 plane of replica
+    0's stored array, in partitions of ``part_buckets``, and broadcast it to
+    every replica.  Jitted so the eager host-planned path (``bulk_build``
+    outside ``jax.jit``) pays one fused dispatch, not one per op; under an
+    outer trace it inlines."""
+    from repro.kernels import packed as pk
+    cols = packed.shape[-3] * pk.LANES
+    col = jnp.where(w_bucket < buckets,
+                    pk.stored_column(w_bucket, part_buckets), cols)
+    tab = get_backend(backend_name).bulk_place(
+        packed[0], col, w_slot, keys, val_w, buckets=cols, slots=slots)
     # every record writes port 0 of every replica (replica invariant)
-    return (store_keys.at[:, 0].set(pk), store_vals.at[:, 0].set(pv),
-            store_valid.at[:, 0].set(pb))
+    return jnp.broadcast_to(tab[None], packed.shape)
 
 
-def bulk_place_records(cfg: HashTableConfig, store_keys, store_vals,
-                       store_valid, bucket, keys, vals,
+def bulk_place_records(cfg: HashTableConfig, packed, bucket, keys, vals,
                        live: Optional[jnp.ndarray] = None, *,
                        bucket_base=0, backend: Optional[str] = None):
-    """Count-then-place a flat record batch into (a partition of) empty
-    stores — the raw-array core shared by :func:`bulk_build`,
+    """Count-then-place a flat record batch into (a partition of) an empty
+    stored array — the raw-array core shared by :func:`bulk_build`,
     :func:`compact` and the shard_map distributed builder.
 
-    ``store_*`` ``[R, k, B, S, W*]`` hold global buckets ``[bucket_base,
-    bucket_base + B)`` and must be EMPTY over the placed range (all records
+    ``packed`` ``[R, G, C, 128]`` holds one partition of ``B`` global
+    buckets ``[bucket_base, bucket_base + B)`` (the whole table of a single
+    memory domain) and must be EMPTY over the placed range (all records
     land in partial store 0, encode basis zero — the serialized-insert
     oracle's layout).  ``bucket`` carries GLOBAL indices; records outside
-    the partition are treated as dead.  Returns ``(store_keys', store_vals',
-    store_valid', placed, spilled, slot, first, max_load)``.
+    the partition are treated as dead.  Returns ``(packed', placed,
+    spilled, slot, first, max_load)``.
     """
-    Bl = store_keys.shape[2]
+    from repro.kernels.packed import stored_buckets
+    Bl = stored_buckets(packed, cfg.local_buckets)
     rel = bucket.astype(jnp.int32) - jnp.asarray(bucket_base, jnp.int32)
     in_part = (rel >= 0) & (rel < Bl)
     live = in_part if live is None else (live & in_part)
     plan = plan_bulk_build(keys, vals, jnp.clip(rel, 0, Bl - 1), live,
-                           buckets=Bl, slots=store_keys.shape[3])
-    sk, sv, sb = _place_into_stores(
-        store_keys, store_vals, store_valid, plan["w_bucket"], plan["w_slot"],
-        keys, plan["val_w"], backend_name=_resolve_name(cfg, backend))
-    return (sk, sv, sb, plan["placed"], plan["spilled"], plan["slot"],
+                           buckets=Bl, slots=cfg.slots)
+    packed = _place_into_stores(
+        packed, plan["w_bucket"], plan["w_slot"], keys, plan["val_w"],
+        backend_name=_resolve_name(cfg, backend), buckets=Bl,
+        part_buckets=cfg.local_buckets, slots=cfg.slots)
+    return (packed, plan["placed"], plan["spilled"], plan["slot"],
             plan["first"], plan["max_load"])
 
 
@@ -1614,13 +1653,12 @@ def bulk_build(table: XorHashTable, keys: jnp.ndarray, vals: jnp.ndarray,
         bucket = kops.h3_hash(keys, table.q_masks)
     else:
         bucket = _h3_jnp(keys, table.q_masks)
-    sk, sv, sb, placed, spilled, slot, first, max_load = bulk_place_records(
-        cfg, table.store_keys, table.store_vals, table.store_valid,
-        bucket, keys, vals, live, backend=name)
+    packed, placed, spilled, slot, first, max_load = bulk_place_records(
+        cfg, table.packed, bucket, keys, vals, live, backend=name)
     report = BulkBuildReport(bucket=bucket.astype(jnp.int32), slot=slot,
                              placed=placed, spilled=spilled, first=first,
                              max_load=max_load)
-    return XorHashTable(table.q_masks, sk, sv, sb, cfg), report
+    return XorHashTable(table.q_masks, packed, cfg), report
 
 
 def extract_records(table: XorHashTable):
@@ -1647,12 +1685,9 @@ def compact(table: XorHashTable, backend: Optional[str] = None
     impossible (at most S live records per bucket come out of S slots)."""
     cfg = table.cfg
     keys, vals, live, bucket = extract_records(table)
-    sk, sv, sb, _, _, _, _, _ = bulk_place_records(
-        cfg, jnp.zeros_like(table.store_keys),
-        jnp.zeros_like(table.store_vals),
-        jnp.zeros_like(table.store_valid),
-        bucket, keys, vals, live, backend=backend)
-    return XorHashTable(table.q_masks, sk, sv, sb, cfg)
+    packed = bulk_place_records(cfg, jnp.zeros_like(table.packed), bucket,
+                                keys, vals, live, backend=backend)[0]
+    return XorHashTable(table.q_masks, packed, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -1768,13 +1803,8 @@ def begin_resize(table: XorHashTable, new_buckets: int,
     if rng is None:
         rng = jax.random.PRNGKey(new_buckets)
     qm = successor_masks(table.q_masks, cfg, new_cfg, rng)
-    R, k, S = new_cfg.replicas, new_cfg.k, new_cfg.slots
-    succ = XorHashTable(
-        qm,
-        jnp.zeros((R, k, new_buckets, S, cfg.key_words), jnp.uint32),
-        jnp.zeros((R, k, new_buckets, S, cfg.val_words), jnp.uint32),
-        jnp.zeros((R, k, new_buckets, S), jnp.uint32),
-        new_cfg)
+    succ = XorHashTable(qm, jnp.zeros(packed_shape(new_cfg), jnp.uint32),
+                        new_cfg)
     return ResizeState(pred=table, succ=succ, watermark=0)
 
 
@@ -1877,32 +1907,49 @@ def _migrate_slab_jit(pred, succ, w, *, n, backend=None):
     cfg, new_cfg = pred.cfg, succ.cfg
     lib = cfg.local_index_bits
     g = new_cfg.index_bits - cfg.index_bits
-    S = cfg.slots
-    sl = lambda x: jax.lax.dynamic_slice_in_dim(x, w, n, axis=2)
-    pk = xor_reduce(sl(pred.store_keys)[0], axis=0)         # [n, S, Wk]
-    pv = xor_reduce(sl(pred.store_vals)[0], axis=0)
-    pb = xor_reduce(sl(pred.store_valid)[0], axis=0)
-    keys = pk.reshape(n * S, cfg.key_words)
-    vals = pv.reshape(n * S, cfg.val_words)
-    live = (pb & 1).reshape(n * S).astype(jnp.bool_)
+    keys, vals, live, rows = slab_records(pred, w, n)
     # single-domain: local bucket == global bucket (the distributed factory
     # runs its own shard-local copy of this sweep with the owner offset)
-    b_old = (w.astype(jnp.uint32)
-             + jnp.repeat(jnp.arange(n, dtype=jnp.uint32), S))
+    b_old = w.astype(jnp.uint32) + rows
     extra = _h3_jnp(keys, succ.q_masks[lib:lib + g])
     b_new = resize_buckets(b_old, extra, lib, g, cfg.local_buckets)
-    # one whole-store place per slab: the scatter itself is O(slab) but XLA
-    # CPU materializes one store-sized copy per update chain, so a single
-    # bulk_place beats per-new-index-bit sliced updates (each full-size
-    # dynamic_update_slice pays that copy again)
-    ssk, ssv, ssb, _, _, _, _, _ = bulk_place_records(
-        new_cfg, succ.store_keys, succ.store_vals, succ.store_valid,
-        b_new, keys, vals, live, backend=backend)
-    zero = lambda x: jax.lax.dynamic_update_slice_in_dim(
-        x, jnp.zeros(x.shape[:2] + (n,) + x.shape[3:], x.dtype), w, axis=2)
-    return (XorHashTable(pred.q_masks, zero(pred.store_keys),
-                         zero(pred.store_vals), zero(pred.store_valid), cfg),
-            XorHashTable(succ.q_masks, ssk, ssv, ssb, new_cfg))
+    packed = bulk_place_records(new_cfg, succ.packed, b_new, keys, vals,
+                                live, backend=backend)[0]
+    return (zero_slab(pred, w, n),
+            XorHashTable(succ.q_masks, packed, new_cfg))
+
+
+def slab_records(table: XorHashTable, w, n: int):
+    """The live plaintext records of local buckets ``[w, w + n)`` (replica
+    0 — replicas are identical), decoded from those buckets' columns of the
+    stored array: ``(keys [n*S, Wk], vals [n*S, Wv], live [n*S], row
+    [n*S])`` with ``row`` the record's bucket offset from ``w``."""
+    from repro.kernels import packed as pk
+    cfg = table.cfg
+    S, Wk, Wv = cfg.slots, cfg.key_words, cfg.val_words
+    W = Wk + Wv + 1
+    row = jnp.arange(n, dtype=jnp.uint32)
+    b = (w.astype(jnp.uint32) + row).astype(jnp.int32)
+    cols = pk.columns(table.packed,
+                      pk.stored_column(b, cfg.local_buckets))   # [n, C]
+    dec = xor_reduce(pk.planes(cols, cfg.k, S, W), axis=1)    # [n, S, W]
+    return (dec[..., :Wk].reshape(n * S, Wk),
+            dec[..., Wk:W - 1].reshape(n * S, Wv),
+            (dec[..., W - 1] & 1).reshape(n * S).astype(jnp.bool_),
+            jnp.repeat(row, S))
+
+
+def zero_slab(table: XorHashTable, w, n: int) -> XorHashTable:
+    """The table with local buckets ``[w, w + n)`` zeroed in every plane and
+    replica (an O(slab) scatter into the stored array)."""
+    from repro.kernels import packed as pk
+    b = w.astype(jnp.int32) + jnp.arange(n, dtype=jnp.int32)
+    C = table.packed.shape[-2]
+    rows = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (n, C))
+    packed = pk.set_words(table.packed,
+                          pk.stored_column(b, table.cfg.local_buckets), rows,
+                          jnp.zeros((n, C), jnp.uint32))
+    return XorHashTable(table.q_masks, packed, table.cfg)
 
 
 def migrate_slab(state: ResizeState, n_buckets: int,
@@ -2000,15 +2047,10 @@ def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
     capacity = (old.buckets != new_cfg.buckets or old.slots != new_cfg.slots)
     if not capacity:
         keys, vals, live, bucket = extract_records(table)
-        R, k = new_cfg.replicas, new_cfg.k
-        Bl, S = table.store_keys.shape[2], table.store_keys.shape[3]
-        sk, sv, sb, _, _, _, _, _ = bulk_place_records(
-            new_cfg,
-            jnp.zeros((R, k, Bl, S, old.key_words), jnp.uint32),
-            jnp.zeros((R, k, Bl, S, old.val_words), jnp.uint32),
-            jnp.zeros((R, k, Bl, S), jnp.uint32),
-            bucket, keys, vals, live, backend=backend)
-        return XorHashTable(table.q_masks, sk, sv, sb, new_cfg)
+        empty = jnp.zeros(packed_shape(new_cfg, table.buckets), jnp.uint32)
+        packed = bulk_place_records(new_cfg, empty, bucket, keys, vals, live,
+                                    backend=backend)[0]
+        return XorHashTable(table.q_masks, packed, new_cfg)
     if old.shards > 1:
         raise ValueError(
             f"capacity reconfigure (buckets {old.buckets}->{new_cfg.buckets}"
@@ -2027,18 +2069,14 @@ def reconfigure(table: XorHashTable, new_cfg: HashTableConfig,
         q_masks = table.q_masks
     keys, vals, live, _ = extract_records(table)
     bucket = _h3_jnp(keys, q_masks)
-    R, k = new_cfg.replicas, new_cfg.k
     B, S = new_cfg.buckets, new_cfg.slots
-    sk, sv, sb, _, spilled, _, _, _ = bulk_place_records(
-        new_cfg,
-        jnp.zeros((R, k, B, S, old.key_words), jnp.uint32),
-        jnp.zeros((R, k, B, S, old.val_words), jnp.uint32),
-        jnp.zeros((R, k, B, S), jnp.uint32),
-        bucket, keys, vals, live, backend=backend)
+    packed, _, spilled, _, _, _ = bulk_place_records(
+        new_cfg, jnp.zeros(packed_shape(new_cfg), jnp.uint32), bucket, keys,
+        vals, live, backend=backend)
     spill_ct = jnp.sum(spilled.astype(jnp.int32))
     if not isinstance(spill_ct, jax.core.Tracer) and int(spill_ct):
         raise ValueError(
             f"capacity reconfigure to (buckets={B}, slots={S}) would drop "
             f"{int(spill_ct)} live records to bucket overflow — grow slots "
             f"or buckets, or delete records first")
-    return XorHashTable(q_masks, sk, sv, sb, new_cfg)
+    return XorHashTable(q_masks, packed, new_cfg)

@@ -43,8 +43,8 @@ from repro.core.xor_memory import xor_reduce
 __all__ = [
     "OP_NOP", "OP_SEARCH", "OP_INSERT", "OP_DELETE",
     "XorHashTable", "QueryBatch", "StepResults",
-    "init_table", "apply_step", "run_stream", "bulk_build", "compact",
-    "reconfigure", "schedule_queries", "pack_trace",
+    "init_table", "packed_shape", "apply_step", "run_stream", "bulk_build",
+    "compact", "reconfigure", "schedule_queries", "pack_trace",
 ]
 
 # Operation codes (OP_INSERT covers the paper's fused Insert/Update).
@@ -59,34 +59,82 @@ OP_DELETE = 3
 class XorHashTable:
     """Functional state: XOR-encoded partial stores across replicas.
 
-    store_* shapes: ``[R, k, buckets, slots, words]`` (valid: ``[R,k,B,S]``).
+    ``packed`` is the table's one stored format: ``[R, G, C, 128]`` uint32,
+    each replica's ``k`` partial stores in the kernels' lane-dense layout
+    (``kernels/packed.py``; ``G = ceil(B / 128)`` bucket groups, ``C =
+    packed_rows(k, S, Wk + Wv + 1)`` plane/word/slot rows).  A sharded
+    table stores each ``cfg.local_buckets`` partition packed on its own.
     R == p for the paper-faithful layout, 1 for the compact TPU layout.
+    Code that wants the logical ``[R, k, B, S, W]`` view converts through
+    :meth:`stores` and :meth:`from_stores`.
     """
     q_masks: jnp.ndarray      # [index_bits, key_words] uint32 — H3 matrix
-    store_keys: jnp.ndarray   # [R, k, B, S, Wk] uint32 (XOR-encoded)
-    store_vals: jnp.ndarray   # [R, k, B, S, Wv] uint32 (XOR-encoded)
-    store_valid: jnp.ndarray  # [R, k, B, S]     uint32 (XOR-encoded, bit 0)
+    packed: jnp.ndarray       # [R, G, C, 128] uint32 (XOR-encoded)
     cfg: HashTableConfig      # static
 
     def tree_flatten(self):
-        return (self.q_masks, self.store_keys, self.store_vals,
-                self.store_valid), self.cfg
+        return (self.q_masks, self.packed), self.cfg
 
     @classmethod
     def tree_unflatten(cls, cfg, children):
         return cls(*children, cfg=cfg)
 
+    @property
+    def buckets(self) -> int:
+        """Buckets the stored array holds (one shard's partition when the
+        table is a shard_map-local view)."""
+        from repro.kernels import packed as pk
+        return pk.stored_buckets(self.packed, self.cfg.local_buckets)
+
+    def stores(self):
+        """The logical view ``(keys [R, k, B, S, Wk], vals [R, k, B, S, Wv],
+        valid [R, k, B, S])`` — an O(table) transpose (oracle/test edge)."""
+        from repro.kernels import packed as pk
+        cfg = self.cfg
+        return pk.unpack_stores(self.packed, cfg.k, cfg.local_buckets,
+                                cfg.slots, cfg.key_words, cfg.val_words)
+
+    @classmethod
+    def from_stores(cls, q_masks, keys, vals, valid,
+                    cfg: HashTableConfig) -> "XorHashTable":
+        """Inverse of :meth:`stores`."""
+        from repro.kernels import packed as pk
+        return cls(q_masks, pk.pack_stores(keys, vals, valid,
+                                           cfg.local_buckets), cfg)
+
     # Convenience plaintext views (debug/test only; not used in the hot path).
     def plaintext(self, replica: int = 0):
-        keys = xor_reduce(self.store_keys[replica], axis=0)
-        vals = xor_reduce(self.store_vals[replica], axis=0)
-        valid = xor_reduce(self.store_valid[replica], axis=0) & 1
-        return keys, vals, valid
+        """``(keys [B, S, Wk], vals [B, S, Wv], valid [B, S])``: the XOR of
+        the k planes, taken on the stored array, then every bucket's column
+        of the one decoded plane."""
+        from repro.kernels import packed as pk
+        cfg = self.cfg
+        S, Wk, W = cfg.slots, cfg.key_words, cfg.entry_words
+        tab = self.packed[replica]
+        one = xor_reduce(tab[:, :cfg.k * S * W].reshape(
+            tab.shape[0], cfg.k, S * W, tab.shape[2]), axis=1)
+        b = jnp.arange(self.buckets, dtype=jnp.int32)
+        cols = pk.columns(one[None], pk.stored_column(b, cfg.local_buckets))
+        dec = pk.planes(cols, 1, S, W)[:, 0]
+        return dec[..., :Wk], dec[..., Wk:W - 1], dec[..., W - 1] & 1
 
     @property
     def memory_bytes(self) -> int:
-        return (self.store_keys.size + self.store_vals.size
-                + self.store_valid.size) * 4
+        """Bytes of the logical stores (the paper's accounting,
+        ``config.memory_bytes``), not of the lane padding."""
+        cfg = self.cfg
+        return (4 * self.packed.shape[0] * cfg.k * self.buckets * cfg.slots
+                * cfg.entry_words)
+
+
+def packed_shape(cfg: HashTableConfig, buckets: int | None = None):
+    """Shape of the stored array of ``buckets`` (default ``cfg.buckets``)
+    buckets, in partitions of ``cfg.local_buckets``."""
+    from repro.kernels import packed as pk
+    bl = cfg.local_buckets
+    buckets = cfg.buckets if buckets is None else buckets
+    return (cfg.replicas, buckets // bl * pk.packed_groups(bl),
+            pk.packed_rows(cfg.k, cfg.slots, cfg.entry_words), pk.LANES)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -127,12 +175,9 @@ class StepResults:
 # ---------------------------------------------------------------------------
 
 def init_table(cfg: HashTableConfig, rng: jax.Array) -> XorHashTable:
-    R, k, B, S = cfg.replicas, cfg.k, cfg.buckets, cfg.slots
     return XorHashTable(
         q_masks=make_h3_params(rng, cfg.key_words, cfg.index_bits),
-        store_keys=jnp.zeros((R, k, B, S, cfg.key_words), jnp.uint32),
-        store_vals=jnp.zeros((R, k, B, S, cfg.val_words), jnp.uint32),
-        store_valid=jnp.zeros((R, k, B, S), jnp.uint32),
+        packed=jnp.zeros(packed_shape(cfg), jnp.uint32),
         cfg=cfg,
     )
 

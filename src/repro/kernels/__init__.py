@@ -8,8 +8,8 @@ index space) and bulk_place (bulk-build placement).  All of them see the
 lane-dense packed table of ``kernels.packed``.  Use repro.kernels.ops for
 the jit'd, fallback-guarded entry points; the jnp oracles live in
 repro.core.engine."""
-from repro.kernels.ops import (h3_hash, replica_bytes, stream_bucket_tiles,
-                               xor_commit, xor_probe, xor_stream)
+from repro.kernels.ops import (h3_hash, stream_bucket_tiles, xor_commit,
+                               xor_probe, xor_stream)
 
 __all__ = ["h3_hash", "xor_probe", "xor_commit", "xor_stream",
-           "replica_bytes", "stream_bucket_tiles"]
+           "stream_bucket_tiles"]

@@ -7,8 +7,8 @@ assigned every surviving representative record a pairwise-distinct
 placement: no probe, no XOR encode (the target stores are empty, so the
 encode basis is zero and plaintext IS the encoding), no supersession mask.
 
-Layout: the plane is the packed ``[G, C, 128]`` array of
-``kernels/packed.py`` (one plane), left in HBM.  An XLA-side pre-pass sorts
+Layout: the kernel writes the port-0 plane rows of one replica's stored
+array (``[G, C, 128]``, ``kernels/packed.py``), left in HBM.  An XLA-side pre-pass sorts
 the records by bucket (masked records, ``bucket == B``, sort last) and
 splits them into chunks of ``chunk`` records; ``grid = (chunks,)`` streams
 each chunk's targets and payload into SMEM.  The kernel walks the records
@@ -75,22 +75,25 @@ def _bulk_place_kernel(bkt_ref, slot_ref, pay_ref, tab_hbm, out_hbm, blk,
         move(blk, out_hbm.at[held[0]])
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("buckets", "slots", "chunk",
+                                             "interpret"))
 def bulk_place_pallas(w_bucket: jnp.ndarray, w_slot: jnp.ndarray,
                       keys: jnp.ndarray, vals: jnp.ndarray,
-                      plane_keys: jnp.ndarray, plane_vals: jnp.ndarray,
-                      plane_valid: jnp.ndarray, chunk: int = DEFAULT_CHUNK,
-                      interpret=None):
+                      table: jnp.ndarray, *, buckets: int, slots: int,
+                      chunk: int = DEFAULT_CHUNK, interpret=None):
     """Place ``n`` pre-planned records into the port-0 plane.
 
-    ``w_bucket``/``w_slot`` ``[n]`` int32 (``bucket == B`` marks a masked
-    record); ``keys [n, Wk]`` / ``vals [n, Wv]`` uint32 plaintext;
-    ``plane_* [B, S, W*]`` (valid ``[B, S]``) ONE port's slice of one
-    replica.  ``chunk`` records stream through SMEM per grid step.  Returns
-    the updated planes.
+    ``w_bucket``/``w_slot`` ``[n]`` int32 (``bucket == buckets`` marks a
+    masked record); ``keys [n, Wk]`` / ``vals [n, Wv]`` uint32 plaintext;
+    ``table`` one replica's stored array ``[G, C, 128]`` of ``slots``
+    slots a bucket.  A bucket here is a column of the array (group ``b //
+    128``, lane ``b % 128``: ``packed.stored_column`` maps a partitioned
+    table's buckets onto them), and ``buckets <= G * 128``.  ``chunk``
+    records stream through SMEM per grid step.  Returns the updated table
+    (in place).
     """
-    B, S, Wk = plane_keys.shape
-    Wv = plane_vals.shape[-1]
+    B, S = buckets, slots
+    Wk, Wv = keys.shape[-1], vals.shape[-1]
     n = w_bucket.shape[0]
     nch = max(-(-n // chunk), 1)
     pad = nch * chunk - n
@@ -103,26 +106,23 @@ def bulk_place_pallas(w_bucket: jnp.ndarray, w_slot: jnp.ndarray,
     pay = jnp.concatenate([keys, vals], axis=-1).astype(jnp.uint32)[order]
     pay = pk.i32(jnp.pad(pay, ((0, pad), (0, 0)))).reshape(-1)
 
-    tab = pk.pack(plane_keys[None], plane_vals[None], plane_valid[None])
-    C = tab.shape[1]
+    C = table.shape[1]
     smem = lambda size: pl.BlockSpec((size,), lambda c: (c,),
                                      memory_space=pltpu.SMEM)
-    tab = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bulk_place_kernel, buckets=B, slots=S,
                           payload_words=Wk + Wv, chunk=chunk),
         grid=(nch,),
         in_specs=[smem(chunk), smem(chunk), smem(chunk * (Wk + Wv)),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(tab.shape, tab.dtype),
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         scratch_shapes=[pltpu.VMEM((C, pk.LANES), jnp.uint32),
                         pltpu.SMEM((1,), jnp.int32),
                         pltpu.SemaphoreType.DMA(())],
-        # the plane updates in place: untouched groups never move
+        # the table updates in place: untouched groups never move
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pk.interpret_mode(interpret),
-    )(bkt, slot, pay, tab)
-    sk, sv, sb = pk.unpack(tab, 1, B, S, Wk, Wv)
-    return sk[0], sv[0], sb[0]
+    )(bkt, slot, pay, table)

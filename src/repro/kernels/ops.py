@@ -32,39 +32,26 @@ VMEM_LIMIT_BYTES = 120 * 1024 * 1024
 VMEM_TABLE_BUDGET_BYTES = 96 * 1024 * 1024
 
 
-def replica_bytes(store_keys, store_vals, store_valid) -> int:
-    """Bytes of ONE replica of the XOR store arrays (4 bytes per uint32 word).
-
-    Accepts either the replicated 5D layout ``[R, k, B, S, W]`` or a single
-    4D replica ``[k, B, S, W]``.
-    """
-    total = 4 * (store_keys.size + store_vals.size + store_valid.size)
-    reps = store_keys.shape[0] if store_keys.ndim == 5 else 1
-    return total // reps
+def table_vmem_bytes(table) -> int:
+    """On-chip bytes of ONE replica of a stored table (``[G, C, 128]``, or
+    ``[R, G, C, 128]``; ``kernels/packed.py``) — the single source of truth
+    for every VMEM-budget check (engine backend resolution, the
+    probe/commit fallbacks, stream sweep passes)."""
+    G, C, lanes = table.shape[-3:]
+    return 4 * G * C * lanes
 
 
-def table_vmem_bytes(store_keys, store_vals, store_valid) -> int:
-    """On-chip bytes of ONE replica in the kernels' packed layout
-    (``kernels/packed.py``) — the single source of truth for every
-    VMEM-budget check (engine backend resolution, the probe/commit
-    fallbacks, stream sweep passes).  Same array forms as
-    :func:`replica_bytes`."""
-    k, B, S, Wk = store_keys.shape[-4:]
-    return pk.packed_bytes(k, B, S, Wk + store_vals.shape[-1] + 1)
+def fits_vmem(table) -> bool:
+    return table_vmem_bytes(table) <= VMEM_TABLE_BUDGET_BYTES
 
 
-def fits_vmem(store_keys, store_vals, store_valid) -> bool:
-    return table_vmem_bytes(store_keys, store_vals,
-                            store_valid) <= VMEM_TABLE_BUDGET_BYTES
-
-
-def stream_bucket_tiles(store_keys, store_vals, store_valid) -> int:
+def stream_bucket_tiles(table) -> int:
     """Sweep passes of the fused stream kernel: the smallest power-of-two
     pass count whose span fits ``VMEM_TABLE_BUDGET_BYTES`` (1 == the whole
-    replica is VMEM-resident), capped at the packed table's bucket-group
+    replica is VMEM-resident), capped at the stored table's bucket-group
     count — one 128-bucket group is the smallest span the kernel moves."""
-    rb = table_vmem_bytes(store_keys, store_vals, store_valid)
-    groups = pk.packed_groups(store_keys.shape[-3])
+    rb = table_vmem_bytes(table)
+    groups = table.shape[-3]
     tiles = 1
     while rb // tiles > VMEM_TABLE_BUDGET_BYTES and tiles < groups:
         tiles *= 2
@@ -87,74 +74,76 @@ def h3_hash(keys: jnp.ndarray, q_masks: jnp.ndarray, use_pallas: bool = True,
     return h3_hash_pallas(keys_t, q_masks, block_n=bn)[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "stagger"))
+@functools.partial(jax.jit, static_argnames=("k", "buckets", "slots",
+                                             "val_words", "use_pallas",
+                                             "stagger"))
 def xor_probe(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
-              store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-              store_valid: jnp.ndarray, use_pallas: bool = True,
-              stagger: bool = False):
-    """Fused decode+probe of one replica.  See xor_probe_pallas docstring."""
-    if not use_pallas or not fits_vmem(store_keys, store_vals, store_valid):
+              table: jnp.ndarray, *, k: int, buckets: int, slots: int,
+              val_words: int, use_pallas: bool = True, stagger: bool = False):
+    """Fused decode+probe of one replica's stored array ``[G, C, 128]``.
+    See xor_probe_pallas; past the VMEM budget the engine's jnp probe
+    gathers the lanes' columns of the same array."""
+    if not use_pallas or not fits_vmem(table):
         from repro.core.engine import probe_jnp
-        return probe_jnp(bucket, port, qkeys, store_keys[None],
-                         store_vals[None], store_valid[None], stagger=stagger)
-    return xor_probe_pallas(bucket, port, qkeys, store_keys, store_vals,
-                            store_valid, stagger=stagger,
+        return probe_jnp(bucket, port, qkeys, table[None], k=k, slots=slots,
+                         val_words=val_words, part_buckets=buckets,
+                         stagger=stagger)
+    return xor_probe_pallas(bucket, port, qkeys, table, k=k, slots=slots,
+                            val_words=val_words, stagger=stagger,
                             vmem_limit=VMEM_LIMIT_BYTES)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def xor_commit(store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-               store_valid: jnp.ndarray, port: jnp.ndarray,
-               bucket: jnp.ndarray, slot: jnp.ndarray, enc_k: jnp.ndarray,
-               enc_v: jnp.ndarray, enc_b: jnp.ndarray,
+@functools.partial(jax.jit, static_argnames=("k", "buckets", "slots",
+                                             "use_pallas"))
+def xor_commit(table: jnp.ndarray, port: jnp.ndarray, bucket: jnp.ndarray,
+               slot: jnp.ndarray, enc_k: jnp.ndarray, enc_v: jnp.ndarray,
+               enc_b: jnp.ndarray, *, k: int, buckets: int, slots: int,
                use_pallas: bool = True):
-    """Masked commit of pre-encoded mutation records into every replica.
-
-    store_* carry the replica axis ``[R, k, B, S, W*]``; enc_* come from
-    ``engine.encode_records`` (one encode serves all replicas — see
-    xor_commit_pallas).  ``bucket >= B`` marks a masked lane.  Falls back to
-    the engine's jnp record scatter when the replica exceeds the VMEM budget.
-    """
-    if not use_pallas or not fits_vmem(store_keys, store_vals, store_valid):
+    """Masked commit of pre-encoded mutation records into one replica's
+    stored array ``[G, C, 128]`` (the engine broadcasts it to every
+    replica).  enc_* come from ``engine.encode_records``; ``bucket >=
+    buckets`` marks a masked lane.  Past the VMEM budget the engine's jnp
+    record scatter writes the records' rows of the same array."""
+    if not use_pallas or not fits_vmem(table):
         from repro.core.engine import _scatter_records
         rec = dict(port=port, bucket=bucket, slot=slot,
                    enc_k=enc_k, enc_v=enc_v, enc_b=enc_b)
-        return _scatter_records(store_keys, store_vals, store_valid, rec)
-    return xor_commit_pallas(store_keys, store_vals, store_valid, port, bucket,
-                             slot, enc_k, enc_v, enc_b,
+        return _scatter_records(table, rec, buckets=buckets, slots=slots,
+                                part_buckets=buckets)
+    return xor_commit_pallas(table, port, bucket, slot, enc_k, enc_v, enc_b,
+                             buckets=buckets, slots=slots,
                              vmem_limit=VMEM_LIMIT_BYTES)
 
 
 def xor_stream(bucket: jnp.ndarray, port: jnp.ndarray, legal: jnp.ndarray,
                ops: jnp.ndarray, qkeys: jnp.ndarray, qvals: jnp.ndarray,
-               store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-               store_valid: jnp.ndarray, stagger: bool = False,
-               bucket_base=0):
-    """Fused in-kernel query streaming over one replica: probe + plan +
-    non-search XOR encode + last-wins commit for a whole ``[T, N]`` stream
-    in a single Pallas kernel, table VMEM-resident across steps (swept in
-    :func:`stream_bucket_tiles` spans when one replica exceeds the VMEM
-    budget; the budget is read at trace time).  ``port``/``legal`` may be ``[N]``
-    lane vectors or ``[T, N]`` per-step rows (the bounded router re-bins
-    routed lanes, so a slot's origin varies by step —
-    engine.route_stream_bounded).  ``bucket_base`` (traced scalar) offsets
-    a shard-local partition into the global bucket space; lanes outside the
+               table: jnp.ndarray, *, k: int, buckets: int, slots: int,
+               stagger: bool = False, bucket_base=0):
+    """Fused in-kernel query streaming over one replica's stored array
+    ``[G, C, 128]``: probe + plan + non-search XOR encode + last-wins
+    commit for a whole ``[T, N]`` stream in a single Pallas kernel, table
+    VMEM-resident across steps (swept in :func:`stream_bucket_tiles` spans
+    when one replica exceeds the VMEM budget; the budget is read at trace
+    time) and updated in place.  ``port``/``legal`` may be ``[N]`` lane
+    vectors or ``[T, N]`` per-step rows (the bounded router re-bins routed
+    lanes, so a slot's origin varies by step — engine.route_stream_bounded).
+    ``bucket_base`` (traced scalar) offsets a shard-local partition of
+    ``buckets`` buckets into the global bucket space; lanes outside the
     partition are inert (DESIGN.md §3.1).  See xor_stream_pallas; the
     scanned per-step engine path is the semantic oracle.
     """
-    passes = stream_bucket_tiles(store_keys, store_vals, store_valid)
-    return xor_stream_pallas(bucket, port, legal, ops, qkeys, qvals,
-                             store_keys, store_vals, store_valid,
-                             passes=passes, stagger=stagger,
-                             bucket_base=bucket_base,
+    return xor_stream_pallas(bucket, port, legal, ops, qkeys, qvals, table,
+                             k=k, buckets=buckets, slots=slots,
+                             passes=stream_bucket_tiles(table),
+                             stagger=stagger, bucket_base=bucket_base,
                              vmem_limit=VMEM_LIMIT_BYTES)
 
 
 def bulk_place(w_bucket: jnp.ndarray, w_slot: jnp.ndarray, keys: jnp.ndarray,
-               vals: jnp.ndarray, plane_keys: jnp.ndarray,
-               plane_vals: jnp.ndarray, plane_valid: jnp.ndarray):
-    """Bulk placement of pre-planned records into the port-0 plane (the
-    commit half of ``engine.bulk_build`` — see bulk_place_pallas).
-    Engine's jnp backend scatter is the oracle."""
-    return bulk_place_pallas(w_bucket, w_slot, keys, vals, plane_keys,
-                             plane_vals, plane_valid)
+               vals: jnp.ndarray, table: jnp.ndarray, *, buckets: int,
+               slots: int):
+    """Bulk placement of pre-planned records into the port-0 plane of one
+    replica's stored array (the commit half of ``engine.bulk_build`` — see
+    bulk_place_pallas).  Engine's jnp backend scatter is the oracle."""
+    return bulk_place_pallas(w_bucket, w_slot, keys, vals, table,
+                             buckets=buckets, slots=slots)

@@ -1,15 +1,27 @@
-"""Lane-dense on-chip layout of the XOR stores, shared by the Pallas kernels.
+"""Lane-dense layout of the XOR stores: the table's one stored format.
 
-The store arrays keep their logical ``[k, B, S, W]`` shape in HBM.  Held as
-kernel blocks that way, their minor dims ``(S, W)`` would be padded to the
-TPU's (8, 128) tiles — 64x for ``W == 2``.  The kernels therefore see one
-packed array ``[G, C, 128]``:
+``XorHashTable.packed`` holds each replica's ``k`` partial stores as one
+array ``[G, C, 128]``, the form every Pallas kernel works on.  Held in the
+logical ``[k, B, S, W]`` shape, kernel blocks would pad the minor dims
+``(S, W)`` to the TPU's (8, 128) tiles — 64x for ``W == 2``.  Instead:
 
   * bucket ``b`` is lane ``b % 128`` of bucket group ``g = b // 128``
     (``G = ceil(B / 128)``; lanes past ``B`` are padding, never addressed);
   * its ``C`` rows are ``row = plane * S * Wtot + word * S + slot`` with the
     words ordered key | value | valid (``Wtot = Wk + Wv + 1``), rounded up
     to a multiple of 8 sublanes.
+
+A bucket-sharded table is stored per partition: each run of
+``local_buckets`` buckets is packed on its own and the partitions follow
+one another on the group axis, so one device's shard of the array is its
+partition's packed table.  :func:`stored_column` maps a bucket to its
+column (group and lane) in such an array, and :func:`columns` /
+:func:`set_words` gather and scatter bucket columns: the jnp oracle, bulk
+placement, resize migration and ``plaintext`` address the stored array
+through them, touching only the buckets they read or write.
+:func:`pack_stores` / :func:`unpack_stores` convert the whole array to and
+from the logical ``[R, k, B, S, W]`` view, for tests and callers that want
+it; no engine path converts.
 
 A kernel reads one bucket as the lane column of its group block (a dynamic
 index on the leading dim — the only dynamic vector index Mosaic lowers) and
@@ -47,11 +59,6 @@ def packed_groups(buckets: int) -> int:
     return -(-buckets // LANES)
 
 
-def packed_bytes(k: int, buckets: int, slots: int, words: int) -> int:
-    """On-chip bytes of a packed table of ``k`` planes."""
-    return 4 * packed_groups(buckets) * packed_rows(k, slots, words) * LANES
-
-
 def pack(keys, vals, valid):
     """``[k, B, S, Wk]``, ``[k, B, S, Wv]``, ``[k, B, S]`` -> ``[G, C, 128]``."""
     k, B, S, _ = keys.shape
@@ -73,6 +80,84 @@ def unpack(packed, k: int, buckets: int, slots: int, key_words: int,
     words = jnp.transpose(words, (0, 3, 2, 1))
     return (words[..., :key_words], words[..., key_words:W - 1],
             words[..., W - 1])
+
+
+def stored_column(b, part_buckets: int):
+    """Column ``group * 128 + lane`` of global bucket ``b`` (int32) in a
+    stored array whose partitions hold ``part_buckets`` buckets each: the
+    bucket itself when partitions fill whole groups, else shifted past the
+    padding lanes of the partitions before it."""
+    gap = packed_groups(part_buckets) * LANES - part_buckets
+    return b if gap == 0 else b + b // part_buckets * gap
+
+
+def entry_rows(plane, slot, slots: int, words: int):
+    """``[n, words]`` rows of the words of slot ``slot [n]`` of plane
+    ``plane`` (scalar or ``[n]``): ``plane * S * W + word * S + slot``."""
+    base = plane * (slots * words) + slot
+    return base[:, None] + slots * jnp.arange(words, dtype=jnp.int32)
+
+
+def columns(tab, col, replica=None):
+    """``[n, C]``: the columns ``col [n]`` of a stored array ``[R, G, C,
+    128]``, each read from replica ``replica [n]`` (default 0)."""
+    r = 0 if replica is None else replica
+    return tab[r, col // LANES, :, col % LANES]
+
+
+def planes(cols, k: int, slots: int, words: int):
+    """Gathered columns ``[n, C]`` -> ``[n, k, S, W]`` plane/slot/word
+    view."""
+    n = cols.shape[0]
+    return cols[:, :k * slots * words].reshape(n, k, words, slots
+                                                ).transpose(0, 1, 3, 2)
+
+
+def set_words(tab, col, rows, words):
+    """``tab [..., G, C, 128]`` with ``words [n, W]`` stored at ``rows [n,
+    W]`` of the columns ``col [n]``, in every leading index (every
+    replica).  A column past the array (``>= G * 128``) is dropped."""
+    return tab.at[..., (col // LANES)[:, None], rows,
+                  (col % LANES)[:, None]].set(words, mode="drop")
+
+
+def stored_buckets(tab, part_buckets: int) -> int:
+    """Buckets a stored array ``[..., G, C, 128]`` holds when each of its
+    partitions holds ``part_buckets``."""
+    return tab.shape[-3] // packed_groups(part_buckets) * part_buckets
+
+
+def pack_stores(keys, vals, valid, part_buckets: int):
+    """``[R, k, B, S, W*]`` stores -> the stored ``[R, G, C, 128]`` array,
+    each run of ``part_buckets`` buckets packed as its own partition."""
+    R, k, B = keys.shape[:3]
+    P = B // part_buckets
+
+    def split(x):                   # [R, k, B, ...] -> [R * P, k, Bp, ...]
+        x = x.reshape((R, k, P, part_buckets) + x.shape[3:])
+        return jnp.moveaxis(x, 2, 1).reshape((R * P, k, part_buckets)
+                                             + x.shape[4:])
+
+    tab = jax.vmap(pack)(split(keys), split(vals), split(valid))
+    return tab.reshape((R, P * tab.shape[1]) + tab.shape[2:])
+
+
+def unpack_stores(tab, k: int, part_buckets: int, slots: int,
+                  key_words: int, val_words: int):
+    """Inverse of :func:`pack_stores`: ``(keys, vals, valid)`` with the
+    replica axis, ``[R, k, B, S, W*]``."""
+    R, G, C, _ = tab.shape
+    Gp = packed_groups(part_buckets)
+    P = G // Gp
+    parts = jax.vmap(lambda t: unpack(t, k, part_buckets, slots, key_words,
+                                      val_words))(
+        tab.reshape(R * P, Gp, C, LANES))
+
+    def merge(x):                   # [R * P, k, Bp, ...] -> [R, k, B, ...]
+        x = jnp.moveaxis(x.reshape((R, P) + x.shape[1:]), 1, 2)
+        return x.reshape((R, k, P * part_buckets) + x.shape[4:])
+
+    return tuple(merge(x) for x in parts)
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,8 @@ store of EVERY replica (inter-PE propagation).
 Replicas are byte-identical at step boundaries, so the encoding is the same
 for every replica — the engine computes it ONCE from the ``ProbeResult`` rem
 basis the probe stage already produced (``engine.encode_records``), and this
-kernel only stores it: into replica 0's packed table (``kernels/packed.py``),
-which the wrapper then broadcasts to all replicas.
+kernel only stores it: into replica 0's stored array (``kernels/packed.py``),
+which the engine then broadcasts to all replicas.
 
 Timing matches the FPGA (and the jnp oracle) exactly: encodings come from the
 pre-step snapshot (via the probe), then all write ports commit.  The commit
@@ -55,34 +55,32 @@ def _xor_commit_kernel(port_ref, bkt_ref, slot_ref, enc_ref, tab_hbm, out_hbm,
     store.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "vmem_limit"))
-def xor_commit_pallas(store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-                      store_valid: jnp.ndarray, port: jnp.ndarray,
+@functools.partial(jax.jit, static_argnames=("buckets", "slots",
+                                             "interpret", "vmem_limit"))
+def xor_commit_pallas(table: jnp.ndarray, port: jnp.ndarray,
                       bucket: jnp.ndarray, slot: jnp.ndarray,
                       enc_k: jnp.ndarray, enc_v: jnp.ndarray,
-                      enc_b: jnp.ndarray, interpret=None,
-                      vmem_limit: int | None = None):
-    """Masked store of encoded records into all replicas.
+                      enc_b: jnp.ndarray, *, buckets: int, slots: int,
+                      interpret=None, vmem_limit: int | None = None):
+    """Masked store of encoded records into one replica's stored array.
 
-    store_* ``[R, k, B, S, W*]``; port/bucket/slot ``[N]`` (``bucket >= B``
-    marks a masked lane — dropped); enc_* the XOR-encoded rows
-    ``[N, Wk] / [N, Wv] / [N]`` from ``engine.encode_records``.  Returns the
-    updated (store_keys, store_vals, store_valid).
+    ``table`` ``[G, C, 128]`` holding ``buckets`` buckets of ``slots``
+    slots; port/bucket/slot ``[N]`` (``bucket >= buckets`` marks a masked
+    lane — dropped); enc_* the XOR-encoded rows ``[N, Wk] / [N, Wv] /
+    [N]`` from ``engine.encode_records``.  Returns the updated table
+    (in place).
     """
-    R, k, B, S, Wk = store_keys.shape
-    Wv = store_vals.shape[-1]
     N = port.shape[0]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    tab = pk.pack(store_keys[0], store_vals[0], store_valid[0])
     enc = jnp.concatenate([enc_k, enc_v, enc_b[:, None]], axis=-1).T
-    tab = pl.pallas_call(
-        functools.partial(_xor_commit_kernel, buckets=B, slots=S,
-                          words=Wk + Wv + 1, n=N),
+    return pl.pallas_call(
+        functools.partial(_xor_commit_kernel, buckets=buckets, slots=slots,
+                          words=enc.shape[0], n=N),
         in_specs=[smem, smem, smem, pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(tab.shape, tab.dtype),
-        scratch_shapes=[pltpu.VMEM(tab.shape, jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        scratch_shapes=[pltpu.VMEM(table.shape, jnp.uint32),
                         pltpu.SemaphoreType.DMA(())],
         # stores update in place — without aliasing every step would
         # round-trip the full table through a fresh output buffer
@@ -90,7 +88,4 @@ def xor_commit_pallas(store_keys: jnp.ndarray, store_vals: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=pk.interpret_mode(interpret),
     )(port.astype(jnp.int32), bucket.astype(jnp.int32),
-      slot.astype(jnp.int32), enc.astype(jnp.uint32), tab)
-    sk, sv, sb = pk.unpack(tab, k, B, S, Wk, Wv)
-    bc = lambda x: jnp.broadcast_to(x[None], (R,) + x.shape)
-    return bc(sk), bc(sv), bc(sb)
+      slot.astype(jnp.int32), enc.astype(jnp.uint32), table)

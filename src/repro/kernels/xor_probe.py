@@ -16,7 +16,7 @@ Per query:
                                            querying port — the encode basis)
 
 Bucket indices arrive as a scalar-prefetch operand; each query reads its
-bucket's column from the packed group block (``packed.gather``).  The
+bucket's column from the group block of the stored array (``packed.gather``).  The
 kernel lowers under Mosaic for TPU and runs in Pallas interpret mode off
 the chip.  Tables larger than the VMEM budget take the jnp fallback in
 ops.py (HBM gathers, same semantics).
@@ -47,22 +47,23 @@ def _xor_probe_kernel(bkt_ref, port_ref, qkey_ref, tab_ref, flags_ref,
     rem_ref[...] = rem
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "stagger", "vmem_limit"))
+@functools.partial(jax.jit, static_argnames=("k", "slots", "val_words",
+                                             "interpret", "stagger",
+                                             "vmem_limit"))
 def xor_probe_pallas(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
-                     store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-                     store_valid: jnp.ndarray, interpret=None,
-                     stagger: bool = False, vmem_limit: int | None = None):
+                     table: jnp.ndarray, *, k: int, slots: int,
+                     val_words: int, interpret=None, stagger: bool = False,
+                     vmem_limit: int | None = None):
     """Probe one replica for a batch of queries.
 
-    bucket [N] uint32, port [N] int32, qkeys [N, Wk] uint32,
-    store_* [k, B, S, W*].  Returns (found[N] bool, match_slot[N] i32,
+    bucket [N] uint32, port [N] int32, qkeys [N, Wk] uint32, ``table`` one
+    replica's stored array ``[G, C, 128]`` (``k`` planes, ``slots`` slots,
+    ``val_words``-word values).  Returns (found[N] bool, match_slot[N] i32,
     open_slot[N] i32, has_open[N] bool, value[N, Wv], rem_keys[N, S, Wk],
     rem_vals[N, S, Wv], rem_valid[N, S]).
     """
     N = bucket.shape[0]
-    k, B, S, Wk = store_keys.shape
-    Wv = store_vals.shape[-1]
+    S, Wk, Wv = slots, qkeys.shape[-1], val_words
     W = Wk + Wv + 1
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     flags, value, rem = pl.pallas_call(
@@ -76,8 +77,7 @@ def xor_probe_pallas(bucket: jnp.ndarray, port: jnp.ndarray, qkeys: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=pk.interpret_mode(interpret),
     )(bucket.astype(jnp.int32), port.astype(jnp.int32)[None],
-      qkeys.astype(jnp.uint32).T,
-      pk.pack(store_keys, store_vals, store_valid))
+      qkeys.astype(jnp.uint32).T, table)
     rem = rem.reshape(W, S, N).transpose(2, 1, 0)          # [N, S, W]
     return (flags[0] != 0, flags[1], flags[2], flags[3] != 0, value.T,
             rem[..., :Wk], rem[..., Wk:Wk + Wv], rem[..., W - 1])

@@ -12,10 +12,11 @@ paper's PE pipeline proper: one ``pallas_call`` processes the whole
   encode   non-search XOR tree against the *pre-step* table state
   commit   per-lane stores of the encodings, in lane order
 
-Table layout: the kernel sees the lane-dense packed table of
-``kernels/packed.py`` (``[G, C, 128]``: bucket groups on the leading axis,
-plane/word/slot rows on sublanes, buckets on lanes).  The wrapper packs the
-``[k, B, S, W]`` stores before the call and unpacks after it.
+Table layout: the kernel takes one replica of the table's stored array
+as it is, the lane-dense layout of ``kernels/packed.py`` (``[G, C, 128]``:
+bucket groups on the leading axis, plane/word/slot rows on sublanes,
+buckets on lanes), and returns it updated in place (the output aliases
+the input).  No layout conversion happens around the call.
 
 Last-wins commit.  Same-step duplicate ``(port, bucket, slot)`` write
 targets must resolve last-in-program-order, matching the jnp oracle's
@@ -157,39 +158,39 @@ def _xor_stream_kernel(offs_ref, bkt_ref, q_ref, tab_hbm, out_hbm, res_ref,
     store.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "stagger",
-                                             "passes", "vmem_limit"))
+@functools.partial(jax.jit, static_argnames=("k", "buckets", "slots",
+                                             "passes", "interpret", "stagger",
+                                             "vmem_limit"))
 def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
                       legal: jnp.ndarray, ops: jnp.ndarray,
                       qkeys: jnp.ndarray, qvals: jnp.ndarray,
-                      store_keys: jnp.ndarray, store_vals: jnp.ndarray,
-                      store_valid: jnp.ndarray, passes: int = 1,
-                      interpret=None, stagger: bool = False,
-                      bucket_base=0, vmem_limit: int | None = None):
+                      table: jnp.ndarray, *, k: int, buckets: int,
+                      slots: int, passes: int = 1, interpret=None,
+                      stagger: bool = False, bucket_base=0,
+                      vmem_limit: int | None = None):
     """Stream T steps of N queries through one fused kernel.
 
     bucket/ops ``[T, N]``; port/legal ``[N]`` (step-invariant lanes) or
     ``[T, N]`` (per-step lanes — the bounded router re-bins lanes so a
     routed slot's origin varies by step); qkeys ``[T, N, Wk]``;
-    qvals ``[T, N, Wv]``; store_* one replica ``[k, B, S, W*]``.  Returns
-    ``(store_keys', store_vals', store_valid', found[T, N] bool,
-    ok[T, N] bool, value[T, N, Wv])``.  ``bucket_base`` (traced scalar)
+    qvals ``[T, N, Wv]``; ``table`` one replica's stored array ``[G, C,
+    128]`` holding ``buckets`` buckets of ``k`` planes and ``slots`` slots.
+    Returns ``(table', found[T, N] bool, ok[T, N] bool, value[T, N, Wv])``
+    with ``table'`` updated in place.  ``bucket_base`` (traced scalar)
     marks the global bucket range this table partition owns; lanes outside
-    ``[base, base + B)`` are inert.  ``passes`` — a divisor of the packed
-    table's bucket-group count, sized from the VMEM budget by
-    ``kernels.ops.stream_bucket_tiles`` — is the number of sweep passes
-    (module docstring).
+    ``[base, base + buckets)`` are inert.  ``passes`` — a divisor of ``G``,
+    sized from the VMEM budget by ``kernels.ops.stream_bucket_tiles`` — is
+    the number of sweep passes (module docstring).
     """
     T, N = ops.shape
-    k, B, S, Wk = store_keys.shape
-    Wv = store_vals.shape[-1]
-    G = pk.packed_groups(B)
+    S, B = slots, buckets
+    Wk, Wv = qkeys.shape[-1], qvals.shape[-1]
+    G, C, _ = table.shape
     if passes < 1 or G % passes:
         raise ValueError(f"passes={passes} must divide the {G} bucket groups")
     if T == 0:
-        return (store_keys, store_vals, store_valid,
-                jnp.zeros((0, N), jnp.bool_), jnp.zeros((0, N), jnp.bool_),
-                jnp.zeros((0, N, Wv), jnp.uint32))
+        return (table, jnp.zeros((0, N), jnp.bool_),
+                jnp.zeros((0, N), jnp.bool_), jnp.zeros((0, N, Wv), jnp.uint32))
     P = passes
     Gs = G // P
     base = jnp.asarray(bucket_base).astype(jnp.int32)
@@ -219,9 +220,6 @@ def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
                                   axis=1).transpose(0, 2, 1)
         bkt_s = jnp.take_along_axis(jnp.where(in_part, rel, 0), perm, axis=1)
 
-    with jax.named_scope("kv.table_pack"):
-        tab = pk.pack(store_keys, store_vals, store_valid)
-    C = tab.shape[1]
     Wq = 1 + Wk + Wv
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(P,),
@@ -241,7 +239,7 @@ def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
                           val_words=Wv, span_groups=Gs, steps=T, n=N,
                           stagger=stagger),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(tab.shape, tab.dtype),
+        out_shape=(jax.ShapeDtypeStruct(table.shape, table.dtype),
                    jax.ShapeDtypeStruct((T, 1 + Wv, N), jnp.uint32)),
         # the table updates in place — a fresh HBM buffer would double the
         # stream's only full-table traffic
@@ -250,14 +248,12 @@ def xor_stream_pallas(bucket: jnp.ndarray, port: jnp.ndarray,
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_limit),
         interpret=pk.interpret_mode(interpret),
         name="xor_stream",
-    )(offs, bkt_s, q_s, tab)
+    )(offs, bkt_s, q_s, table)
 
-    with jax.named_scope("kv.table_unpack"):
-        sk, sv, sb = pk.unpack(tab, k, B, S, Wk, Wv)
     with jax.named_scope("kv.unpermute"):
         inv = jnp.argsort(perm, axis=1)                    # sorted -> program
         out = jnp.take_along_axis(out, inv[:, None, :], axis=2)
         found = (out[:, 0] & 1) != 0
         ok = ((out[:, 0] >> 1) & 1) != 0
         value = out[:, 1:].transpose(0, 2, 1)
-    return sk, sv, sb, found, ok, value
+    return tab, found, ok, value

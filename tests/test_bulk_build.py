@@ -63,9 +63,8 @@ def _serialized_oracle(cfg, rng_key, keys, vals):
 
 
 def _assert_tables_equal(a, b, ctx=""):
-    for nm in ("store_keys", "store_vals", "store_valid"):
-        x, y = np.asarray(getattr(a, nm)), np.asarray(getattr(b, nm))
-        assert (x == y).all(), (ctx, nm)
+    for nm, x, y in zip(("keys", "vals", "valid"), a.stores(), b.stores()):
+        assert (np.asarray(x) == np.asarray(y)).all(), (ctx, nm)
 
 
 def _first_occurrence(keys):
@@ -110,12 +109,11 @@ def test_bulk_build_pallas_multipass_bit_exact(chunk, rng, key):
     plan = plan_bulk_build(jnp.array(keys), jnp.array(vals),
                            h3_hash(jnp.array(keys), t0.q_masks),
                            buckets=cfg.buckets, slots=cfg.slots)
-    planes = (t0.store_keys[0, 0], t0.store_vals[0, 0], t0.store_valid[0, 0])
     args = (plan["w_bucket"], plan["w_slot"], jnp.array(keys), plan["val_w"])
-    want = get_backend("jnp").bulk_place(*planes, *args)
-    got = bulk_place_pallas(*args, *planes, chunk=chunk)
-    for x, y in zip(got, want):
-        assert (np.asarray(x) == np.asarray(y)).all(), chunk
+    geom = dict(buckets=cfg.buckets, slots=cfg.slots)
+    want = get_backend("jnp").bulk_place(t0.packed[0], *args, **geom)
+    got = bulk_place_pallas(*args, t0.packed[0], chunk=chunk, **geom)
+    assert (np.asarray(got) == np.asarray(want)).all(), chunk
 
 
 def test_bulk_build_empty_batch(key):
@@ -210,14 +208,13 @@ SHARDED = textwrap.dedent("""
             cfg_r = dataclasses.replace(cfg, shards=1)
             ref = init_table(cfg_r, jax.random.key(1))
             ref = XorHashTable(jnp.array(jax.device_get(dtab.q_masks)),
-                               ref.store_keys, ref.store_vals,
-                               ref.store_valid, cfg_r)
+                               ref.packed, cfg_r)
             ref2, rrep = bulk_build(ref, keys.reshape(T * N, -1),
                                     vals.reshape(T * N, -1), backend='jnp')
-            for nm in ('store_keys', 'store_vals', 'store_valid'):
-                a = np.asarray(getattr(dtab2, nm))
-                b = np.asarray(getattr(ref2, nm))
-                assert (a == b).all(), (D, router, nm)
+            for nm, a, b in zip(('keys', 'vals', 'valid'), dtab2.stores(),
+                                ref2.stores()):
+                assert (np.asarray(a) == np.asarray(b)).all(), \\
+                    (D, router, nm)
             for nm in ('placed', 'spilled', 'first', 'slot'):
                 a = np.asarray(getattr(rep, nm)).reshape(T * N)
                 b = np.asarray(getattr(rrep, nm))
@@ -226,10 +223,8 @@ SHARDED = textwrap.dedent("""
             assert int(rep.max_load) == int(rrep.max_load), (D, router)
             # distributed compaction: bulk output is already canonical
             dcomp = make_distributed_compact(mesh, cfg)(dtab2)
-            for nm in ('store_keys', 'store_vals', 'store_valid'):
-                a = np.asarray(getattr(dcomp, nm))
-                b = np.asarray(getattr(dtab2, nm))
-                assert (a == b).all(), (D, router, 'compact', nm)
+            a, b = np.asarray(dcomp.packed), np.asarray(dtab2.packed)
+            assert (a == b).all(), (D, router, 'compact')
     print('SHARDED_BULK_OK')
 """)
 

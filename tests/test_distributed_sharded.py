@@ -26,10 +26,12 @@ BITEXACT = textwrap.dedent("""
         mesh = make_ht_mesh(D)
         tab_s = init_distributed_table(cfg, jax.random.key(1), mesh)
         # each device holds only buckets/shards of the table (live sharding)
-        for arr in (tab_s.store_keys, tab_s.store_vals, tab_s.store_valid):
-            shp = arr.sharding.shard_shape(arr.shape)
-            assert shp[2] == cfg.local_buckets == cfg.buckets // D, shp
-            assert len({s.device for s in arr.addressable_shards}) == D
+        from repro.kernels.packed import packed_groups
+        arr = tab_s.packed
+        shp = arr.sharding.shard_shape(arr.shape)
+        assert shp[1] == packed_groups(cfg.local_buckets), shp
+        assert cfg.local_buckets == cfg.buckets // D
+        assert len({s.device for s in arr.addressable_shards}) == D
         cfg_rep = dataclasses.replace(cfg, shards=1)
         tab_r = init_distributed_table(cfg_rep, jax.random.key(1))
         stream_s = make_distributed_stream(mesh, cfg)
@@ -46,17 +48,17 @@ BITEXACT = textwrap.dedent("""
             a, b = np.asarray(getattr(rs, nm)), np.asarray(getattr(rr, nm))
             assert (a == b).all(), (D, nm)
         # the gathered sharded table == the replicated table, byte for byte
-        for nm in ('store_keys', 'store_vals', 'store_valid'):
-            a, b = np.asarray(getattr(ts, nm)), np.asarray(getattr(tr, nm))
-            assert (a == b).all(), (D, nm)
+        for nm, a, b in zip(('keys', 'vals', 'valid'), ts.stores(),
+                            tr.stores()):
+            assert (np.asarray(a) == np.asarray(b)).all(), (D, nm)
         # T == 1 special case: the rewritten per-step entry agrees too
         step_s = make_distributed_step(mesh, cfg)
         step_r = make_distributed_step(mesh, cfg_rep)
         t1s = step_s(tab_s, ops[0], keys[0], vals[0])
         t1r = step_r(tab_r, ops[0], keys[0], vals[0])
         assert (np.asarray(t1s[1].found) == np.asarray(t1r[1].found)).all()
-        assert (np.asarray(t1s[0].store_keys)
-                == np.asarray(t1r[0].store_keys)).all()
+        assert (np.asarray(t1s[0].stores()[0])
+                == np.asarray(t1r[0].stores()[0])).all()
     print('SHARDED_BITEXACT_OK')
 """)
 
@@ -98,7 +100,7 @@ SKEW = textwrap.dedent("""
     assert np.asarray(res.found)[1].all()
     assert (np.asarray(res.value)[1, :, 0] == srch_vals[:, 0]).all()
     # the whole population lives on shard 5's partition and nowhere else
-    occupied = np.asarray(tab2.store_valid).sum(axis=(0, 1, 3))  # per bucket
+    occupied = np.asarray(tab2.stores()[2]).sum(axis=(0, 1, 3))  # per bucket
     lb = cfg.local_buckets
     assert occupied[5 * lb:(5 + 1) * lb].sum() > 0
     assert occupied[:5 * lb].sum() == 0 and occupied[6 * lb:].sum() == 0
@@ -108,7 +110,7 @@ SKEW = textwrap.dedent("""
     tr, rr = make_distributed_stream(mesh, cfg_rep)(tab_r, ops, keys, vv)
     assert (np.asarray(res.found) == np.asarray(rr.found)).all()
     assert (np.asarray(res.value) == np.asarray(rr.value)).all()
-    assert (np.asarray(tab2.store_keys) == np.asarray(tr.store_keys)).all()
+    assert (np.asarray(tab2.stores()[0]) == np.asarray(tr.stores()[0])).all()
     print('SHARDED_SKEW_OK')
 """)
 
@@ -118,8 +120,10 @@ PREFIX_CACHE = textwrap.dedent("""
 
     pc = PrefixCache(num_pages=64, p=8, shards=4)
     assert pc.cfg.shards == 4
-    sk = pc.table.store_keys
-    assert sk.sharding.shard_shape(sk.shape)[2] == pc.cfg.local_buckets
+    from repro.kernels.packed import packed_groups
+    tab = pc.table.packed
+    assert (tab.sharding.shard_shape(tab.shape)[1]
+            == packed_groups(pc.cfg.local_buckets))
     keys = np.arange(1, 25, dtype=np.uint64) * 0x9E3779B97F4A7C15
     pages = pc.admit_batch(keys)
     assert (pages >= 0).all() and len(set(pages.tolist())) == len(keys)

@@ -38,9 +38,9 @@ def test_backends_bit_exact_on_random_trace(replicate, stagger, kw,
         a = np.asarray(getattr(res_j, name))
         b = np.asarray(getattr(res_p, name))
         assert (a == b).all(), f"StepResults.{name} diverged"
-    for name in ("store_keys", "store_vals", "store_valid"):
-        a = np.asarray(getattr(tab_j, name))
-        b = np.asarray(getattr(tab_p, name))
+    for name, a, b in zip(("keys", "vals", "valid"), tab_j.stores(),
+                          tab_p.stores()):
+        a, b = np.asarray(a), np.asarray(b)
         assert (a == b).all(), f"table.{name} diverged ({(a != b).sum()} words)"
 
 
@@ -59,7 +59,7 @@ def test_engine_step_matches_apply_step(backend, trace_gen):
         tab_b, res_b = engine.step(tab_b, batch)
         assert (np.asarray(res_a.found) == np.asarray(res_b.found)).all()
         assert (np.asarray(res_a.value) == np.asarray(res_b.value)).all()
-    assert (np.asarray(tab_a.store_keys) == np.asarray(tab_b.store_keys)).all()
+    assert (np.asarray(tab_a.packed) == np.asarray(tab_b.packed)).all()
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])

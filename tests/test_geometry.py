@@ -142,12 +142,14 @@ def test_bad_op_mix_rejected():
 
 
 def test_replica_bytes_matches_kernel_accounting():
-    from repro.kernels.ops import replica_bytes as kernel_replica_bytes
+    from repro.kernels.ops import table_vmem_bytes
     cfg = _cfg(k=3)
     tab = init_table(cfg, jax.random.key(0))
-    assert cfg.replica_bytes == kernel_replica_bytes(
-        tab.store_keys, tab.store_vals, tab.store_valid)
+    assert cfg.replica_bytes == 4 * sum(x[0].size for x in tab.stores())
     assert cfg.table_bytes == cfg.replicas * cfg.replica_bytes
+    assert tab.memory_bytes == cfg.table_bytes
+    # the kernels' VMEM accounting adds only the stored layout's padding
+    assert cfg.replica_bytes <= table_vmem_bytes(tab.packed)
 
 
 # --------------------------------------------------------------------------
@@ -249,7 +251,7 @@ def test_reconfigure_round_trip(backend, trace_gen):
 
     compact = reconfigure(table, dataclasses.replace(cfg, k=2),
                           backend=backend)
-    assert compact.store_keys.shape[1] == 2
+    assert compact.stores()[0].shape[1] == 2
     assert _record_set(compact) == before
     # searches on the migrated table resolve every live record
     rec = sorted(before)
@@ -283,7 +285,7 @@ def test_reconfigure_to_replicated_and_back(trace_gen):
     before = _record_set(table)
     compact = reconfigure(table, dataclasses.replace(
         cfg, k=1, replicate_reads=False))
-    assert compact.store_keys.shape[:2] == (1, 1)
+    assert compact.stores()[0].shape[:2] == (1, 1)
     assert _record_set(compact) == before
     assert _record_set(reconfigure(compact, cfg)) == before
 
@@ -304,7 +306,7 @@ def test_reconfigure_capacity_round_trip(trace_gen):
     assert before
     big = reconfigure(table, dataclasses.replace(cfg, buckets=1 << 9),
                       rng=jax.random.key(7))
-    assert big.store_keys.shape[2] == 1 << 9
+    assert big.stores()[0].shape[2] == 1 << 9
     assert big.q_masks.shape[0] == big.cfg.index_bits
     assert _record_set(big) == before
     # searches resolve on the grown table

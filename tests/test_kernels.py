@@ -9,6 +9,7 @@ from repro.core import (HashTableConfig, OP_INSERT, init_table, run_stream,
                         schedule_queries)
 from repro.core.engine import commit_jnp, probe_jnp
 from repro.core.hashing import h3_hash as h3_jnp
+from repro.kernels import packed as pk
 from repro.kernels.h3_hash import h3_hash_pallas
 from repro.kernels.xor_probe import xor_probe_pallas
 from repro.kernels.xor_commit import xor_commit_pallas
@@ -67,11 +68,10 @@ def test_xor_probe_kernel_sweep(k, slots, kw, vw, rng):
     from repro.core.hashing import h3_hash as h3core
     bucket = h3core(jnp.array(qkeys), tab.q_masks)
     port = jnp.array(rng.integers(0, k, N, dtype=np.int32))
-    args = (bucket, port, jnp.array(qkeys), tab.store_keys[0],
-            tab.store_vals[0], tab.store_valid[0])
-    outs_k = xor_probe_pallas(*args)
-    outs_r = probe_jnp(args[0], args[1], args[2], args[3][None], args[4][None],
-                       args[5][None])
+    outs_k = xor_probe_pallas(bucket, port, jnp.array(qkeys), tab.packed[0],
+                              k=k, slots=slots, val_words=vw)
+    outs_r = probe_jnp(bucket, port, jnp.array(qkeys), tab.packed, k=k,
+                       slots=slots, val_words=vw, part_buckets=128)
     names = ["found", "mslot", "oslot", "hopen", "value", "remk", "remv",
              "remb"]
     for nm, a, b in zip(names, outs_k, outs_r):
@@ -97,20 +97,19 @@ def test_ops_wrappers_fallback(rng):
 def test_xor_commit_kernel_vs_oracle(k, slots, stagger, R, rng):
     """Scatter-only commit kernel fed one engine-side encode == the jnp
     encode+scatter oracle, for every replica (replicas byte-identical, so
-    one encoding serves all R — the per-replica grid only scatters)."""
+    one encoding into one replica's stored array serves all R)."""
     kw, vw, B, N = 2, 1, 64, 32
     _, tab, ins_keys, _ = _populated_table(rng, k, B, slots, kw, vw, 24)
     # build a write batch against a populated single-replica table, then
     # replicate the state R times (replicas are identical by construction)
-    sk = jnp.broadcast_to(tab.store_keys[0], (R,) + tab.store_keys.shape[1:])
-    sv = jnp.broadcast_to(tab.store_vals[0], (R,) + tab.store_vals.shape[1:])
-    sb = jnp.broadcast_to(tab.store_valid[0], (R,) + tab.store_valid.shape[1:])
+    tabR = jnp.broadcast_to(tab.packed[0], (R,) + tab.packed.shape[1:])
     qkeys = np.zeros((N, kw), np.uint32)
     qkeys[:24] = ins_keys                        # overwrite existing entries
     qkeys[24:] = rng.integers(1, 2 ** 32, size=(N - 24, kw), dtype=np.uint32)
     bucket = h3_jnp(jnp.array(qkeys), tab.q_masks)
     port = jnp.array(rng.integers(0, k, N, dtype=np.int32))
-    pr = probe_jnp(bucket, port, jnp.array(qkeys), sk, sv, sb, stagger=stagger)
+    pr = probe_jnp(bucket, port, jnp.array(qkeys), tabR, k=k, slots=slots,
+                   val_words=vw, part_buckets=B, stagger=stagger)
     found, mslot, oslot, hopen = pr[0], pr[1], pr[2], pr[3]
     remk, remv, remb = pr[5], pr[6], pr[7]
     slot = jnp.where(found, mslot, oslot)
@@ -135,15 +134,16 @@ def test_xor_commit_kernel_vs_oracle(k, slots, stagger, R, rng):
     enc_k = new_key ^ pick(remk, slot)
     enc_v = new_val ^ pick(remv, slot)
     enc_b = new_valid ^ pick(remb, slot)
-    outs_k = xor_commit_pallas(sk, sv, sb, port, w_bucket, slot,
-                               enc_k, enc_v, enc_b)
-    outs_r = commit_jnp(sk, sv, sb, port, w_bucket, slot, do_write,
-                        new_key, new_val, new_valid)
+    out_tab = xor_commit_pallas(tab.packed[0], port, w_bucket, slot,
+                                enc_k, enc_v, enc_b, buckets=B, slots=slots)
+    outs_k = pk.unpack(out_tab, k, B, slots, kw, vw)
+    out_r = commit_jnp(tabR, port, w_bucket, slot, do_write, new_key,
+                       new_val, new_valid, k=k, buckets=B, slots=slots)
+    assert out_r.shape == tabR.shape
+    outs_r = pk.unpack_stores(out_r, k, B, slots, kw, vw)
     for nm, a, b in zip(("keys", "vals", "valid"), outs_k, outs_r):
-        assert (np.asarray(a) == np.asarray(b)).all(), nm
-    # replicas must stay identical after the commit
-    for a in outs_k:
-        assert (np.asarray(a) == np.asarray(a)[0:1]).all()
+        # replicas must stay identical after the commit
+        assert (np.asarray(b) == np.asarray(a)[None]).all(), nm
 
 
 def test_h3_distribution_quality(rng):

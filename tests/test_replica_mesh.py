@@ -80,9 +80,9 @@ CONFORM = textwrap.dedent("""
                     assert (a == b).all(), (S, groups, kind, name, nm)
                 # replica coherence: device d's partition == the oracle's
                 # slice for shard_of[d], byte for byte
-                for nm in ('store_keys', 'store_vals', 'store_valid'):
-                    a = np.asarray(getattr(ts, nm))
-                    b = np.asarray(getattr(tr, nm))
+                for nm, a, b in zip(('keys', 'vals', 'valid'),
+                                    ts.stores(), tr.stores()):
+                    a, b = np.asarray(a), np.asarray(b)
                     for d in range(Dv):
                         s = shard_of[d]
                         assert (a[:, :, d * lb:(d + 1) * lb]
@@ -119,8 +119,7 @@ BULK = textwrap.dedent("""
         cfg_r = dataclasses.replace(cfg, shards=1, replica_groups=None)
         ref = init_table(cfg_r, jax.random.key(2))
         ref = XorHashTable(jnp.array(jax.device_get(dtab.q_masks)),
-                           ref.store_keys, ref.store_vals,
-                           ref.store_valid, cfg_r)
+                           ref.packed, cfg_r)
         ref2, rrep = engine.bulk_build(ref, keys.reshape(T * N, -1),
                                        vals.reshape(T * N, -1),
                                        backend='jnp')
@@ -128,9 +127,9 @@ BULK = textwrap.dedent("""
             a = np.asarray(getattr(rep, nm)).reshape(T * N)
             b = np.asarray(getattr(rrep, nm))
             assert (a == b).all(), (S, groups, nm)
-        for nm in ('store_keys', 'store_vals', 'store_valid'):
-            a, b = np.asarray(getattr(tab, nm)), \\
-                np.asarray(getattr(ref2, nm))
+        for nm, a, b in zip(('keys', 'vals', 'valid'), tab.stores(),
+                            ref2.stores()):
+            a, b = np.asarray(a), np.asarray(b)
             for d in range(Dv):
                 s = shard_of[d]
                 assert (a[:, :, d * lb:(d + 1) * lb]
@@ -139,7 +138,7 @@ BULK = textwrap.dedent("""
         # grouped compaction keeps every group member's partition identical
         compact = make_distributed_compact(mesh, cfg)
         tab2 = compact(tab)
-        v = np.asarray(tab2.store_valid)
+        v = np.asarray(tab2.stores()[2])
         for s in range(S):
             o = cfg.group_offsets[s]
             ref = v[:, :, o * lb:(o + 1) * lb]

@@ -45,9 +45,7 @@ def _record_set(tab):
 def _born_big(state):
     """Empty twin at the successor geometry with the SAME H3 masks."""
     s = state.succ
-    return XorHashTable(s.q_masks, jnp.zeros_like(s.store_keys),
-                        jnp.zeros_like(s.store_vals),
-                        jnp.zeros_like(s.store_valid), s.cfg)
+    return XorHashTable(s.q_masks, jnp.zeros_like(s.packed), s.cfg)
 
 
 def _mixed_trace(rng, T, cfg, key_space=300):
@@ -245,10 +243,8 @@ def drive(cfg, tag):
     table, _ = stream(table, op[:3], keys[:3], vals[:3])
     rs = dist.make_distributed_resize(mesh, cfg, cfg.buckets * 2)
     st = rs.begin(table, jax.random.PRNGKey(42))
-    twin = XorHashTable(st.succ.q_masks,
-                        jnp.zeros_like(st.succ.store_keys),
-                        jnp.zeros_like(st.succ.store_vals),
-                        jnp.zeros_like(st.succ.store_valid), st.succ.cfg)
+    twin = XorHashTable(st.succ.q_masks, jnp.zeros_like(st.succ.packed),
+                        st.succ.cfg)
     tstream = dist.make_distributed_stream(mesh, st.succ.cfg)
     twin, _ = tstream(twin, op[:3], keys[:3], vals[:3])
     # NSQ-contract rejections and full-bucket failures hit both sides
@@ -265,7 +261,7 @@ def drive(cfg, tag):
     final = rs.finish(st)
     assert recset(final) == recset(twin), tag
     # successor kept the shard partitioning (owner bits never moved)
-    assert "ht" in str(final.store_keys.sharding), final.store_keys.sharding
+    assert "ht" in str(final.packed.sharding), final.packed.sharding
     print("SHARDED_RESIZE_OK", tag, len(recset(final)))
 
 drive(HashTableConfig(p=8, k=8, buckets=1 << 6, slots=8, key_words=2,
@@ -325,9 +321,7 @@ def test_server_grows_and_matches_twin():
     assert 0.0 < st["load_factor"] < pol.grow_load_factor
 
     twin_tab = XorHashTable(srv.table.q_masks,
-                            jnp.zeros_like(srv.table.store_keys),
-                            jnp.zeros_like(srv.table.store_vals),
-                            jnp.zeros_like(srv.table.store_valid), srv.cfg)
+                            jnp.zeros_like(srv.table.packed), srv.cfg)
     tsrv = TableServer(srv.cfg, twin_tab, eng.run_stream,
                        ServeConfig(slab_steps=2, geometry_replan=False))
     treqs = [(o, k, v, tsrv.submit(o, k, v)) for (o, k, v, _) in reqs]
@@ -400,12 +394,10 @@ srv.run()
 st = srv.stats()
 assert st["resizes"] >= 1, st
 assert srv.cfg.buckets > cfg.buckets
-assert "ht" in str(srv.table.store_keys.sharding), srv.table.store_keys.sharding
+assert "ht" in str(srv.table.packed.sharding), srv.table.packed.sharding
 
-twin_tab = XorHashTable(srv.table.q_masks,
-                        jnp.zeros_like(srv.table.store_keys),
-                        jnp.zeros_like(srv.table.store_vals),
-                        jnp.zeros_like(srv.table.store_valid), srv.cfg)
+twin_tab = XorHashTable(srv.table.q_masks, jnp.zeros_like(srv.table.packed),
+                        srv.cfg)
 tsrv = TableServer(srv.cfg, twin_tab,
                    dist.make_distributed_stream(mesh, srv.cfg),
                    ServeConfig(slab_steps=2, geometry_replan=False))

@@ -55,10 +55,10 @@ CONFORM = textwrap.dedent("""
                     a = np.asarray(getattr(rs, nm))
                     b = np.asarray(getattr(rr, nm))
                     assert (a == b).all(), (D, kind, name, nm)
-                for nm in ('store_keys', 'store_vals', 'store_valid'):
-                    a = np.asarray(getattr(ts, nm))
-                    b = np.asarray(getattr(tr, nm))
-                    assert (a == b).all(), (D, kind, name, nm)
+                for nm, a, b in zip(('keys', 'vals', 'valid'),
+                                    ts.stores(), tr.stores()):
+                    assert (np.asarray(a) == np.asarray(b)).all(), \\
+                        (D, kind, name, nm)
             # the bounded plan really shrank the routed width on this trace
             bucket = h3_hash(keys.reshape(T * N, 1),
                              streams['bounded'][1].q_masks).reshape(T, N)
@@ -131,9 +131,8 @@ CARRY = textwrap.dedent("""
     for nm in ('found', 'value', 'ok', 'bucket'):
         a, b = np.asarray(getattr(rb, nm)), np.asarray(getattr(rr, nm))
         assert (a == b).all(), nm
-    for nm in ('store_keys', 'store_vals', 'store_valid'):
-        a, b = np.asarray(getattr(tb, nm)), np.asarray(getattr(tr, nm))
-        assert (a == b).all(), nm
+    for nm, a, b in zip(('keys', 'vals', 'valid'), tb.stores(), tr.stores()):
+        assert (np.asarray(a) == np.asarray(b)).all(), nm
     assert np.asarray(rb.found)[2].all(), 'carried burst searches must hit'
     print('ROUTER_CARRY_OK')
 """)

@@ -5,6 +5,7 @@ fake-device subprocess."""
 import functools
 import glob
 import os
+import re
 import subprocess
 import sys
 
@@ -427,8 +428,12 @@ def test_spans_off_construct_no_annotation(monkeypatch, trace_gen):
 
 
 def test_served_stream_carries_each_scope():
+    """The served stream keeps the seam's scopes around the kernel, and
+    hands the stored table to it as it is: no op under a repack scope, and
+    the donated table parameter aliased to the table output."""
     cfg = HashTableConfig(p=4, k=4, buckets=1 << 8, slots=4, key_words=2,
-                          val_words=2, backend="pallas")
+                          val_words=2, replicate_reads=False,
+                          backend="pallas")
     stream = jax.jit(functools.partial(engine.run_stream, backend="pallas"),
                      donate_argnums=(0,))
     T, N = 2, cfg.queries_per_step
@@ -436,9 +441,13 @@ def test_served_stream_carries_each_scope():
                        jnp.zeros((T, N), jnp.int32),
                        jnp.zeros((T, N, 2), jnp.uint32),
                        jnp.zeros((T, N, 2), jnp.uint32)).compile().as_text()
-    for scope in ("kv.h3", "kv.prepass", "kv.table_pack", "kv.table_unpack",
-                  "kv.unpermute", "xor_stream"):
+    for scope in ("kv.h3", "kv.prepass", "kv.unpermute", "xor_stream"):
         assert f"/{scope}/" in hlo, scope
+    for scope in ("kv.table_pack", "kv.table_unpack"):
+        assert scope not in hlo, scope
+    # flat argument 1 (the stored table) is flat result 1: in place
+    assert re.search(r"\{1\}: \(1, \{\}, (may|must)-alias\)", hlo), \
+        hlo.splitlines()[0]
 
 
 # --------------------------------------------------------------------------

@@ -13,8 +13,8 @@ import jax.numpy as jnp
 
 import repro.kernels.ops as kops
 from repro.core import (HashTableConfig, OP_DELETE, OP_INSERT, OP_SEARCH,
-                        QueryBatch, apply_step, engine, init_table,
-                        run_stream, schedule_queries)
+                        QueryBatch, XorHashTable, apply_step, engine,
+                        init_table, run_stream, schedule_queries)
 
 
 def _assert_same(tab_a, res_a, tab_b, res_b, what=""):
@@ -22,9 +22,9 @@ def _assert_same(tab_a, res_a, tab_b, res_b, what=""):
         a = np.asarray(getattr(res_a, name))
         b = np.asarray(getattr(res_b, name))
         assert (a == b).all(), f"{what}: StepResults.{name} diverged"
-    for name in ("store_keys", "store_vals", "store_valid"):
-        a = np.asarray(getattr(tab_a, name))
-        b = np.asarray(getattr(tab_b, name))
+    for name, a, b in zip(("keys", "vals", "valid"), tab_a.stores(),
+                          tab_b.stores()):
+        a, b = np.asarray(a), np.asarray(b)
         assert (a == b).all(), \
             f"{what}: table.{name} diverged ({(a != b).sum()} words)"
 
@@ -66,11 +66,9 @@ def test_fused_stream_bucket_blocked_bit_exact(stagger, buckets, trace_gen,
     op, keys, vals = trace_gen.mixed(128, 1)
     ops, kk, vv = schedule_queries(op, keys, vals, cfg)
     tab = init_table(cfg, jax.random.key(0))
-    rb = kops.table_vmem_bytes(tab.store_keys, tab.store_vals,
-                               tab.store_valid)
+    rb = kops.table_vmem_bytes(tab.packed)
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", rb // 7)
-    assert kops.stream_bucket_tiles(tab.store_keys, tab.store_vals,
-                                    tab.store_valid) == buckets // 128
+    assert kops.stream_bucket_tiles(tab.packed) == buckets // 128
     (tab_j, res_j), (tab_f, res_f) = _oracle_and_fused(cfg, ops, kk, vv)
     _assert_same(tab_j, res_j, tab_f, res_f,
                  f"blocked stagger={stagger} buckets={buckets}")
@@ -88,10 +86,9 @@ def test_fused_stream_pass_count_follows_budget(passes, trace_gen,
     op, keys, vals = trace_gen.mixed(64, 1)
     ops, kk, vv = schedule_queries(op, keys, vals, cfg)
     tab = init_table(cfg, jax.random.key(0))
-    args = (tab.store_keys, tab.store_vals, tab.store_valid)
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES",
-                        kops.table_vmem_bytes(*args) // passes)
-    assert kops.stream_bucket_tiles(*args) == passes
+                        kops.table_vmem_bytes(tab.packed) // passes)
+    assert kops.stream_bucket_tiles(tab.packed) == passes
     (tab_j, res_j), (tab_f, res_f) = _oracle_and_fused(cfg, ops, kk, vv)
     _assert_same(tab_j, res_j, tab_f, res_f, f"passes={passes}")
 
@@ -105,8 +102,8 @@ def test_xor_stream_rejects_passes_that_split_a_group():
     zq = jnp.zeros((1, 2, 1), jnp.uint32)
     with pytest.raises(ValueError):
         xor_stream_pallas(z.astype(jnp.uint32), z[0], z[0] + 1, z, zq, zq,
-                          tab.store_keys[0], tab.store_vals[0],
-                          tab.store_valid[0], passes=3)
+                          tab.packed[0], k=cfg.k, buckets=cfg.buckets,
+                          slots=cfg.slots, passes=3)
 
 
 def test_fused_stream_matches_scanned_pallas(trace_gen):
@@ -153,10 +150,9 @@ RACE_BUCKETS = 512
 
 def _set_layout(layout, tab, monkeypatch):
     passes = _LAYOUTS[layout]
-    args = (tab.store_keys, tab.store_vals, tab.store_valid)
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES",
-                        kops.table_vmem_bytes(*args) // passes)
-    assert kops.stream_bucket_tiles(*args) == passes
+                        kops.table_vmem_bytes(tab.packed) // passes)
+    assert kops.stream_bucket_tiles(tab.packed) == passes
 
 
 def _keys_in_last_pass(tab, n):
@@ -166,7 +162,7 @@ def _keys_in_last_pass(tab, n):
     from repro.core.hashing import h3_hash as h3
     cand = np.arange(1, 4096, dtype=np.uint32)
     b = np.asarray(h3(jnp.asarray(cand[:, None]), tab.q_masks))
-    B = tab.store_keys.shape[2]
+    B = tab.buckets
     return [int(x) for x in cand[b >= 3 * B // 4][:n]]
 
 
@@ -254,9 +250,9 @@ def test_stream_backend_dispatch(trace_gen):
         ref, _ = apply_step(ref, QueryBatch(jnp.array(ops[t]),
                                             jnp.array(kk[t]),
                                             jnp.array(vv[t])))
-    base = np.asarray(ref.store_keys)
+    base = np.asarray(ref.packed)
     for label, (tab_x, _) in outs.items():
-        assert (np.asarray(tab_x.store_keys) == base).all(), label
+        assert (np.asarray(tab_x.packed) == base).all(), label
     _assert_same(*outs["jnp"], *outs["pallas-auto"], what="jnp vs dispatch")
 
 
@@ -268,7 +264,7 @@ def test_stream_empty_and_shape_guard():
                            jnp.zeros((0, n, 1), jnp.uint32),
                            jnp.zeros((0, n, 1), jnp.uint32), fused=True)
     assert res.found.shape == (0, n)
-    assert (np.asarray(tab2.store_keys) == np.asarray(tab.store_keys)).all()
+    assert (np.asarray(tab2.packed) == np.asarray(tab.packed)).all()
     with pytest.raises(ValueError):
         run_stream(tab, jnp.zeros((1, n + 1), jnp.int32),
                    jnp.zeros((1, n + 1, 1), jnp.uint32),
@@ -279,11 +275,11 @@ def test_replica_bytes_helper():
     cfg = HashTableConfig(p=4, k=2, buckets=64, slots=2, key_words=2,
                           val_words=1, replicate_reads=True)
     tab = init_table(cfg, jax.random.key(0))
-    rb = kops.replica_bytes(tab.store_keys, tab.store_vals, tab.store_valid)
-    assert rb == tab.memory_bytes // cfg.replicas
-    # 4D single replica == one 5D replica
-    assert kops.replica_bytes(tab.store_keys[0], tab.store_vals[0],
-                              tab.store_valid[0]) == rb
+    rb = 4 * sum(x[0].size for x in tab.stores())
+    assert rb == tab.memory_bytes // cfg.replicas == cfg.replica_bytes
+    # one replica's stored array == one replica of the replicated array
+    vb = kops.table_vmem_bytes(tab.packed)
+    assert kops.table_vmem_bytes(tab.packed[0]) == vb >= rb
     # helper is the engine's budget check too
     assert engine.resolve_backend(
         dataclasses.replace(cfg, backend="pallas"), tab).name == "pallas"
@@ -292,14 +288,13 @@ def test_replica_bytes_helper():
 def test_stream_bucket_tiles_power_of_two(monkeypatch):
     cfg = HashTableConfig(p=2, k=2, buckets=1024, slots=2)
     tab = init_table(cfg, jax.random.key(0))
-    args = (tab.store_keys, tab.store_vals, tab.store_valid)
-    assert kops.stream_bucket_tiles(*args) == 1
-    rb = kops.table_vmem_bytes(*args)
+    assert kops.stream_bucket_tiles(tab.packed) == 1
+    rb = kops.table_vmem_bytes(tab.packed)
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", rb // 3)
-    assert kops.stream_bucket_tiles(*args) == 4
+    assert kops.stream_bucket_tiles(tab.packed) == 4
     monkeypatch.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", 1)
     # capped at one 128-bucket group per pass
-    assert kops.stream_bucket_tiles(*args) == cfg.buckets // 128
+    assert kops.stream_bucket_tiles(tab.packed) == cfg.buckets // 128
 
 
 def test_run_stream_local_partitions_merge_to_oracle(trace_gen, monkeypatch):
@@ -328,29 +323,27 @@ def test_run_stream_local_partitions_merge_to_oracle(trace_gen, monkeypatch):
     Bl = scfg.local_buckets
     # (fused, shrink_budget): scanned jnp, one-pass fused, four-pass sweep
     combos = [(False, False), (True, False), (True, True)]
+    stores = tab.stores()
     for fused, shrink in combos:
         label = f"fused={fused} shrink={shrink}"
-        parts = {"store_keys": [], "store_vals": [], "store_valid": []}
+        parts = []
         got_f = np.zeros((T, N), bool)
         got_ok = np.zeros((T, N), bool)
         got_v = np.zeros((T, N, 1), np.uint32)
         for s in range(scfg.shards):
             lo = s * Bl
-            part = (tab.store_keys[:, :, lo:lo + Bl],
-                    tab.store_vals[:, :, lo:lo + Bl],
-                    tab.store_valid[:, :, lo:lo + Bl])
+            part = XorHashTable.from_stores(
+                tab.q_masks, *(x[:, :, lo:lo + Bl] for x in stores), scfg)
             with monkeypatch.context() as m:
                 if shrink:     # multi-pass: 4 sweep passes
-                    rb = kops.table_vmem_bytes(*part)
+                    rb = kops.table_vmem_bytes(part.packed)
                     m.setattr(kops, "VMEM_TABLE_BUDGET_BYTES", rb // 3)
-                    assert kops.stream_bucket_tiles(*part) == 4
-                sk, sv, sb, f, ok, val = engine.run_stream_local(
-                    scfg, *part,
+                    assert kops.stream_bucket_tiles(part.packed) == 4
+                packed, f, ok, val = engine.run_stream_local(
+                    scfg, part.packed,
                     pe, bucket, jnp.array(ops), jnp.array(kk), jnp.array(vv),
                     bucket_base=lo, fused=fused)
-            parts["store_keys"].append(np.asarray(sk))
-            parts["store_vals"].append(np.asarray(sv))
-            parts["store_valid"].append(np.asarray(sb))
+            parts.append(dataclasses.replace(part, packed=packed).stores())
             # exactly one partition owns each lane; the rest stay False/0
             assert not (got_f & np.asarray(f)).any()
             got_f |= np.asarray(f)
@@ -359,9 +352,10 @@ def test_run_stream_local_partitions_merge_to_oracle(trace_gen, monkeypatch):
         assert (got_f == np.asarray(ores.found)).all(), label
         assert (got_ok == np.asarray(ores.ok)).all(), label
         assert (got_v == np.asarray(ores.value)).all(), label
-        for nm, chunks in parts.items():
-            merged = np.concatenate(chunks, axis=2)
-            assert (merged == np.asarray(getattr(otab, nm))).all(), \
+        for nm, chunks, want in zip(("keys", "vals", "valid"), zip(*parts),
+                                    otab.stores()):
+            merged = np.concatenate([np.asarray(c) for c in chunks], axis=2)
+            assert (merged == np.asarray(want)).all(), \
                 f"{label}: {nm} diverged"
 
 

@@ -75,13 +75,12 @@ def test_fused_layouts_match_oracle_on_duplicate_heavy_traces(trace, stagger):
     assert (np.asarray(tab.q_masks) == masks).all()
     args = (tab, jnp.array(ops), jnp.array(kk), jnp.array(vv))
     tab_j, res_j = run_stream(*args, backend="jnp", fused=False)
-    stores = (tab.store_keys, tab.store_vals, tab.store_valid)
     saved = kops.VMEM_TABLE_BUDGET_BYTES
     try:
         for passes in (1, 2, 4):
             kops.VMEM_TABLE_BUDGET_BYTES = \
-                kops.table_vmem_bytes(*stores) // passes
-            assert kops.stream_bucket_tiles(*stores) == passes
+                kops.table_vmem_bytes(tab.packed) // passes
+            assert kops.stream_bucket_tiles(tab.packed) == passes
             tab_f, res_f = run_stream(*args, fused=True)
             _assert_same(tab_j, res_j, tab_f, res_f,
                          f"{passes}-pass stagger={stagger}")
