@@ -63,7 +63,7 @@ def backlog(cell, win, at_s: float) -> int:
     return int((arrived & ~done).sum())
 
 
-def sweep(spec, args, device) -> None:
+def sweep(spec, args, devices) -> None:
     import kvbench
     entry = kvbench.cell_entry(spec, args.workload)
     config = kvbench.load_config(spec, entry["config"])
@@ -88,13 +88,13 @@ def sweep(spec, args, device) -> None:
         del cell, win
 
 
-def control(spec, args, device) -> None:
+def control(spec, args, devices) -> None:
     import kvbench
     wrap = StaleReads if args.mode == "control" else None
     for seed in [int(s) for s in args.seeds.split(",")]:
         r = kvbench.run_cell(spec, args.workload, seed, args.seconds, False,
-                             t_start=time.perf_counter(), device=device,
-                             n_devices=1, wrap_stream=wrap,
+                             t_start=time.perf_counter(), devices=devices,
+                             wrap_stream=wrap,
                              donate=wrap is None)
         print(json.dumps({"seed": seed, "correct": r["correct"],
                           "checks": {k: v["value"]
@@ -117,12 +117,12 @@ def main(argv=None) -> int:
     enable_compile_cache()
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    device = jax.devices()[0]
-    if device.platform != "tpu":
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
         print("bench/calibrate.py: no TPU found", file=sys.stderr)
         return 1
     spec = kvbench.load_spec()
-    (sweep if args.mode == "sweep" else control)(spec, args, device)
+    (sweep if args.mode == "sweep" else control)(spec, args, devices)
     return 0
 
 

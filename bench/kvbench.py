@@ -7,13 +7,28 @@ entries that list it, and each per-layer metric is read by
 ``bench/metrics/<name>.py``.  Nothing here names a cell, a configuration
 or a metric, so a later change adds any of them as files and entries.
 
+A configuration's keys (``CONFIG_KEYS``): ``table`` (the
+``HashTableConfig`` fields), ``recordcount`` and ``load_factor`` (the
+bulk load), ``slab_steps``, ``record``, ``guarantees``, ``deployment``
+(the chips the cell stands for and how the table is split over them),
+``source``, ``why``, ``assumed`` and ``reduced``.  The table's layout
+comes from ``table``: ``shards`` 1 is one memory domain on one chip; a
+mesh configuration sets ``table.shards`` to its partitions (with
+``router``, and ``replica_groups`` where shards have replicas), and its
+cell's ``chips`` must equal the devices the table occupies
+(``HashTableConfig.mesh_devices``: ``shards``, or the sum of
+``replica_groups``).
+
 A run:
 
 1. set-up (``setup_s``): the compile cache, the records, the H3 matrix and
-   the requests made from the seed; ``engine.bulk_build``; the served
-   stream (``engine.run_stream`` on the fused Pallas kernel, jitted for the
-   one ``[slab_steps, p]`` slab shape, table donated) compiled and warmed;
-   the ``TableServer``;
+   the requests made from the seed; the bulk load and the served stream,
+   through the program's entry points for the table's layout (one domain:
+   ``engine.bulk_build`` and ``engine.run_stream`` on the fused Pallas
+   kernel, jitted for the one ``[slab_steps, p]`` slab shape, table
+   donated; a mesh: ``distributed.make_distributed_bulk_build`` and
+   ``make_distributed_stream`` as the program returns them), compiled and
+   warmed on NOP slabs; the ``TableServer``;
 2. the window: ``--seconds`` of a closed loop (``clients`` each with one
    request outstanding) or an open loop (requests at seeded arrival
    times), through ``TableServer.submit`` / ``TableServer.step``, the
@@ -22,9 +37,10 @@ A run:
    Python's collector is held off it (the harness keeps every request for
    the check, which a server would have freed);
 3. the check: once the window has closed and the server has drained, the
-   peak device memory is read, the table's final plaintext is fetched and
-   the program's state freed; then ``kvref.compare`` holds every answer
-   and the final table to the plain reference.
+   peak device memory is read (the fullest of the cell's devices), the
+   table's final plaintext is fetched and the program's state freed; then
+   ``kvref.compare`` holds every answer and the final table to the plain
+   reference.
 """
 from __future__ import annotations
 
@@ -49,8 +65,8 @@ import trace_reduce
 BENCH = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(BENCH)
 CONFIG_KEYS = {"source", "table", "recordcount", "load_factor",
-               "slab_steps", "guarantees", "record", "assumed", "reduced",
-               "why"}
+               "slab_steps", "guarantees", "record", "deployment", "assumed",
+               "reduced", "why"}
 
 
 # ---------------------------------------------------------------------------
@@ -111,22 +127,61 @@ def cell_entry(spec: dict, cell: str) -> dict:
     raise ValueError(f"no workload {cell!r} in BENCHMARK.json")
 
 
+def cell_config(spec: dict, cell: str, root: str = REPO) -> dict:
+    """The configuration of ``cell``, refused when the cell's ``chips``
+    differ from the devices its table occupies."""
+    entry = cell_entry(spec, cell)
+    config = load_config(spec, entry["config"], root)
+    devices = table_config(config).mesh_devices
+    if entry["chips"] != devices:
+        raise ValueError(
+            f"{cell}: the cell asks for {entry['chips']} chips but "
+            f"configuration {entry['config']!r} lays its table over "
+            f"mesh_devices = {devices}")
+    return config
+
+
 # ---------------------------------------------------------------------------
 # Set-up
 # ---------------------------------------------------------------------------
 
 class Capture:
-    """The stream the server is given: calls the served stream and keeps
-    each dispatched slab's inputs and results for the check."""
+    """The stream the server is given: the served stream's entry points,
+    each dispatched slab's inputs and results kept for the check.
 
-    def __init__(self, stream: Callable):
+    It forwards the stream's ``router``, ``slack``, ``cfg``, ``measure`` and
+    ``plan``, so ``TableServer`` sees a bounded router as such, keeps its
+    plan cache and hands each slab's plan to :meth:`dispatch`.  ``wrap``
+    (the control and the fault tests) puts a changed stream in the served
+    one's place on both routes, ``__call__`` and ``dispatch``."""
+    FORWARDED = ("router", "slack", "cfg", "measure", "plan")
+
+    def __init__(self, stream: Callable, wrap: Optional[Callable] = None):
         self.stream = stream
         self.slabs: list = []
+        self._plan = None
+        wrap = wrap or (lambda f: f)
+        self._call = wrap(stream)
+        self._planned = wrap(lambda t, o, k, v: stream.dispatch(
+            t, o, k, v, self._plan))
+
+    def __getattr__(self, name):
+        if name in Capture.FORWARDED:
+            return getattr(self.stream, name)
+        raise AttributeError(name)
+
+    def _keep(self, ops, keys, vals, out):
+        self.slabs.append((ops, keys, vals, out[1]))
+        return out
 
     def __call__(self, table, ops, keys, vals):
-        table, res = self.stream(table, ops, keys, vals)
-        self.slabs.append((ops, keys, vals, res))
-        return table, res
+        return self._keep(ops, keys, vals,
+                          self._call(table, ops, keys, vals))
+
+    def dispatch(self, table, ops, keys, vals, plan):
+        self._plan = plan
+        return self._keep(ops, keys, vals,
+                          self._planned(table, ops, keys, vals))
 
 
 @dataclasses.dataclass
@@ -147,16 +202,106 @@ def table_config(config: dict, overrides: Optional[dict] = None):
     return HashTableConfig(**table)
 
 
+def _nop_slab(cfg, T: int):
+    import jax.numpy as jnp
+    N = cfg.queries_per_step
+    return (jnp.zeros((T, N), jnp.int32),
+            jnp.zeros((T, N, cfg.key_words), jnp.uint32),
+            jnp.zeros((T, N, cfg.val_words), jnp.uint32))
+
+
+def _one_domain(cfg, loaded, T: int, donate: bool):
+    """One memory domain: ``init_table``, ``engine.bulk_build`` and the
+    fused ``engine.run_stream``, jitted with the table donated, compiled
+    and run once on a NOP slab."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import engine, init_table
+    table = init_table(cfg, jax.random.key(0))
+    table = dataclasses.replace(table, q_masks=jnp.asarray(loaded.q_masks))
+    table, report = engine.bulk_build(table, jnp.asarray(loaded.keys),
+                                      jnp.asarray(loaded.vals))
+    spilled = int(np.asarray(report.spilled).sum())
+    stream = jax.jit(functools.partial(engine.run_stream, backend="pallas"),
+                     donate_argnums=(0,) if donate else ())
+    table, res = stream(table, *_nop_slab(cfg, T))  # compile + warm
+    jax.block_until_ready((table, res))
+    return table, stream, spilled
+
+
+def _mesh(cfg, loaded, T: int, requests, scfg):
+    """A table over ``cfg.mesh_devices`` devices: ``make_ht_mesh``,
+    ``init_distributed_table`` with the seed's H3 matrix replicated,
+    ``make_distributed_bulk_build`` over the records laid out as one
+    ``[1, N]`` row in load order (each device's lane block a contiguous
+    run, padding lanes not ``live``), and ``make_distributed_stream`` as
+    the program returns it.
+
+    The stream is warmed on NOP slabs, which write nothing: a bounded
+    router builds one program per routed shape, so the cell's request pool
+    is packed into slabs as the server packs a full queue and planned by a
+    plan cache of the server's settings, and each shape those plans take is
+    run once."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import distributed as dist
+    from repro.serving.engine import TableServer
+    from repro.serving.serve_loop import (PlanCache, SlabQueue, SlabRequest,
+                                          measure_loads_host, op_mix_bucket)
+    D = cfg.mesh_devices
+    mesh = dist.make_ht_mesh(D)
+    table = dist.init_distributed_table(cfg, jax.random.key(0), mesh)
+    table = dataclasses.replace(table, q_masks=jax.device_put(
+        jnp.asarray(loaded.q_masks), NamedSharding(mesh, P())))
+
+    R = len(loaded.keys)
+    n = -(-R // D)
+    keys = np.zeros((D * n, cfg.key_words), np.uint32)
+    vals = np.zeros((D * n, cfg.val_words), np.uint32)
+    live = np.zeros(D * n, bool)
+    keys[:R], vals[:R], live[:R] = loaded.keys, loaded.vals, True
+    lanes = NamedSharding(mesh, P(None, "ht"))
+    table, report = dist.make_distributed_bulk_build(mesh, cfg)(
+        table, jax.device_put(keys[None], lanes),
+        jax.device_put(vals[None], lanes), jax.device_put(live[None], lanes))
+    spilled = int((np.asarray(report.spilled)[0] & live).sum())
+
+    stream = dist.make_distributed_stream(mesh, cfg)
+    nop = _nop_slab(cfg, T)
+    if getattr(stream, "router", None) != "bounded":
+        table, res = stream(table, *nop)
+        jax.block_until_ready((table, res))
+        return table, stream, spilled
+    N = cfg.queries_per_step
+    queue = SlabQueue(T, N, cfg.key_words, cfg.val_words,
+                      nsq_lanes=TableServer._nsq_mask(cfg))
+    kind, _, vals = requests.ops_made(requests.t.pool_ops)
+    queue.submit(SlabRequest(rid=0, ops=kind, keys=requests.keys, vals=vals))
+    cache = PlanCache(cfg, plans=scfg.plan_cache_plans, slack=stream.slack)
+    shapes = {}
+    while queue.pending_requests:
+        slab = queue.next_slab()
+        loads, pair = measure_loads_host(cfg, loaded.q_masks, slab.keys,
+                                         slab.ops)
+        plan, _ = cache.lookup(loads, pair, op_mix_bucket(slab.ops),
+                               n_local=N // D)
+        shapes.setdefault((plan.pair_capacity, plan.routed_width,
+                           plan.routed_steps, plan.carried_lanes), plan)
+    for plan in shapes.values():
+        table, res = stream.dispatch(table, *nop, plan)
+        jax.block_until_ready((table, res))
+    return table, stream, spilled
+
+
 def build(config: dict, traffic: kvtraffic.Traffic, seed: int, *,
           overrides: Optional[dict] = None,
           wrap_stream: Optional[Callable] = None, donate: bool = True
           ) -> Cell:
     """Everything before the window.  ``overrides`` replaces table fields
     (the tests' tiny tables); ``wrap_stream`` puts a changed stream in the
-    served one's place (the control and the fault tests)."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core import engine, init_table
+    served one's place (the control and the fault tests).  ``table.shards``
+    picks the layout: 1 is one memory domain, more a mesh."""
     from repro.serving.engine import ServeConfig, TableServer
 
     cfg = table_config(config, overrides)
@@ -166,27 +311,16 @@ def build(config: dict, traffic: kvtraffic.Traffic, seed: int, *,
                          f"load_factor x buckets x slots = {records}")
     loaded = kvtraffic.make_loaded(records, cfg.index_bits, cfg.key_words,
                                    cfg.val_words, cfg.slots, seed)
-    table = init_table(cfg, jax.random.key(0))
-    table = dataclasses.replace(table, q_masks=jnp.asarray(loaded.q_masks))
-    table, report = engine.bulk_build(table, jnp.asarray(loaded.keys),
-                                      jnp.asarray(loaded.vals))
-    spilled = int(np.asarray(report.spilled).sum())
-
-    T, N = config["slab_steps"], cfg.queries_per_step
-    stream = jax.jit(functools.partial(engine.run_stream, backend="pallas"),
-                     donate_argnums=(0,) if donate else ())
-    nop = (jnp.zeros((T, N), jnp.int32),
-           jnp.zeros((T, N, cfg.key_words), jnp.uint32),
-           jnp.zeros((T, N, cfg.val_words), jnp.uint32))
-    table, res = stream(table, *nop)              # compile + warm
-    jax.block_until_ready((table, res))
-    if wrap_stream is not None:
-        stream = wrap_stream(stream)
-    capture = Capture(stream)
-    server = TableServer(cfg, table, capture,
-                         ServeConfig(slab_steps=T, geometry_replan=False))
-
     requests = kvtraffic.RequestStream(traffic, loaded, seed)
+    T = config["slab_steps"]
+    scfg = ServeConfig(slab_steps=T, geometry_replan=False)
+    if cfg.shards > 1:
+        table, stream, spilled = _mesh(cfg, loaded, T, requests, scfg)
+    else:
+        table, stream, spilled = _one_domain(cfg, loaded, T, donate)
+    capture = Capture(stream, wrap_stream)
+    server = TableServer(cfg, table, capture, scfg)
+
     gc.collect()
     gc.freeze()
     return Cell(cfg, server, capture, loaded, requests, traffic, spilled)
@@ -201,7 +335,7 @@ class Window:
     t0: float
     deadline: float
     submitted: list                 # SlabRequest of request i, in order
-    counters: Dict[str, int]        # server counters' change in the window
+    counters: Dict[str, int]        # change of server.stats()'s integers
     slab_range: tuple               # captured slabs dispatched in the window
     lateness_s: np.ndarray          # open loop: submit time - arrival time
     step_max_s: float               # longest TableServer.step() in the window
@@ -209,9 +343,16 @@ class Window:
     trace: Optional[dict] = None    # trace_reduce.summarize of the window
 
 
-def _counters(server) -> Dict[str, int]:
-    return {"slabs": server.slabs, "live_lanes": server.live_lanes,
-            "pad_lanes": server.pad_lanes}
+def counters(stats: dict, prefix: str = "") -> Dict[str, int]:
+    """Every integer of ``TableServer.stats()``, nested keys joined by
+    ``.`` (``plan_cache.misses``); flags, ratios and lists are left out."""
+    out: Dict[str, int] = {}
+    for key, v in stats.items():
+        if isinstance(v, dict):
+            out.update(counters(v, f"{prefix}{key}."))
+        elif isinstance(v, (int, np.integer)) and not isinstance(v, bool):
+            out[prefix + key] = int(v)
+    return out
 
 
 class _CompileCounter:
@@ -343,11 +484,11 @@ def run_window(cell: Cell, seconds: float, trace_dir: Optional[str] = None,
     gc.disable()
     try:
         with _CompileCounter() as compiles:
-            c0, s0 = _counters(srv), len(cell.capture.slabs)
+            c0, s0 = counters(srv.stats()), len(cell.capture.slabs)
             t0 = time.perf_counter()
             with _span()("bench.window"):
                 late = drive(cell, t0, seconds, submit, steps, lateness)
-            c1, s1 = _counters(srv), len(cell.capture.slabs)
+            c1, s1 = counters(srv.stats()), len(cell.capture.slabs)
         for _ in range(late):            # arrivals the loop had not reached
             lateness.append(time.perf_counter() - t0
                             - float(rs.arrival_s(len(submitted))))
@@ -361,7 +502,7 @@ def run_window(cell: Cell, seconds: float, trace_dir: Optional[str] = None,
     summary = (trace_reduce.summarize(trace_reduce.load(trace_dir), platform)
                if trace_dir is not None else None)
     return Window(t0, t0 + seconds, submitted,
-                  {k: c1[k] - c0[k] for k in c0}, (s0, s1),
+                  {k: v - c0.get(k, 0) for k, v in c1.items()}, (s0, s1),
                   np.asarray(lateness), steps.max_s, compiles.count, summary)
 
 
@@ -408,7 +549,7 @@ def check(cell: Cell, win: Window, final, slabs, got):
         kind=kind, kid=kid, op_vals=op_vals, answered=answered,
         found=found, ok=ok, value=value, slab_ops=slabs[0],
         slab_keys=slabs[1], slab_vals=slabs[2], final_keys=final[0],
-        final_vals=final[1], final_valid=final[2])
+        final_vals=final[1], final_valid=final[2], devices=cfg.mesh_devices)
 
 
 # ---------------------------------------------------------------------------
@@ -451,9 +592,11 @@ def reader_context(cell: Cell, win: Window, slabs, peak: dict) -> dict:
                  "accepted_mutations": int((ok & (ops >= 2)).sum())},
         "geometry": {"p": cfg.p, "k": cfg.k, "slots": cfg.slots,
                      "key_words": cfg.key_words,
-                     "val_words": cfg.val_words, "buckets": cfg.buckets},
+                     "val_words": cfg.val_words, "buckets": cfg.buckets,
+                     "shards": cfg.shards, "devices": cfg.mesh_devices},
         "peak": peak,
         "steps": {"max_ms": win.step_max_s * 1e3},
+        "window": {"compiles": win.compiles},
     }
 
 
@@ -465,15 +608,26 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def memory_peaks(devices) -> List[int]:
+    """``peak_bytes_in_use`` of each device (0 where the backend keeps no
+    count)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
 def run_cell(spec: dict, workload: str, seed: int, seconds: float,
-             trace: bool, *, t_start: float, device, n_devices: int,
-             overrides=None,
-             wrap_stream=None, donate: bool = True) -> dict:
-    """One run of one cell; returns the result line's object."""
+             trace: bool, *, t_start: float, devices: list,
+             overrides=None, wrap_stream=None, donate: bool = True,
+             root: str = REPO) -> dict:
+    """One run of one cell on ``devices`` (every device JAX sees; the cell
+    uses the first ``chips``); returns the result line's object.  The
+    cell's files are read from the checkout at ``root``."""
     import jax
+    bench = os.path.join(root, "bench")
     entry = cell_entry(spec, workload)
-    config = load_config(spec, entry["config"])
-    traffic = load_traffic(entry["traffic"])
+    config = cell_config(spec, workload, root)
+    traffic = load_traffic(entry["traffic"], bench)
+    device = devices[0]
     peak = peaks_for(device.device_kind) if device.platform == "tpu" else {}
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
@@ -490,15 +644,15 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    stats = device.memory_stats() or {}
-    mem_peak = int(stats.get("peak_bytes_in_use", 0))
+    peaks = memory_peaks(devices[:entry["chips"]])
     lat = win.lateness_s
     log(f"[{workload}] window {seconds} s: {len(win.submitted)} requests, "
         f"{win.counters['slabs']} slabs, {win.compiles} programs built in "
         f"the window, longest step {win.step_max_s * 1e3:.3f} ms; "
         f"generator late by p99 "
         f"{np.percentile(lat, 99) * 1e3 if len(lat) else 0.0:.3f} ms, max "
-        f"{lat.max() * 1e3 if len(lat) else 0.0:.3f} ms")
+        f"{lat.max() * 1e3 if len(lat) else 0.0:.3f} ms; memory peak by "
+        f"device {peaks}")
 
     final = fetch_final(cell)
     slabs = fetch_slabs(cell)
@@ -516,7 +670,7 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     metrics = {}
     if trace:
         for m in cell_metrics(spec, workload, "per_layer"):
-            v = load_metric(m["name"]).read(ctx)
+            v = load_metric(m["name"], bench).read(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
@@ -525,7 +679,7 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
     dev = {"platform": device.platform, "kind": device.device_kind,
-           "count": n_devices, "memory_peak_bytes": mem_peak}
+           "count": len(devices), "memory_peak_bytes": max(peaks)}
     result = {"correct": all(v == 0 for v in checks.values()),
               "attempted": n_ops, "failed": failed, "metrics": metrics,
               "device": dev}

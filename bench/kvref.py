@@ -12,10 +12,14 @@ numpy what every op must answer and what the table must hold afterwards:
 * steps: the lanes of one ``[N]`` step of a slab read the table as it stood
   before the step.  A search answers ``found`` and the stored value; an
   update (insert of a key) answers the same and is acknowledged exactly
-  where its key is present: the server has to put every update on one of
-  the ``k`` of every ``p`` lanes that may write (lane ``n`` belongs to PE
-  ``n % p``; PEs ``< k`` own write ports), so an update of a present key
+  where its key is present: the server has to put every update on a lane
+  whose PE owns a write port (PEs ``< k``), so an update of a present key
   that is refused is a fault of the server;
+* lanes and PEs (:func:`lane_pe`): on one memory domain lane ``n`` of an
+  ``[N]`` step belongs to PE ``n % p``; on a mesh of ``devices`` origin
+  devices the step's lanes are split into ``devices`` contiguous blocks of
+  ``N / devices`` and lane ``n`` belongs to its origin device, PE
+  ``n // (N / devices)``;
 * write ports: PE ``j`` writes XOR plane ``j``, encoded against the pre-step
   planes.  Updates of one record from ``m`` ports in one step therefore
   leave the XOR of their values, and of the old value when ``m`` is even
@@ -74,10 +78,18 @@ def pack64(words: np.ndarray) -> np.ndarray:
     return w[..., 0] | (w[..., 1] << np.uint64(32))
 
 
+def lane_pe(lanes: int, p: int, devices: int = 1) -> np.ndarray:
+    """The PE of each of a step's ``lanes`` lanes: ``n % p`` on one memory
+    domain (``devices == 1``), the origin device ``n // (lanes / devices)``
+    on a mesh of ``devices`` devices."""
+    n = np.arange(lanes, dtype=np.int64)
+    return n % p if devices == 1 else n // (lanes // devices)
+
+
 def compare(*, p: int, k: int, slots: int, loaded_keys, loaded_vals,
             q_masks, kind, kid, op_vals, answered, found, ok, value,
             slab_ops, slab_keys, slab_vals, final_keys, final_vals,
-            final_valid):
+            final_valid, devices: int = 1):
     """Counts of disagreement with the reference (each must be 0), and a
     few facts about the run.
 
@@ -85,7 +97,11 @@ def compare(*, p: int, k: int, slots: int, loaded_keys, loaded_vals,
     position); ``answered/found/ok/value`` are what the clients got back;
     ``slab_*`` are the ``[S, T, N, ...]`` slabs the server dispatched, in
     order; ``final_*`` is the table's plaintext after the run
-    (``[B, slots, W]`` records and their valid bits)."""
+    (``[B, slots, W]`` records and their valid bits).  ``devices`` is the
+    number of origin devices the steps' lanes are split over: 1 for one
+    memory domain, whose lane ``n`` belongs to PE ``n % p``; on a mesh,
+    lane ``n`` belongs to PE ``n // (N / devices)``, its origin device
+    (:func:`lane_pe`)."""
     n = len(kind)
     present = bulk_load(loaded_keys, q_masks, slots)
 
@@ -113,7 +129,7 @@ def compare(*, p: int, k: int, slots: int, loaded_keys, loaded_vals,
     ran = seen == 1
 
     # -- accepted writes, grouped per (key, step) --------------------------
-    pe = op_lane % p
+    pe = lane_pe(N, p, devices)[op_lane]
     is_upd = kind == OP_UPDATE
     writes = ran & is_upd & present[kid] & (pe < k)
     w_idx = np.flatnonzero(writes)
@@ -124,11 +140,11 @@ def compare(*, p: int, k: int, slots: int, loaded_keys, loaded_vals,
     o = np.lexsort((op_lane[w_idx], w_port, w_step, w_key))
     w_idx, w_key, w_step, w_port = w_idx[o], w_key[o], w_step[o], w_port[o]
     last = np.r_[(w_key[1:] != w_key[:-1]) | (w_step[1:] != w_step[:-1])
-                 | (w_port[1:] != w_port[:-1]), True]
+                 | (w_port[1:] != w_port[:-1]), True][:len(w_idx)]
     w_idx, w_key, w_step = w_idx[last], w_key[last], w_step[last]
     new = pack64(op_vals[w_idx])
     g_new = np.r_[True, (w_key[1:] != w_key[:-1])
-                  | (w_step[1:] != w_step[:-1])]
+                  | (w_step[1:] != w_step[:-1])][:len(w_key)]
     g_id = np.cumsum(g_new) - 1
     g_key, g_step = w_key[g_new], w_step[g_new]
     m = np.bincount(g_id)

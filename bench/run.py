@@ -13,7 +13,9 @@ with the plain reference beside its limit.  The same numbers close
 standard error.
 
 It exits non-zero and prints no result when JAX finds no TPU, or fewer
-chips than the cell asks for, or the checkout lacks the program.
+chips than the cell asks for, or the checkout lacks the program, or the
+cell's ``chips`` differ from the devices its configuration lays the table
+over (``HashTableConfig.mesh_devices``).
 """
 from __future__ import annotations
 
@@ -45,6 +47,11 @@ def main(argv=None) -> int:
     import kvbench
     spec = kvbench.load_spec()
     entry = kvbench.cell_entry(spec, args.workload)
+    try:
+        kvbench.cell_config(spec, args.workload)
+    except ValueError as e:
+        print(f"bench/run.py: {e}", file=sys.stderr)
+        return 2
 
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -61,7 +68,7 @@ def main(argv=None) -> int:
         return 1
     result = kvbench.run_cell(spec, args.workload, args.seed, args.seconds,
                               bool(args.trace), t_start=t_start,
-                              device=devices[0], n_devices=len(devices))
+                              devices=devices)
     for name, c in result["checks"].items():
         print(f"check {name} = {c['value']} (limit {c['limit']})",
               file=sys.stderr)

@@ -5,7 +5,13 @@ configuration, traffic mix and metric load from new files alone, reduce a
 trace recorded here, count the roofline's bytes by hand, rehearse every
 cell at a tiny table with the kernels in interpret mode, and show that the
 check reads ``correct`` false for the control and for each fault a cell can
-have.  No TPU is needed or described.
+have.  A cell on more than one chip, and a sharded configuration given
+only as test data, run in a child process on as many virtual CPU devices.
+No TPU is needed or described.
+
+Run as a script, the file is that child: ``python bench/test_bench_harness.py
+<job.json>`` runs one cell once per fault named in the job (``null``: the
+served stream as it is) and prints each result line.
 """
 from __future__ import annotations
 
@@ -37,12 +43,43 @@ _RES = kvbench.table_config(kvbench.load_config(SPEC, "ycsb_resident"))
 SLAB_GEOMETRY = (_RES.p, _RES.k)
 
 
+SEED = 2 ** 31 + 12345
+
+
 def _run(cell, trace=False, **kw):
+    """One rehearsal of a ``BENCHMARK.json`` cell at a tiny table; a cell on
+    more than one chip runs in a child process (:func:`_run_devices`)."""
     import jax
-    return kvbench.run_cell(SPEC, cell, 2 ** 31 + 12345, 1.0, trace,
+    chips = kvbench.cell_entry(SPEC, cell)["chips"]
+    if chips > 1:
+        assert not kw, "the child takes a fault by name (_run_devices)"
+        return _run_devices(SPEC, REPO, cell, chips, trace, [None])[0][0]
+    return kvbench.run_cell(SPEC, cell, SEED, 1.0, trace,
                             t_start=time.perf_counter(),
-                            device=jax.devices()[0], n_devices=1,
-                            overrides=TINY, **kw)
+                            devices=jax.devices()[:1], overrides=TINY, **kw)
+
+
+def _run_devices(spec, root, cell, chips, trace, faults):
+    """Runs ``cell`` in a child on ``chips`` virtual CPU devices, once per
+    fault name in ``faults`` (``None``: the served stream); returns the
+    result lines and the child's standard error."""
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        job = os.path.join(d, "job.json")
+        with open(job, "w") as f:
+            json.dump({"spec": spec, "root": root, "cell": cell,
+                       "trace": trace, "faults": faults}, f)
+        env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
+            os.environ.get("XLA_FLAGS", "") +
+            f" --xla_force_host_platform_device_count={chips}").strip())
+        p = subprocess.run([sys.executable, os.path.abspath(__file__), job],
+                           cwd=REPO, env=env, capture_output=True, text=True,
+                           timeout=600)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-8000:]
+    lines = [json.loads(x) for x in p.stdout.splitlines()
+             if x.startswith("{")]
+    assert len(lines) == len(faults), p.stdout[-4000:]
+    return lines, p.stderr
 
 
 # ---------------------------------------------------------------------------
@@ -222,8 +259,7 @@ def test_open_loop_rehearsal(trace):
         for n in ("pad_fraction.open", "step_max_ms.open")]
     r = kvbench.run_cell(spec, OPEN_CELL["name"], 2 ** 31 + 99, 1.0, trace,
                          t_start=time.perf_counter(),
-                         device=jax.devices()[0], n_devices=1,
-                         overrides=TINY)
+                         devices=jax.devices()[:1], overrides=TINY)
     assert r["correct"], r["checks"]
     want = ({"pad_fraction.open", "step_max_ms.open"} if trace
             else {"p50_ms", "p99_ms", "setup_s"})
@@ -277,40 +313,215 @@ def _answer_altered(stream):
     return run
 
 
-def _update_on_search_lane(stream):
+def _update_on_search_lane(p, k, devices=1):
     """Moves the first update of each slab from its write lane to a lane
-    whose PE has no write port, and its answer back."""
+    whose PE has no write port, and its answer back.  PEs of lanes by the
+    table's layout (``kvref.lane_pe``)."""
     import jax.numpy as jnp
 
-    def run(t, o, k, v):
-        o_np = np.asarray(o)
-        p, kk = SLAB_GEOMETRY
-        pe = np.arange(o_np.shape[1]) % p
-        at = np.argwhere((o_np == kvref.OP_UPDATE) & (pe < kk))
-        if not len(at):
-            return stream(t, o, k, v)
-        step, a = at[0]
-        b = int(np.flatnonzero(pe >= kk)[0])
+    def wrap(stream):
+        def run(t, o, k_, v):
+            o_np = np.asarray(o)
+            pe = kvref.lane_pe(o_np.shape[1], p, devices)
+            at = np.argwhere((o_np == kvref.OP_UPDATE) & (pe < k))
+            if not len(at):
+                return stream(t, o, k_, v)
+            step, a = at[0]
+            b = int(np.flatnonzero(pe >= k)[0])
 
-        def swap(x):
-            x = np.array(x)
-            x[step, [a, b]] = x[step, [b, a]]
-            return jnp.asarray(x)
-        t, res = stream(t, swap(o), swap(k), swap(v))
-        return t, dataclasses.replace(res, found=swap(res.found),
-                                      ok=swap(res.ok), value=swap(res.value))
-    return run
+            def swap(x):
+                x = np.array(x)
+                x[step, [a, b]] = x[step, [b, a]]
+                return jnp.asarray(x)
+            t, res = stream(t, swap(o), swap(k_), swap(v))
+            return t, dataclasses.replace(
+                res, found=swap(res.found), ok=swap(res.ok),
+                value=swap(res.value))
+        return run
+    return wrap
 
 
-@pytest.mark.parametrize("wrap", [StaleReads, _state_unchanged,
-                                  _half_the_batch, _answer_altered,
-                                  _update_on_search_lane],
-                         ids=["control_stale_reads", "state_unchanged",
-                              "half_the_batch", "answer_altered",
-                              "update_on_search_lane"])
-def test_control_and_faults_read_incorrect(wrap):
-    r = _run("ycsb_b.hbm", wrap_stream=wrap, donate=False)
+def _faults(p, k, devices=1):
+    """The control and each fault a cell can have, by name, for a table of
+    ``p`` PEs, ``k`` write ports over ``devices`` origin devices."""
+    return {"control_stale_reads": StaleReads,
+            "state_unchanged": _state_unchanged,
+            "half_the_batch": _half_the_batch,
+            "answer_altered": _answer_altered,
+            "update_on_search_lane": _update_on_search_lane(p, k, devices)}
+
+
+@pytest.mark.parametrize("fault", list(_faults(*SLAB_GEOMETRY)))
+def test_control_and_faults_read_incorrect(fault):
+    r = _run("ycsb_b.hbm", wrap_stream=_faults(*SLAB_GEOMETRY)[fault],
+             donate=False)
     assert not r["correct"]
     assert any(c["value"] > 0 for c in r["checks"].values())
-    if wrap is _update_on_search_lane:
+    if fault == "update_on_search_lane":
         assert r["checks"]["ok_mismatch"]["value"] > 0
+
+
+# ---------------------------------------------------------------------------
+# A sharded configuration as data: four virtual devices
+# ---------------------------------------------------------------------------
+
+MESH_TABLE = {"buckets": 1024, "shards": 4, "router": "bounded", "k": 2}
+MESH_CELL = {"name": "ycsb_b.mesh_rehearsal", "config": "ycsb_mesh_rehearsal",
+             "traffic": "ycsb_b", "chips": 4, "why": "x"}
+MESH_READERS = {
+    "plan_cache_hits":
+        "def read(ctx):\n    return ctx['counters'].get('plan_cache.hits')\n",
+    "window_compiles":
+        "def read(ctx):\n    return ctx['window']['compiles']\n",
+}
+
+
+def _mesh_checkout(tmp_path):
+    """A checkout holding only a sharded bounded-router configuration (the
+    ``ycsb_hbm`` file with ``MESH_TABLE``'s fields), the YCSB-B mix, two
+    readers of the program's counters, and the spec entries naming them."""
+    bench = tmp_path / "bench"
+    for d in ("configs", "traffic", "metrics"):
+        (bench / d).mkdir(parents=True)
+    cfg = kvbench.load_config(SPEC, "ycsb_hbm")
+    cfg["table"].update(MESH_TABLE)
+    t = kvbench.table_config(cfg)
+    cfg["recordcount"] = int(cfg["load_factor"] * t.buckets * t.slots)
+    cfg["deployment"] = {"chips": 4, "split": "4 bucket shards, one a chip"}
+    name = MESH_CELL["config"]
+    (bench / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    (bench / "traffic" / "ycsb_b.json").write_text(
+        open(os.path.join(BENCH, "traffic", "ycsb_b.json")).read())
+    for m, src in MESH_READERS.items():
+        (bench / "metrics" / f"{m}.py").write_text(src)
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": name, "source": "x", "reduced": [],
+                            "file": f"bench/configs/{name}.json", "why": "x"})
+    spec["workloads"].append(MESH_CELL)
+    spec["per_layer"] = [{"name": m, "unit": "x", "layer": "x",
+                          "workloads": [MESH_CELL["name"]]}
+                         for m in MESH_READERS]
+    return spec, t
+
+
+def test_sharded_configuration_runs_as_data(tmp_path):
+    """``shards=4`` with the bounded router, given only as a configuration
+    dict and spec entries, runs through ``kvbench.run_cell`` on four
+    virtual devices: ``correct``, the server's plan cache used through
+    ``Capture``, no program built in the window, a peak read from every
+    device; an altered answer and an update on a search lane (a device
+    ``>= k``) each read ``correct`` false."""
+    spec, t = _mesh_checkout(tmp_path)
+    assert t.mesh_devices == 4 and t.k < t.mesh_devices
+    faults = [None, "answer_altered", "update_on_search_lane"]
+    (ok, altered, search_lane), err = _run_devices(
+        spec, str(tmp_path), MESH_CELL["name"], 4, True, faults)
+    assert ok["correct"], ok["checks"]
+    assert ok["failed"] == 0 and ok["attempted"] > 0
+    assert ok["metrics"]["plan_cache_hits"]["value"] > 0
+    assert ok["metrics"]["window_compiles"]["value"] == 0
+    assert ok["device"]["count"] == 4
+    peaks = [line for line in err.splitlines()
+             if "memory peak by device" in line]
+    assert len(peaks) == 3
+    assert peaks[0].rsplit("[", 1)[1].count(",") == 3
+    assert not altered["correct"]
+    assert altered["checks"]["value_mismatch"]["value"] > 0
+    assert not search_lane["correct"]
+    assert search_lane["checks"]["ok_mismatch"]["value"] > 0
+
+
+def test_reference_takes_the_lane_pe_rule_from_the_layout():
+    """An update on lane 8 of a 16-lane step of a ``p=4, k=2`` table: PE
+    ``8 % 4 = 0`` may write on one domain; on four devices the lane is
+    origin device ``8 // 4 = 2 >= k``, which has no write port.  A run that
+    wrote it reads correct under the first rule and not the second."""
+    p, k, N = 4, 2, 16
+    assert kvref.lane_pe(N, p).tolist() == [0, 1, 2, 3] * 4
+    assert kvref.lane_pe(N, p, 4).tolist() == sum(([d] * 4 for d in
+                                                   range(4)), [])
+    q = np.array([[1, 0], [2, 0]], np.uint32)             # 2 bits, 4 buckets
+    keys = np.array([[1, 7]], np.uint32)
+    old, new = np.array([[5, 0]], np.uint32), np.array([[0, 9]], np.uint32)
+    ops = np.zeros((1, 1, N), np.int32)
+    ops[0, 0, 8] = kvref.OP_UPDATE
+    skeys = np.zeros((1, 1, N, 2), np.uint32)
+    skeys[0, 0, 8] = keys[0]
+    svals = np.zeros((1, 1, N, 2), np.uint32)
+    svals[0, 0, 8] = new[0]
+    fk = np.zeros((4, 4, 2), np.uint32)
+    fv = np.zeros((4, 4, 2), np.uint32)
+    fvalid = np.zeros((4, 4), np.uint32)
+    b = int(kvref.h3(keys, q)[0])
+    fk[b, 0], fv[b, 0], fvalid[b, 0] = keys[0], new[0], 1
+    run = dict(p=p, k=k, slots=4, loaded_keys=keys, loaded_vals=old,
+               q_masks=q, kind=np.array([kvref.OP_UPDATE]),
+               kid=np.array([0]), op_vals=new, answered=np.array([True]),
+               found=np.array([True]), ok=np.array([True]), value=old,
+               slab_ops=ops, slab_keys=skeys, slab_vals=svals,
+               final_keys=fk, final_vals=fv, final_valid=fvalid)
+    one, _ = kvref.compare(**run)
+    assert all(v == 0 for v in one.values()), one
+    mesh, _ = kvref.compare(**run, devices=4)
+    assert mesh["state_mismatch"] == 1, mesh
+
+
+def test_memory_peak_is_the_fullest_device():
+    class Dev:
+        def __init__(self, peak):
+            self.peak = peak
+
+        def memory_stats(self):
+            return None if self.peak is None else {
+                "peak_bytes_in_use": self.peak}
+    assert kvbench.memory_peaks([Dev(5), Dev(9), Dev(7), Dev(None)]) == [
+        5, 9, 7, 0]
+    assert kvbench.memory_peaks([Dev(3)]) == [3]
+
+
+def test_command_refuses_chips_unlike_the_table(tmp_path):
+    """``bench/run.py`` refuses a cell whose ``chips`` differ from the
+    devices its configuration lays the table over, before it looks for a
+    chip."""
+    spec = json.loads(json.dumps(SPEC))
+    spec["workloads"].append({"name": "ycsb_b.resident4",
+                              "config": "ycsb_resident", "traffic": "ycsb_b",
+                              "chips": 4, "why": "x"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    os.symlink(BENCH, tmp_path / "bench")
+    os.symlink(os.path.join(REPO, "src"), tmp_path / "src")
+    with pytest.raises(ValueError, match="4 chips.*mesh_devices = 1"):
+        kvbench.cell_config(spec, "ycsb_b.resident4")
+    p = subprocess.run([sys.executable, str(tmp_path / "bench" / "run.py"),
+                        "--workload", "ycsb_b.resident4", "--seed", "1",
+                        "--seconds", "1", "--trace", "0"],
+                       cwd=tmp_path, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "4 chips" in p.stderr and "mesh_devices = 1" in p.stderr
+
+
+# ---------------------------------------------------------------------------
+# The child of _run_devices
+# ---------------------------------------------------------------------------
+
+def _child(job_path: str) -> None:
+    import jax
+    with open(job_path) as f:
+        job = json.load(f)
+    spec, cell = job["spec"], job["cell"]
+    cfg = kvbench.table_config(kvbench.cell_config(spec, cell, job["root"]),
+                               TINY)
+    faults = _faults(cfg.p, cfg.k, cfg.mesh_devices)
+    for name in job["faults"]:
+        r = kvbench.run_cell(spec, cell, SEED, 1.0, job["trace"],
+                             t_start=time.perf_counter(),
+                             devices=jax.devices(), overrides=TINY,
+                             wrap_stream=faults.get(name),
+                             donate=name is None, root=job["root"])
+        print(json.dumps(r), flush=True)
+
+
+if __name__ == "__main__":
+    _child(sys.argv[1])
